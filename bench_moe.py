@@ -22,7 +22,7 @@ if it doesn't) and `moe_a2a_bytes_per_step` prices the routing wire
 traffic from the `kv_collective_bytes{op=moe_all_to_all}` counter.
 
 Needs >= 4 devices (the (2,2) mesh); below that `value: None` so the
-bench.py supervisor fields are omitted honestly rather than faked —
+bench.py JSON fields are omitted honestly rather than faked —
 the BENCH_SHARD=0 pattern.
 
 Standalone: `python bench_moe.py` prints ONE JSON line.
@@ -93,7 +93,7 @@ def _build(moe):
 
 
 def measure(on_result=None):
-    """The supervisor arm: sharded-MoE vs equal-parameter dense-FFN
+    """The bench.py arm: sharded-MoE vs equal-parameter dense-FFN
     captured steps. Returns the `moe_*` contract fields; `value: None`
     below 4 devices."""
     import jax

@@ -30,7 +30,7 @@ host tier + engine-prefetched hot cache (`shard/tiered.py`), fed by the
 `RowPrefetcher`.
 
 Needs >= 4 devices (a (2,2) mesh); below that `value: None` so the
-bench.py supervisor fields (`rec_step_throughput`,
+bench.py JSON fields (`rec_step_throughput`,
 `rec_embed_bytes_per_dev`, `rec_vs_replicated`, and the `rec_tiered_*`
 set) are omitted honestly rather than faked — the BENCH_SHARD=0
 pattern.
@@ -109,7 +109,7 @@ def _build(vocabs, dim, batch, sharded):
 
 
 def measure(on_result=None):
-    """The supervisor arm: sharded-vs-replicated captured DLRM steps.
+    """The bench.py arm: sharded-vs-replicated captured DLRM steps.
     Returns the `rec_*` contract fields; `value: None` below 4
     devices."""
     import jax

@@ -2,7 +2,7 @@
 tokens/s under Poisson arrivals, continuous vs static batching.
 
 ISSUE 12 extension — the `--fastpath` arm (also folded into bench.py's
-supervisor fields) measures the serving fast path on a shared-system-
+bench.py fields) measures the serving fast path on a shared-system-
 prompt Poisson mix: the SAME prompted trace runs warm (content-hashed
 radix prefix cache on — later requests adopt cached prompt pages and
 skip that prefill) vs cold (cache disabled), and once more with
@@ -16,7 +16,7 @@ while a sustained background engine flood (prefetch/checkpoint stand-in
 tasks) contends for the engine workers, once with QoS priorities on and
 once with `engine.set_qos(False)` (pure FIFO): the contended p99 pair is
 what the priority classes + aging actually buy a serving tenant sharing
-chips with training. `p99_contended_ms` rides the supervisor JSON as
+chips with training. `p99_contended_ms` rides bench.py's JSON line as
 `serve_p99_contended_ms`.
 
 The workload is a mixed-length open-loop arrival process: exponential
@@ -32,7 +32,7 @@ The same request trace is replayed twice through the SAME model:
 
 Reports p50/p95/p99 end-to-end latency, p50 TTFT and tokens/s for both
 policies plus the speedup. Prints exactly ONE JSON line on stdout
-(standalone); `measure()` returns the dict for bench.py's supervisor
+(standalone); `measure()` returns the dict for bench.py's JSON line
 contract (`serve_tokens_per_s` / `serve_p99_ms` ride the headline
 metric). Off the driver line by default only in --smoke runs; disable
 with BENCH_SERVE=0.
@@ -40,7 +40,6 @@ with BENCH_SERVE=0.
 from __future__ import annotations
 
 import json
-import os
 import sys
 import time
 
@@ -144,7 +143,7 @@ def _contended_fields(reqs):
     """The QoS-vs-FIFO contended arm, one pass each (the deterministic
     decode-turn witness makes repeats unnecessary): decode p99 while a
     background-train flood contends for the engine, with and without
-    priority scheduling. One source for both the supervisor-contract
+    priority scheduling. One source for both the bench.py JSON
     fields in measure() and the standalone --background-train line."""
     qos = measure_contended(reqs, qos=True)
     fifo = measure_contended(reqs, qos=False)
@@ -417,9 +416,6 @@ def measure(seed=0, repeats=2, background_train=True):
 
 def main(argv=None):
     argv = list(sys.argv[1:] if argv is None else argv)
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     if "--fastpath" in argv:
         # ISSUE 12 arms only: prefix-heavy warm-vs-cold + speculative
         print(json.dumps(measure_fastpath()), flush=True)

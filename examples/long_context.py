@@ -32,12 +32,7 @@ def main():
                               "--xla_force_host_platform_device_count=8")
         import jax
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", 8)
-        except AttributeError:
-            # older jax (< 0.5): the XLA_FLAGS
-            # host_platform_device_count above provides the 8 devices
-            pass
+        jax.config.update("jax_num_cpu_devices", 8)
         args.seq = 256
     import jax
     import jax.numpy as jnp

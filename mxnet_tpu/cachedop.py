@@ -532,7 +532,7 @@ class CachedStep:
         kv = tr._kvstore
         from .optimizer import multi_tensor as _mt
         from .optimizer.multi_tensor import apply_param_update
-        from .jax_compat import shard_map
+        from jax import shard_map
         from .shard import embedding as _semb
         from .shard import moe as _smoe
         from jax.sharding import PartitionSpec as P
@@ -993,8 +993,18 @@ class CachedStep:
             # gather-before-use / reduce-scatter-after-backward and TP
             # collectives the specs imply.
             from jax.sharding import NamedSharding
-            fn = program
+            from .ops.pallas_kernels import kernel_mesh
             pmesh = plan.mesh
+            # the partitioner cannot split a Mosaic kernel: the Pallas
+            # calls inside run per shard (batch over the data axis,
+            # heads over the one other axis of a 2-D mesh)
+            others = [a for a in pmesh.axis_names if a != plan.data_axis]
+            head_axis = others[0] if len(others) == 1 else None
+
+            def fn(*args):
+                with kernel_mesh(pmesh, plan.data_axis, head_axis):
+                    return program(*args)
+
             repl = NamedSharding(pmesh, P())
             n_dp = int(pmesh.shape[plan.data_axis])
             bsh = plan.batch_sharding()

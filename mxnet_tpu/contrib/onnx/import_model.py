@@ -307,7 +307,7 @@ def _range(node, ctx, S):
     start = ctx.const_array(node["inputs"][0])
     delta = ctx.const_array(node["inputs"][2])
     # .reshape(()).item(): int() on an ndim>0 size-1 array is a NumPy
-    # deprecation (VERDICT r4 weak #5)
+    # deprecation
     return S._dynamic_arange(ctx.get(node["inputs"][1]),
                              start=int(np.asarray(start).reshape(()).item()),
                              delta=int(np.asarray(delta).reshape(()).item()),

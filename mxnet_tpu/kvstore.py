@@ -846,7 +846,7 @@ class KVStore:
     def _process_sum_impl(self, a):
         import numpy as _np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from .jax_compat import shard_map
+        from jax import shard_map
         devs = _np.asarray(jax.devices())
         mesh = Mesh(devs, ("dp",))
         ldc = jax.local_device_count()
@@ -1017,7 +1017,7 @@ class KVStore:
 
     def _psum_stacked(self, a, axis):
         from jax.sharding import PartitionSpec as P
-        from .jax_compat import shard_map
+        from jax import shard_map
         mesh = self._mesh
         n = mesh.shape[axis]
         if a.ndim == 0 or a.shape[0] % n:
@@ -1042,7 +1042,7 @@ class KVStore:
         actually cross the interconnect). Call with (stacked, residual)
         full-shape arrays or pass to jax.make_jaxpr."""
         from jax.sharding import PartitionSpec as P
-        from .jax_compat import shard_map
+        from jax import shard_map
         axis = axis or self._mesh.axis_names[0]
         n = self._mesh.shape[axis]
         wire = self._make_wire_fn(a.shape[1:], a.dtype, axis)
@@ -1112,7 +1112,7 @@ class KVStore:
 
     def _compressed_psum_stacked(self, a, axis, key):
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from .jax_compat import shard_map
+        from jax import shard_map
         mesh = self._mesh
         n = mesh.shape[axis]
         if a.ndim == 0 or a.shape[0] % n:

@@ -65,7 +65,7 @@ from .metrics_registry import registry as _registry
 
 __all__ = ["instrument", "InstrumentedJit", "inspect_hlo_text",
            "analyze_jit", "analyze_compiled", "set_compilation_cache",
-           "compilation_cache_dir", "compile_cache_stats", "executables",
+           "entry_compilation_cache", "compilation_cache_dir", "compile_cache_stats", "executables",
            "instrumented", "COLLECTIVE_OPS", "set_dispatch_hook",
            "dispatch_hook"]
 
@@ -399,6 +399,11 @@ def set_compilation_cache(path, min_compile_seconds=0.0):
     is the write threshold (0 caches everything — CPU-mesh compiles are
     fast but still worth skipping in a fleet cold start).
 
+    A cache placed from OUTSIDE wins: with `JAX_COMPILATION_CACHE_DIR`
+    set, jax already uses that directory (the path is part of what makes
+    a later run find the entries again), so `path` is ignored in its
+    favour and only the write thresholds are applied.
+
     Exported as `mx.set_compilation_cache`; `MXTPU_COMPILE_CACHE=dir`
     applies it at import time. Cache outcomes land on
     `compile_cache_hits` / `compile_cache_misses` (`compile_cache_stats()`).
@@ -406,24 +411,27 @@ def set_compilation_cache(path, min_compile_seconds=0.0):
     if path is None:
         jax.config.update("jax_compilation_cache_dir", None)
         return None
-    path = os.fspath(path)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.fspath(path)
     os.makedirs(path, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_seconds))
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass                      # knob absent on older jax: defaults apply
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
+
+
+def entry_compilation_cache(checkout):
+    """The cache rule of the entry scripts (`chip_smoke.py`, `bench*.py`):
+    the directory `JAX_COMPILATION_CACHE_DIR` names when it is set, else
+    the fixed `<checkout>/.jax_cache` — never a temporary, pid- or
+    time-derived path, which no later run could hit. A plain
+    `import mxnet_tpu` turns no disk cache on; the entry scripts do."""
+    return set_compilation_cache(os.path.join(checkout, ".jax_cache"))
 
 
 def compilation_cache_dir():
     """The active persistent-cache directory, or None when disabled."""
-    try:
-        return jax.config.jax_compilation_cache_dir
-    except Exception:
-        return None
+    return jax.config.jax_compilation_cache_dir
 
 
 def compile_cache_stats():
@@ -436,7 +444,4 @@ def compile_cache_stats():
 # the disk cache with no code change (the fleet cold-start path)
 _env_dir = os.environ.get("MXTPU_COMPILE_CACHE")
 if _env_dir:
-    try:
-        set_compilation_cache(_env_dir)
-    except Exception:             # unwritable dir etc. — never break import
-        pass
+    set_compilation_cache(_env_dir)

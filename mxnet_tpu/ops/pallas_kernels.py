@@ -15,47 +15,39 @@ Kernels:
     cotangent folds into the Pallas backward as a delta shift).
   * fused_layer_norm — single-pass layernorm.
 
-All kernels fall back to pure-XLA implementations off-TPU (CPU test mesh) or
-for shapes that don't tile (seq not multiple of block after padding). Set
-MXTPU_PALLAS_INTERPRET=1 to run the kernels in Pallas interpret mode on CPU
-(used by tests to pin the kernel numerics without a chip).
+The pure-XLA implementations are chosen off-TPU (CPU test mesh) and for
+shapes that don't tile (seq not multiple of block after padding) — by what
+the call can observe, never by catching an exception: a kernel the chip's
+compiler refuses raises. Set MXTPU_PALLAS_INTERPRET=1 to run the kernels in
+Pallas interpret mode on CPU (used by tests to pin the kernel numerics
+without a chip).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from .. import _env
 from ..observability.metrics_registry import registry as _metrics_registry
 from ..tune import overrides as _tune_overrides
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-    # older jax spells it TPUCompilerParams; module-local alias keeps the
-    # call sites on the current spelling without mutating jax's namespace
-    _CompilerParams = getattr(pltpu, "CompilerParams",
-                              getattr(pltpu, "TPUCompilerParams", None))
-    if _CompilerParams is None:  # pallas too old for either spelling:
-        _HAS_PALLAS = False      # route to the non-pallas fallback
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
-
 __all__ = ["flash_attention", "flash_block_attention", "fused_layer_norm",
            "attention_reference", "on_tpu", "conv1x1_bn_stats",
-           "single_query_cached_attention", "ragged_paged_attention"]
+           "single_query_cached_attention", "ragged_paged_attention",
+           "kernel_mesh"]
 
 
 def on_tpu():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _interpret():
@@ -67,7 +59,7 @@ def _interpret():
 def _pallas_ok(seq_len):
     if os.environ.get("MXTPU_PALLAS_DISABLE") == "1":  # A/B vs XLA path
         return False
-    return (_HAS_PALLAS and (on_tpu() or _interpret())
+    return ((on_tpu() or _interpret())
             and seq_len % 128 == 0 and seq_len >= 128)
 
 
@@ -162,7 +154,7 @@ def _rpa_sublanes(W):
 def _sds(shape, dtype, *refs):
     """ShapeDtypeStruct whose vma is the union of the inputs' varying axes —
     under shard_map(check_vma=True) pallas_call out_shapes must carry vma
-    or lowering refuses (and the try/except would silently fall back)."""
+    or lowering refuses."""
     vma = None
     try:
         sets = [jax.typeof(r).vma for r in refs]
@@ -174,17 +166,48 @@ def _sds(shape, dtype, *refs):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-_warned_fallback = set()
+# ---------------------------------------------------------------------------
+# kernels inside a GSPMD-partitioned program
+# ---------------------------------------------------------------------------
+_mesh_scope = threading.local()
 
 
-def _warn_fallback(site, err):
-    """The Pallas path raising and silently taking the XLA path cost a 10%
-    bench regression once (r2); surface it loudly, once per site."""
-    if site not in _warned_fallback:
-        _warned_fallback.add(site)
-        import warnings
-        warnings.warn(f"pallas {site} kernel failed, using XLA fallback: "
-                      f"{err!r}", RuntimeWarning, stacklevel=3)
+@contextlib.contextmanager
+def kernel_mesh(mesh, batch_axis, head_axis=None):
+    """Partition the Pallas calls traced inside this scope over `mesh`.
+
+    The partitioner cannot split a Mosaic kernel ("Mosaic kernels cannot
+    be automatically partitioned"), so a program that is one GSPMD `jit`
+    over NamedShardings — the rule-sharded captured step (cachedop.py)
+    holds this scope while it traces — runs each kernel per shard through
+    `shard_map`: the batch dim over `batch_axis`, the heads dim over
+    `head_axis`. Attention and layernorm are independent per (batch,
+    head) / per row, so any such split is exact; a dim its axis does not
+    divide stays whole on every device."""
+    prev = getattr(_mesh_scope, "value", None)
+    _mesh_scope.value = (mesh, batch_axis, head_axis)
+    try:
+        yield
+    finally:
+        _mesh_scope.value = prev
+
+
+def _over_mesh(fn, args, arg_lead, out_lead):
+    """`fn(*args)`, per shard under an active `kernel_mesh` scope.
+    arg_lead / out_lead: for each array, how many of its leading dims are
+    (batch, heads) — 2, 1 (batch only) or 0 (whole on every device);
+    args[0] carries the most."""
+    scope = getattr(_mesh_scope, "value", None)
+    if scope is None:
+        return fn(*args)
+    mesh, b_ax, h_ax = scope
+    axes = [ax if ax is not None and dim % mesh.shape[ax] == 0 else None
+            for ax, dim in zip((b_ax, h_ax), args[0].shape)]
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(P(*axes[:n]) for n in arg_lead),
+        out_specs=tuple(P(*axes[:n]) for n in out_lead),
+        check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -295,18 +318,25 @@ def _flash_fwd_kernel(*refs, sm_scale, causal, block_q, block_k,
         lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
 
 
-def _flash_fwd_pallas(q, k, v, causal, sm_scale, lengths=None,
-                      block_q=None, block_k=None):
+def _flash_fwd_pallas(q, k, v, causal, sm_scale, lengths=None):
     """Returns (out, lse); lse is the per-row logsumexp of the scaled
-    logits, shape (B*H, S, 128) fp32 with the value broadcast across the
-    minor (lane) dim — the backward kernels' softmax residual.
+    logits, (B, H, Sq) fp32 — the backward kernels' softmax residual.
     lengths: optional (B,) int32 kv valid lengths (padding mask).
     Sq and Sk may differ (cross-attention); causal requires Sq == Sk."""
+    block_q, block_k = _block_sizes(q.shape[2], k.shape[2])
+    local = functools.partial(_flash_fwd_local, causal=causal,
+                              sm_scale=sm_scale, block_q=block_q,
+                              block_k=block_k)
+    if lengths is None:
+        return _over_mesh(local, (q, k, v), (2, 2, 2), (2, 2))
+    return _over_mesh(local, (q, k, v, lengths), (2, 2, 2, 1), (2, 2))
+
+
+def _flash_fwd_local(q, k, v, lengths=None, *, causal, sm_scale, block_q,
+                     block_k):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bh = b * h
-    if block_q is None or block_k is None:
-        block_q, block_k = _block_sizes(sq, sk)
     qr = q.reshape(bh, sq, d)
     kr = k.reshape(bh, sk, d)
     vr = v.reshape(bh, sk, d)
@@ -341,15 +371,18 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, lengths=None,
             _sds((bh, sq, d), q.dtype, q, k, v),
             _sds((bh, sq, 128), jnp.float32, q, k, v),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="mxtpu_flash_fwd",
     )
     if has_lengths:
         out, lse = call(lengths.astype(jnp.int32), qr, kr, vr)
     else:
         out, lse = call(qr, kr, vr)
-    return out.reshape(b, h, sq, d), lse
+    # the kernel writes lse broadcast across the 128-lane minor dim; keep
+    # it compact (128x less HBM held from forward to backward)
+    return out.reshape(b, h, sq, d), lse[..., 0].reshape(b, h, sq)
 
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lengths=None):
@@ -387,11 +420,8 @@ def _flash_vl_impl(q, k, v, lengths, causal, sm_scale):
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     if _pallas_ok(q.shape[2]) and _pallas_ok(k.shape[2]):
-        try:
-            return _flash_fwd_pallas(q, k, v, causal, sm_scale,
-                                     lengths=lengths)[0]
-        except Exception as e:
-            _warn_fallback("flash_fwd_vl", e)
+        return _flash_fwd_pallas(q, k, v, causal, sm_scale,
+                                 lengths=lengths)[0]
     return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                                mask=_lengths_mask(lengths, k.shape[2]))
 
@@ -400,10 +430,7 @@ def _flash_attention_impl(q, k, v, causal, sm_scale):
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     if _pallas_ok(q.shape[2]) and _pallas_ok(k.shape[2]):
-        try:
-            return _flash_fwd_pallas(q, k, v, causal, sm_scale)[0]
-        except Exception as e:
-            _warn_fallback("flash_fwd", e)
+        return _flash_fwd_pallas(q, k, v, causal, sm_scale)[0]
     return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale)
 
 
@@ -544,15 +571,28 @@ def _flash_bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k,
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale, lengths=None,
-                      block_q=None, block_k=None, delta_shift=None):
-    """delta_shift (B,H,Sq) fp32, optional: subtracted from the standard
-    delta = rowsum(dO∘O). Used by flash_block_attention to fold an lse
-    cotangent into the backward (dS gains +g_lse∘p, i.e. delta -= g_lse)."""
+                      delta_shift=None):
+    """lse: the forward's (B,H,Sq) residual. delta_shift (B,H,Sq) fp32,
+    optional: subtracted from the standard delta = rowsum(dO∘O). Used by
+    flash_block_attention to fold an lse cotangent into the backward (dS
+    gains +g_lse∘p, i.e. delta -= g_lse)."""
+    block_q, block_k = _block_sizes(q.shape[2], k.shape[2])
+    if delta_shift is None:
+        delta_shift = jnp.zeros(lse.shape, jnp.float32)
+    local = functools.partial(_flash_bwd_local, causal=causal,
+                              sm_scale=sm_scale, block_q=block_q,
+                              block_k=block_k)
+    args, lead = (q, k, v, o, lse, g, delta_shift), (2,) * 7
+    if lengths is not None:
+        args, lead = args + (lengths,), lead + (1,)
+    return _over_mesh(local, args, lead, (2, 2, 2))
+
+
+def _flash_bwd_local(q, k, v, o, lse, g, delta_shift, lengths=None, *,
+                     causal, sm_scale, block_q, block_k):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bh = b * h
-    if block_q is None or block_k is None:
-        block_q, block_k = _block_sizes(sq, sk)
     qr = q.reshape(bh, sq, d)
     kr = k.reshape(bh, sk, d)
     vr = v.reshape(bh, sk, d)
@@ -560,12 +600,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale, lengths=None,
     # delta_i = rowsum(dO ∘ O): the softmax-jacobian correction term; cheap
     # elementwise+reduce, left to XLA. Lane-broadcast to 128 like lse so the
     # block shape is Mosaic-tileable.
-    delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32),
-                    axis=-1).reshape(bh, sq)
-    if delta_shift is not None:
-        delta = delta - delta_shift.astype(jnp.float32).reshape(bh, sq)
+    delta = (jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
+             - delta_shift.astype(jnp.float32)).reshape(bh, sq)
     delta = jnp.broadcast_to(delta[..., None], (bh, sq, 128))
-    lse = jnp.broadcast_to(lse[..., None], (bh, sq, 128))  # compact residual
+    lse = jnp.broadcast_to(lse.reshape(bh, sq)[..., None], (bh, sq, 128))
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
     has_lengths = lengths is not None
@@ -588,9 +626,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale, lengths=None,
                             pltpu.VMEM((block_k, d), jnp.float32)],
         ),
         out_shape=[_sds((bh, sk, d), q.dtype, q, k, v, g)] * 2,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="mxtpu_flash_bwd_dkv",
     )(*scal, qr, kr, vr, gr, lse, delta)
 
     qspec2 = pl.BlockSpec((1, block_q, d), lambda b_, i, j, *_: (b_, i, 0))
@@ -608,9 +647,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale, lengths=None,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         ),
         out_shape=_sds((bh, sq, d), q.dtype, q, k, v, g),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="mxtpu_flash_bwd_dq",
     )(*scal, qr, kr, vr, gr, lse, delta)
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))
@@ -619,13 +659,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale, lengths=None,
 def _flash_fwd_rule(q, k, v, causal, sm_scale):
     scale = sm_scale if sm_scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     if _pallas_ok(q.shape[2]) and _pallas_ok(k.shape[2]):
-        try:
-            out, lse = _flash_fwd_pallas(q, k, v, causal, scale)
-            # residual kept compact: (bh, sq), not the lane-broadcast
-            # (bh, sq, 128) the kernel writes (128x the HBM held fwd->bwd)
-            return out, (q, k, v, out, lse[..., 0])
-        except Exception as e:
-            _warn_fallback("flash_fwd", e)
+        out, lse = _flash_fwd_pallas(q, k, v, causal, scale)
+        return out, (q, k, v, out, lse)
     out = attention_reference(q, k, v, causal=causal, sm_scale=scale)
     return out, (q, k, v, None, None)
 
@@ -634,11 +669,8 @@ def _flash_bwd_rule(causal, sm_scale, res, g):
     q, k, v, o, lse = res
     scale = sm_scale if sm_scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     if o is not None and _pallas_ok(q.shape[2]):
-        try:
-            return _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale)
-        except Exception as e:
-            _warn_fallback("flash_bwd", e)
-    # fallback: recompute-backward through the XLA reference
+        return _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale)
+    # forward took the XLA path: recompute-backward through the reference
     _, vjp = jax.vjp(
         lambda q_, k_, v_: attention_reference(q_, k_, v_, causal=causal,
                                                sm_scale=scale), q, k, v)
@@ -651,12 +683,9 @@ _flash_plain.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def _flash_vl_fwd_rule(q, k, v, lengths, causal, sm_scale):
     scale = sm_scale if sm_scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     if _pallas_ok(q.shape[2]) and _pallas_ok(k.shape[2]):
-        try:
-            out, lse = _flash_fwd_pallas(q, k, v, causal, scale,
-                                         lengths=lengths)
-            return out, (q, k, v, lengths, out, lse[..., 0])
-        except Exception as e:
-            _warn_fallback("flash_fwd_vl", e)
+        out, lse = _flash_fwd_pallas(q, k, v, causal, scale,
+                                     lengths=lengths)
+        return out, (q, k, v, lengths, out, lse)
     out = attention_reference(q, k, v, causal=causal, sm_scale=scale,
                               mask=_lengths_mask(lengths, k.shape[2]))
     return out, (q, k, v, lengths, None, None)
@@ -668,12 +697,9 @@ def _flash_vl_bwd_rule(causal, sm_scale, res, g):
     scale = sm_scale if sm_scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     dlen = np.zeros(lengths.shape, dtype=jax.dtypes.float0)
     if o is not None and _pallas_ok(q.shape[2]):
-        try:
-            dq, dk, dv = _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale,
-                                           lengths=lengths)
-            return dq, dk, dv, dlen
-        except Exception as e:
-            _warn_fallback("flash_bwd_vl", e)
+        dq, dk, dv = _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale,
+                                       lengths=lengths)
+        return dq, dk, dv, dlen
     _, vjp = jax.vjp(
         lambda q_, k_, v_: attention_reference(
             q_, k_, v_, causal=causal, sm_scale=scale,
@@ -727,12 +753,8 @@ def _flash_block_impl(q, k, v, causal, sm_scale):
     """Shared primal: (out, lse, used_pallas)."""
     scale = sm_scale if sm_scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     if _pallas_ok(q.shape[2]) and _pallas_ok(k.shape[2]):
-        try:
-            out, lse = _flash_fwd_pallas(q, k, v, causal, scale)
-            b, h, s, _ = q.shape
-            return out, lse[..., 0].reshape(b, h, s), True
-        except Exception as e:
-            _warn_fallback("flash_block_fwd", e)
+        out, lse = _flash_fwd_pallas(q, k, v, causal, scale)
+        return out, lse, True
     out, lse = _block_fwd_xla(q, k, v, causal, scale)
     return out, lse, False
 
@@ -760,12 +782,8 @@ def _flash_block_bwd_rule(causal, sm_scale, res, cts):
     g, g_lse = cts
     scale = sm_scale if sm_scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     if used_pallas:
-        try:
-            return _flash_bwd_pallas(
-                q, k, v, out, lse.reshape(-1, lse.shape[-1]), g, causal,
-                scale, delta_shift=g_lse)
-        except Exception as e:
-            _warn_fallback("flash_block_bwd", e)
+        return _flash_bwd_pallas(q, k, v, out, lse, g, causal, scale,
+                                 delta_shift=g_lse)
     return _block_bwd_xla(q, k, v, out, lse, g, g_lse, causal, scale)
 
 
@@ -869,7 +887,8 @@ def _paged_attention_lax_multi(q, k_pages, v_pages, page_tables, lengths,
     return out.transpose(0, 2, 1, 3)
 
 
-def _rpa_kernel(*refs, psize, block_k, num_heads, sm_scale, quant=False):
+def _rpa_kernel(*refs, psize, block_k, num_heads, window, sm_scale,
+                quant=False):
     """Ragged paged attention, one (slot, head) per grid row, one
     (block_k, dh) KV tile per inner step — `psize // block_k` steps per
     page (block_k == psize is the one-page-per-step default; the
@@ -878,148 +897,20 @@ def _rpa_kernel(*refs, psize, block_k, num_heads, sm_scale, quant=False):
     (scalar prefetch); here we only need the slot's valid length for
     masking and dead-page skipping.
 
-    quant (ISSUE 14): the page pools are int8 and two extra scalar-
-    prefetch refs carry the per-page/per-head dequant scales as BITCAST
-    int32 (scalar prefetch is SMEM/int territory; `bitcast_convert_type`
-    recovers the f32 in-kernel) — the page block dequantizes in VMEM
-    right after the DMA, so HBM only ever moves int8 bytes."""
-    if quant:
-        (pt_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-    else:
-        ks_ref = vs_ref = None
-        (pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-    npb = psize // block_k                  # sub-page blocks per page
-    g = pl.program_id(0)                    # slot * num_heads + head
-    j = pl.program_id(1)                    # page slot * npb + block
-    nj = pl.num_programs(1)
-    s_idx = g // num_heads
-    length = len_ref[s_idx]
-    k_start = j * block_k
-    if quant:
-        page = pt_ref[s_idx, j // npb]
-        h_idx = g % num_heads
-        ks = lax.bitcast_convert_type(ks_ref[h_idx, page], jnp.float32)
-        vs = lax.bitcast_convert_type(vs_ref[h_idx, page], jnp.float32)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, -1e30)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    # blocks entirely beyond the valid length are skipped — the ragged
-    # part: a 3-token request costs one block of work while its
-    # 300-token neighbour walks its whole table, in the same launch
-    @pl.when(k_start < length)
-    def _compute():
-        q = q_ref[0]                        # (1, dh)
-        k = k_ref[0, 0]                     # (block_k, dh)
-        v = v_ref[0, 0]                     # (block_k, dh)
-        if quant:
-            # dequantize in VMEM, same element-wise form as the lax
-            # fallback's gathered dequant (parity pinned in interpret)
-            k = k.astype(jnp.float32) * ks
-            v = v.astype(jnp.float32) * vs
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        kj = k_start + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-        s = jnp.where(kj < length, s, -1e30)
-        m_prev = m_scr[:1, :1]              # (1, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)              # (1, psize) fp32
-        l_new = alpha * l_scr[:1, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc_scr[:1] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # (8, x) scratch: every row carries the running value (a (1, x)
-        # block would violate Mosaic's (8, 128) min tile); row 0 is read
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-        acc_scr[:] = jnp.broadcast_to(acc, acc_scr.shape)
-
-    @pl.when(j == nj - 1)
-    def _finalize():
-        # a slot with length 0 (empty decode slot) has l == 0: guard the
-        # divide; its output is garbage the scheduler never reads
-        o_ref[0] = (acc_scr[:1] /
-                    jnp.maximum(l_scr[:1, :1], 1e-30)).astype(o_ref.dtype)
-
-
-def _scale_bits(scales):
-    """(P, H) f32 scales -> (H, P) int32 bitcast for scalar prefetch
-    (SMEM carries ints; the kernel bitcasts the f32 back)."""
-    return lax.bitcast_convert_type(
-        scales.astype(jnp.float32).T, jnp.int32)
-
-
-def _rpa_pallas(q, k_pages, v_pages, page_tables, lengths, sm_scale,
-                k_scales=None, v_scales=None):
-    S, H, dh = q.shape
-    psize = k_pages.shape[1]
-    npages = page_tables.shape[1]
-    quant = k_scales is not None
-    bk = _rpa_block_k(psize)
-    npb = psize // bk               # sub-page K blocks per page
-    qr = q.reshape(S * H, 1, dh)
-    # page-major layout for the kernel: (H, P, psize, dh) so one (slot,
-    # head, page) block is a contiguous (psize, dh) tile
-    kr = k_pages.transpose(2, 0, 1, 3)
-    vr = v_pages.transpose(2, 0, 1, 3)
-    grid = (S * H, npages * npb)
-    kern = functools.partial(_rpa_kernel, psize=psize, block_k=bk,
-                             num_heads=H, sm_scale=sm_scale, quant=quant)
-    nsp = 4 if quant else 2
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=nsp,        # page tables + lengths (+ scales)
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, dh), lambda g, j, pt, ln, *_: (g, 0, 0)),
-            # the paged gather: the page id comes from the scalar-
-            # prefetched table, so the DMA fetches exactly the pages the
-            # slot owns — never a dense (S, Lmax) context; with bk <
-            # psize the dim-2 block index walks the npb tiles of a page
-            pl.BlockSpec((1, 1, bk, dh),
-                         lambda g, j, pt, ln, *_, _h=H, _b=npb:
-                         (g % _h, pt[g // _h, j // _b], j % _b, 0)),
-            pl.BlockSpec((1, 1, bk, dh),
-                         lambda g, j, pt, ln, *_, _h=H, _b=npb:
-                         (g % _h, pt[g // _h, j // _b], j % _b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, dh),
-                               lambda g, j, pt, ln, *_: (g, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((8, 128), jnp.float32),
-            pltpu.VMEM((8, 128), jnp.float32),
-            pltpu.VMEM((8, dh), jnp.float32),
-        ],
-    )
-    scal = (page_tables.astype(jnp.int32), lengths.astype(jnp.int32))
-    if quant:
-        scal += (_scale_bits(k_scales), _scale_bits(v_scales))
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=_sds((S * H, 1, dh), q.dtype, q, k_pages, v_pages),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(*scal, qr, kr, vr)
-    return out.reshape(S, H, dh)
-
-
-def _rpa_multi_kernel(*refs, psize, block_k, num_heads, sm_scale,
-                      quant=False):
-    """Widened ragged paged attention (ISSUE 12): W query rows per
-    (slot, head) grid row, one KV page per inner step. Query row i masks
-    keys at `len_ref[slot] + i` — consecutive positions, so a single
-    per-slot scalar carries the whole ragged query-length structure.
+    `window` (ISSUE 12) real query rows per slot, padded to the
+    8-sublane tile: query row i masks keys at `len_ref[slot] + i` —
+    consecutive positions, so a single per-slot scalar carries the whole
+    ragged query-length structure. The one-token decode turn is
+    window == 1 of the same kernel: a (1, dh) query block with a (1, 1)
+    running max does not lower on the chip (Mosaic has no broadcast
+    over sublanes and lanes at once), so every form keeps its running
+    max/sum as lane-replicated (rows, 128) like the flash kernels.
     Rows beyond a slot's real window produce garbage nobody commits.
-    quant: int8 page pools with bitcast-int32 scalar-prefetch scales,
-    dequantized in VMEM (same scheme as `_rpa_kernel`)."""
+
+    quant (ISSUE 14): the page pools are int8 and two extra scalar-
+    prefetch refs carry the per-page/per-head f32 dequant scales — the
+    page block dequantizes in VMEM right after the DMA, so HBM only ever
+    moves int8 bytes."""
     if quant:
         (pt_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref,
          m_scr, l_scr, acc_scr) = refs
@@ -1038,8 +929,8 @@ def _rpa_multi_kernel(*refs, psize, block_k, num_heads, sm_scale,
     if quant:
         page = pt_ref[s_idx, j // npb]
         h_idx = g % num_heads
-        ks = lax.bitcast_convert_type(ks_ref[h_idx, page], jnp.float32)
-        vs = lax.bitcast_convert_type(vs_ref[h_idx, page], jnp.float32)
+        ks = ks_ref[h_idx, page]
+        vs = vs_ref[h_idx, page]
 
     @pl.when(j == 0)
     def _init():
@@ -1047,14 +938,17 @@ def _rpa_multi_kernel(*refs, psize, block_k, num_heads, sm_scale,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # a block is live when ANY query row can see it: row wp-1 sees
-    # length + wp - 1 keys
-    @pl.when(k_start < length + wp - 1)
+    # blocks beyond what the LAST real query row sees are skipped — the
+    # ragged part: a 3-token request costs one block of work while its
+    # 300-token neighbour walks its whole table, in the same launch
+    @pl.when(k_start < length + window - 1)
     def _compute():
         q = q_ref[0]                        # (wp, dh)
         k = k_ref[0, 0]                     # (block_k, dh)
         v = v_ref[0, 0]                     # (block_k, dh)
         if quant:
+            # dequantize in VMEM, same element-wise form as the lax
+            # fallback's gathered dequant (parity pinned in interpret)
             k = k.astype(jnp.float32) * ks
             v = v.astype(jnp.float32) * vs
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -1076,12 +970,15 @@ def _rpa_multi_kernel(*refs, psize, block_k, num_heads, sm_scale,
 
     @pl.when(j == nj - 1)
     def _finalize():
+        # a slot with length 0 (empty decode slot) has l == 0: guard the
+        # divide; its output is garbage the scheduler never reads
         o_ref[0] = (acc_scr[:] /
                     jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
 
 
-def _rpa_multi_pallas(q, k_pages, v_pages, page_tables, lengths, sm_scale,
-                      k_scales=None, v_scales=None):
+def _rpa_pallas(q, k_pages, v_pages, page_tables, lengths, sm_scale,
+                k_scales=None, v_scales=None):
+    """q: (S, W, H, dh); returns (S, W, H, dh)."""
     S, W, H, dh = q.shape
     psize = k_pages.shape[1]
     npages = page_tables.shape[1]
@@ -1089,23 +986,29 @@ def _rpa_multi_pallas(q, k_pages, v_pages, page_tables, lengths, sm_scale,
     bk = _rpa_block_k(psize)
     npb = psize // bk               # sub-page K blocks per page
     # pad the query-row dim to the Mosaic 8-sublane tile (or the forced
-    # tuner sublane count); extra rows attend a few more (valid-page)
-    # keys and are sliced away below
+    # tuner sublane count); the extra rows are sliced away below
     wp = _rpa_sublanes(W)
     qr = q.transpose(0, 2, 1, 3).reshape(S * H, W, dh)
     if wp != W:
         qr = jnp.pad(qr, ((0, 0), (0, wp - W), (0, 0)))
-    kr = k_pages.transpose(2, 0, 1, 3)      # (H, P, psize, dh)
+    # page-major layout for the kernel: (H, P, psize, dh) so one (slot,
+    # head, page) block is a contiguous (psize, dh) tile
+    kr = k_pages.transpose(2, 0, 1, 3)
     vr = v_pages.transpose(2, 0, 1, 3)
     grid = (S * H, npages * npb)
-    kern = functools.partial(_rpa_multi_kernel, psize=psize, block_k=bk,
-                             num_heads=H, sm_scale=sm_scale, quant=quant)
+    kern = functools.partial(_rpa_kernel, psize=psize, block_k=bk,
+                             num_heads=H, window=W, sm_scale=sm_scale,
+                             quant=quant)
     nsp = 4 if quant else 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=nsp,        # page tables + lengths (+ scales)
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, wp, dh), lambda g, j, pt, ln, *_: (g, 0, 0)),
+            # the paged gather: the page id comes from the scalar-
+            # prefetched table, so the DMA fetches exactly the pages the
+            # slot owns — never a dense (S, Lmax) context; with bk <
+            # psize the dim-2 block index walks the npb tiles of a page
             pl.BlockSpec((1, 1, bk, dh),
                          lambda g, j, pt, ln, *_, _h=H, _b=npb:
                          (g % _h, pt[g // _h, j // _b], j % _b, 0)),
@@ -1123,14 +1026,17 @@ def _rpa_multi_pallas(q, k_pages, v_pages, page_tables, lengths, sm_scale,
     )
     scal = (page_tables.astype(jnp.int32), lengths.astype(jnp.int32))
     if quant:
-        scal += (_scale_bits(k_scales), _scale_bits(v_scales))
+        # (H, P) f32 in SMEM: the kernel reads one scalar per grid step
+        scal += (k_scales.astype(jnp.float32).T,
+                 v_scales.astype(jnp.float32).T)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=_sds((S * H, wp, dh), q.dtype, q, k_pages, v_pages),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="mxtpu_rpa",
     )(*scal, qr, kr, vr)
     return out[:, :W].reshape(S, H, W, dh).transpose(0, 2, 1, 3)
 
@@ -1138,7 +1044,7 @@ def _rpa_multi_pallas(q, k_pages, v_pages, page_tables, lengths, sm_scale,
 def _rpa_pallas_ok(psize):
     if os.environ.get("MXTPU_PALLAS_DISABLE") == "1":
         return False
-    return (_HAS_PALLAS and (on_tpu() or _interpret())
+    return ((on_tpu() or _interpret())
             and psize % 8 == 0 and psize >= 8)
 
 
@@ -1159,7 +1065,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lengths,
 
     k_scales/v_scales (ISSUE 14): per-page/per-head (P, H) f32 dequant
     scales for int8 page pools. The Pallas kernels carry them through
-    scalar prefetch (bitcast int32) and dequantize each page block in
+    scalar prefetch (f32 in SMEM) and dequantize each page block in
     VMEM after the DMA — HBM traffic stays int8, the dequant rides free
     inside the kernel; the lax fallback dequantizes only the GATHERED
     context.
@@ -1180,27 +1086,16 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lengths,
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be given together")
-    if q.ndim == 4:
-        if _rpa_pallas_ok(k_pages.shape[1]):
-            try:
-                return _rpa_multi_pallas(q, k_pages, v_pages, page_tables,
-                                         lengths, sm_scale,
-                                         k_scales=k_scales,
-                                         v_scales=v_scales)
-            except Exception as e:
-                _warn_fallback("ragged_paged_multi", e)
-        return _paged_attention_lax_multi(q, k_pages, v_pages, page_tables,
-                                          lengths, k_scales=k_scales,
-                                          v_scales=v_scales)
-    if _rpa_pallas_ok(k_pages.shape[1]):
-        try:
-            return _rpa_pallas(q, k_pages, v_pages, page_tables, lengths,
-                               sm_scale, k_scales=k_scales,
-                               v_scales=v_scales)
-        except Exception as e:
-            _warn_fallback("ragged_paged", e)
-    return _paged_attention_lax(q, k_pages, v_pages, page_tables, lengths,
-                                k_scales=k_scales, v_scales=v_scales)
+    if not _rpa_pallas_ok(k_pages.shape[1]):
+        lax_fn = (_paged_attention_lax_multi if q.ndim == 4
+                  else _paged_attention_lax)
+        return lax_fn(q, k_pages, v_pages, page_tables, lengths,
+                      k_scales=k_scales, v_scales=v_scales)
+    # the one-token decode turn is the W == 1 window of the same kernel
+    out = _rpa_pallas(q if q.ndim == 4 else q[:, None], k_pages, v_pages,
+                      page_tables, lengths, sm_scale,
+                      k_scales=k_scales, v_scales=v_scales)
+    return out if q.ndim == 4 else out[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -1227,17 +1122,26 @@ def _fused_ln(x, gamma, beta, eps):
 
 def _fused_ln_fwd_impl(x, gamma, beta, eps):
     d = x.shape[-1]
-    rows = 1
-    for s in x.shape[:-1]:
-        rows *= s
     lead = x.shape[:-1]
-    if (_HAS_PALLAS and (on_tpu() or _interpret()) and d % 128 == 0
+    # rows are independent: under a `kernel_mesh` scope they split over
+    # the batch axis (dim 0 of x is the batch, and stays dim-0-major here)
+    out, mean, rstd = _over_mesh(
+        functools.partial(_ln_rows, eps=eps),
+        (x.reshape(-1, d), gamma, beta), (1, 0, 0), (1, 1, 1))
+    return (out.reshape(x.shape), mean.reshape(lead + (1,)),
+            rstd.reshape(lead + (1,)))
+
+
+def _ln_rows(x2, gamma, beta, *, eps):
+    """(rows, d) layernorm -> (y, mean (rows, 1), rstd (rows, 1)): the
+    Pallas kernel where the rows and lanes tile, XLA otherwise."""
+    rows, d = x2.shape
+    if ((on_tpu() or _interpret()) and d % 128 == 0
             and rows % 8 == 0 and rows >= 8):
         br = min(256, rows)
         while rows % br:
             br //= 2
-        x2 = x.reshape(rows, d)
-        out, mean, rstd = pl.pallas_call(
+        return tuple(pl.pallas_call(
             functools.partial(_ln_kernel, eps=eps),
             grid=(rows // br,),
             in_specs=[
@@ -1251,21 +1155,20 @@ def _fused_ln_fwd_impl(x, gamma, beta, eps):
                 pl.BlockSpec((br, 1), lambda i: (i, 0)),
             ],
             out_shape=[
-                _sds((rows, d), x.dtype, x, gamma, beta),
-                _sds((rows, 1), jnp.float32, x, gamma, beta),
-                _sds((rows, 1), jnp.float32, x, gamma, beta),
+                _sds((rows, d), x2.dtype, x2, gamma, beta),
+                _sds((rows, 1), jnp.float32, x2, gamma, beta),
+                _sds((rows, 1), jnp.float32, x2, gamma, beta),
             ],
             interpret=_interpret(),
-        )(x2, gamma, beta)
-        return (out.reshape(x.shape), mean.reshape(lead + (1,)),
-                rstd.reshape(lead + (1,)))
-    xf = x.astype(jnp.float32)
+            name="mxtpu_layer_norm",
+        )(x2, gamma, beta))
+    xf = x2.astype(jnp.float32)
     mean = jnp.mean(xf, axis=-1, keepdims=True)
     xc = xf - mean
     var = jnp.mean(xc * xc, axis=-1, keepdims=True)
     rstd = lax.rsqrt(var + eps)
     y = ((xc * rstd) * gamma.astype(jnp.float32)
-         + beta.astype(jnp.float32)).astype(x.dtype)
+         + beta.astype(jnp.float32)).astype(x2.dtype)
     return y, mean, rstd
 
 
@@ -1328,10 +1231,10 @@ def conv1x1_bn_stats(x2d, w, bm=1024):
     (mean, E[y^2]) WHILE each output tile is still in VMEM — deleting
     the separate stats pass's full HBM read of y. tools/
     probe_fused_convbn.py carries the keep-or-reject timings vs XLA
-    conv + fused reduce (docs/PERF.md); numerics pinned in
+    conv + fused reduce; numerics pinned in
     tests/test_pallas.py. Returns (y (M, N) in x's dtype, mean (N,) f32,
     meansq (N,) f32)."""
-    if not (_HAS_PALLAS and (on_tpu() or _interpret())):
+    if not (on_tpu() or _interpret()):
         # match the kernel's numerics: fp32 accumulate + fp32 stats,
         # THEN cast y — bf16-rounded stats would diverge from the TPU
         # path (and meansq - mean^2 could even go slightly negative)
@@ -1355,6 +1258,7 @@ def conv1x1_bn_stats(x2d, w, bm=1024):
                    jax.ShapeDtypeStruct((8, n), jnp.float32),
                    jax.ShapeDtypeStruct((8, n), jnp.float32)],
         interpret=_interpret(),
+        name="mxtpu_conv1x1_bn_stats",
     )(xp, w)
     inv = 1.0 / m
     return y[:m], s[0] * inv, q[0] * inv

@@ -42,7 +42,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .ring_attention import ring_attention

@@ -14,7 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from ..jax_compat import shard_map
+from jax import shard_map
 
 __all__ = ["pipeline_apply", "stack_stage_params"]
 

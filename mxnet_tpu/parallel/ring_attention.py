@@ -27,8 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ..jax_compat import shard_map
-from ..jax_compat import axis_size as _axis_size
+from jax import shard_map
+from jax.lax import axis_size as _axis_size
 
 from ..ops.pallas_kernels import flash_block_attention
 
@@ -39,8 +39,8 @@ def _as_varying(x, axis_name):
     """lax.pcast(x, axis, to='varying') where available; no-op off
     shard_map. NOTE: pcast takes axis_name positionally — the kwarg
     spelling used through round 4 raised TypeError on every call and
-    silently fell through to the deprecated `pvary` (VERDICT r4 weak
-    #5), which is why the suite carried a DeprecationWarning."""
+    silently fell through to the deprecated `pvary`,
+    which is why the suite carried a DeprecationWarning."""
     try:
         from jax.lax import pcast
         return pcast(x, axis_name, to="varying")

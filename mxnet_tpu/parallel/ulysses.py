@@ -27,8 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..jax_compat import shard_map
-from ..jax_compat import axis_size as _axis_size
+from jax import shard_map
+from jax.lax import axis_size as _axis_size
 from jax.sharding import PartitionSpec as P
 
 from ..ops.pallas_kernels import flash_block_attention

@@ -57,7 +57,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..base import MXNetError
-from ..jax_compat import shard_map
+from jax import shard_map
 from .exchange import (exchange, local_offsets,  # noqa: F401  (re-export)
                        plan_buckets)
 

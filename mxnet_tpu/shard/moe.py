@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..jax_compat import shard_map
+from jax import shard_map
 from .exchange import exchange, group_ranks
 
 __all__ = ["A2A_PER_LAYER", "STEP_TRAVERSALS", "capacity",

@@ -1,6 +1,6 @@
 """Ordered regex partition rules mapping parameter names to
 `jax.sharding.PartitionSpec` (reference idiom: fmengine-style
-`match_partition_rules`, SNIPPETS.md [1]; the paper-side motivation is
+`match_partition_rules`; the paper-side motivation is
 arXiv:2004.13336 — shard the state, not just the work).
 
 A rule set is an ordered sequence of ``(pattern, spec)`` pairs. Matching

@@ -986,8 +986,8 @@ def _dynamic_arange(limit, start=0, delta=1, name=None):
                  {"start": start, "delta": delta}, name=name)
 
 
-# -- indexing/selection mirrors of the nd surface (VERDICT-style probe
-# gaps, round 5): one_hot, topk, pick, gather_nd, slice_like,
+# -- indexing/selection mirrors of the nd surface (probe
+# gaps): one_hot, topk, pick, gather_nd, slice_like,
 # broadcast_axis, masked_softmax, SVMOutput -------------------------------
 def _one_hot_eval(idx, depth=0, on_value=1.0, off_value=0.0,
                   dtype=None):

@@ -139,7 +139,7 @@ def test_convert_block_fixes_blanket_cast(_amp_off):
 
 
 def test_trainer_skips_update_on_overflow_and_halves_scale(_amp_off):
-    """The VERDICT-mandated test: force an overflow, assert the update is
+    """Force an overflow, assert the update is
     skipped and the loss scale halves."""
     amp.init("float16")
     scaler = amp._state["scaler"]

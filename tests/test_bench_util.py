@@ -1,12 +1,13 @@
-"""bench_util protocol tests: the shared sweep (already covered in
-test_bench_supervisor.py) and the shared SGD-momentum step builder the
-four bench workers compile."""
+"""bench_util protocol tests: the shared candidate sweep and the shared
+SGD-momentum step builder the bench scripts compile."""
 import sys
 import os
 import numpy as np
+import pytest
 import jax.numpy as jnp
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import bench_util  # noqa: E402
 from bench_util import make_sgd_step  # noqa: E402
 
 
@@ -49,3 +50,38 @@ def test_make_sgd_step_unroll_equals_sequential():
     # step: 6 sequential dispatches == 2 dispatches of 3 unrolled steps
     assert aux_seq == 6.0 and aux_unr == 6.0
     np.testing.assert_allclose(l_unr, l_seq, rtol=1e-6)
+
+
+# ------------------------------------------------------------- sweep unit
+def test_sweep_skips_failures_and_reports_best():
+    seen = []
+    results = {8: 10.0, 16: RuntimeError("oom"), 32: 30.0}
+
+    def run_one(c):
+        r = results[c]
+        if isinstance(r, Exception):
+            raise r
+        return r
+    best, cand = bench_util.sweep([8, 16, 32], 1e9, run_one,
+                                  on_best=seen.append)
+    assert (best, cand) == (30.0, 32)
+    assert seen == [10.0, 30.0]       # checkpoint per improvement
+
+
+def test_sweep_budget_gates_later_candidates(monkeypatch):
+    clock = {"t": 0.0}
+    monkeypatch.setattr(bench_util.time, "monotonic",
+                        lambda: clock["t"])
+
+    def run_one(c):
+        clock["t"] += 400.0           # each candidate is slow
+        return float(c)
+    best, cand = bench_util.sweep([1, 2, 3], 300.0, run_one)
+    assert (best, cand) == (1.0, 1)   # 2 and 3 never start
+
+
+def test_sweep_raises_when_nothing_lands():
+    def always_fail(c):
+        raise ValueError("x")
+    with pytest.raises(RuntimeError, match="no sweep candidate"):
+        bench_util.sweep([1, 2], 1e9, always_fail)

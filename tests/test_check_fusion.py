@@ -77,10 +77,12 @@ def test_fusion_budgets_hold_and_control_trips():
     assert res["moe"]["aliased_inputs"] == \
         check_fusion.BUDGETS["moe_step"]["aliased_inputs"]
     # the gate provably bites: the fusion-pass-disabled control landed
-    # below the band and tripped the SAME budget table
+    # outside the band and tripped the SAME budget table (the installed
+    # XLA leaves each un-fused op in a fusion of its own, so the count
+    # leaves the band at the top, not at zero)
     assert res["control_tripped"] is True
-    assert res["control_fusions"] < \
-        check_fusion.BUDGETS["captured_step"]["fusions"][0]
+    lo, hi = check_fusion.BUDGETS["captured_step"]["fusions"]
+    assert not lo <= res["control_fusions"] <= hi
 
 
 def test_sharded_collectives_match_rule_derived_expectation():

@@ -1,4 +1,4 @@
-"""Reference `contrib` op namespace parity (VERDICT r3 item 3; upstream:
+"""Reference `contrib` op namespace parity (upstream:
 src/operator/contrib/*.cc). Every op is exercised from nd AND sym, with
 parity pinned against closed forms (lax conv, numpy FFT, hand-computed
 sketches) rather than against our own kernels."""
@@ -373,7 +373,7 @@ def test_adaptive_avg_pooling2d_sym_json_roundtrip():
 
 def test_bilinear_resize2d_contrib_alias():
     """upstream documents BilinearResize2D under contrib; both nd.contrib
-    and sym.contrib must carry the alias (VERDICT r4 missing #5)."""
+    and sym.contrib must carry the alias."""
     x = np.random.RandomState(3).rand(1, 2, 5, 5).astype(np.float32)
     top = mx.nd.BilinearResize2D(nd.array(x), height=10, width=10).asnumpy()
     via_contrib = nd.contrib.BilinearResize2D(

@@ -1,5 +1,4 @@
-"""Multi-host (multi-process) bootstrap smoke tests (VERDICT r1 #4 /
-SURVEY §1 distributed row; reference: kvstore_dist ps-lite bootstrap).
+"""Multi-host (multi-process) bootstrap smoke tests (SURVEY §1 distributed row; reference: kvstore_dist ps-lite bootstrap).
 
 Spawns REAL separate processes that rendezvous through
 `kvstore.init_distributed` (jax.distributed.initialize) on the CPU
@@ -31,7 +30,7 @@ import numpy as np
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import make_array_from_process_local_data
-from mxnet_tpu.jax_compat import shard_map
+from jax import shard_map
 
 mesh = Mesh(jax.devices(), ("dp",))
 f = shard_map(lambda x: jax.lax.psum(x, "dp"), mesh=mesh,

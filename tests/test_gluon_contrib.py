@@ -3,7 +3,7 @@ mesh), pixel shuffle, ConvLSTM/LSTMP/VariationalDropout cells."""
 import numpy as np
 import jax
 import jax.numpy as jnp
-from mxnet_tpu.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import mxnet_tpu as mx

@@ -253,7 +253,7 @@ def test_2bit_error_feedback_accumulates():
 def test_compressed_training_matches_uncompressed():
     """MLP trained with int8-compressed ici allreduce converges to the
     same solution as uncompressed (within tolerance) on the 8-device
-    mesh — the VERDICT r2 item 4 acceptance test."""
+    mesh — the acceptance test."""
     from mxnet_tpu.parallel.mesh import make_mesh
 
     def train(compression):

@@ -1,4 +1,4 @@
-"""tools/launch.py multi-process launcher (VERDICT r3 item 6; reference:
+"""tools/launch.py multi-process launcher (reference:
 upstream tools/launch.py + dmlc_tracker). Spawns REAL processes that
 bootstrap `kvstore.init_distributed` purely from the launcher-exported
 env (MXTPU_*/DMLC_*), reduce a gradient-like array across workers, and

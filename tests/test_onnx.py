@@ -501,7 +501,7 @@ def _bert_mini():
 
 
 def test_bert_encoder_export_matches_torch_runtime(tmp_path):
-    """BERT-mini (VERDICT r3 item 8): the symbolic encoder trace —
+    """BERT-mini: the symbolic encoder trace —
     fused-QKV attention decomposed to slice/batch_dot/length-masked
     softmax — exports to opset 11 and reproduces the framework's eager
     (flash-attention-path) logits under the independent torch runtime,

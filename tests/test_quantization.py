@@ -138,7 +138,7 @@ def test_quantize_net_custom_block_supported():
 def test_quantize_net_zoo_resnet18():
     """The obvious int8 target works end to end: quantize_net over a zoo
     resnet18 (custom residual HybridBlocks), classification decisions
-    within 1% of fp32 on synthetic data (VERDICT r3 item 4 done-bar)."""
+    within 1% of fp32 on synthetic data."""
     mx.random.seed(7)
     from mxnet_tpu.gluon.model_zoo.vision import resnet18_v1
     net = resnet18_v1(classes=10)
@@ -156,8 +156,7 @@ def test_quantize_net_zoo_resnet18():
 
 def test_entropy_calibration_beats_naive_on_skewed_activations():
     """A heavy-tailed input (one huge outlier) wrecks max-abs scaling;
-    the KL threshold clips the tail and must reconstruct the bulk better
-    (VERDICT r3 item 4 done-bar)."""
+    the KL threshold clips the tail and must reconstruct the bulk better."""
     mx.random.seed(8)
     rs = np.random.RandomState(0)
     bulk = rs.uniform(-1, 1, size=(256, 32)).astype(np.float32)
@@ -363,7 +362,7 @@ def test_quantize_net_multi_input_bert():
     np.testing.assert_allclose(again.asnumpy(), ref_pool.asnumpy())
 
 
-# ---- op-level quantization surface (VERDICT r4 item 5; upstream:
+# ---- op-level quantization surface (upstream:
 # src/operator/quantization/*.cc) ---------------------------------------
 def test_nd_contrib_quantize_int8_closed_form():
     rs = np.random.RandomState(0)

@@ -1,4 +1,4 @@
-"""Legacy mx.rnn cell API (VERDICT r4 item 4; reference:
+"""Legacy mx.rnn cell API (reference:
 python/mxnet/rnn/rnn_cell.py): cells build Symbol graphs, unroll,
 bind through Module/BucketingModule, and the fused sym.RNN node
 computes the same numbers as the unfused per-step chain."""

@@ -205,7 +205,7 @@ def test_sharded_matches_local_bitwise():
 
 # ------------------------------------------------- captured fast path
 @pytest.mark.skipif(len(jax.devices()) < 4, reason="needs a (2,2) mesh")
-def test_captured_moe_step_contract():
+def test_captured_moe_step_contract(monkeypatch):
     """The headline contract in one warm run: the step publishes as
     `moe_step`, the HLO holds EXACTLY A2A_PER_LAYER * STEP_TRAVERSALS
     all-to-alls for one layer, 1 dispatch + zero sync H2D through the
@@ -213,6 +213,9 @@ def test_captured_moe_step_contract():
     matches `a2a_bytes_per_step`, drop accounting accumulates, and
     publish_metrics lands it all in the registry."""
     from mxnet_tpu import profiler
+    # inspect THIS compile even if another test file on the same xdist
+    # worker compiled a `moe_step` first (default policy: first only)
+    monkeypatch.setenv("MXTPU_HLO_TELEMETRY", "always")
     from mxnet_tpu.observability import compilex
     from mxnet_tpu.prefetch import DevicePrefetcher
 

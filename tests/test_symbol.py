@@ -329,7 +329,7 @@ def test_auto_names_deterministic_and_collision_free():
     """Auto-names come from NameManager monotonic counters at creation:
     the same build sequence under a fresh manager yields byte-identical
     tojson(), and long chains never collide (regression for the old
-    id()%10000 scheme — VERDICT r2 weak #3)."""
+    id()%10000 scheme)."""
     def build():
         x = sym.Variable("x")
         h = sym.FullyConnected(x, num_hidden=4)

@@ -81,7 +81,7 @@ def test_checkpoint_arg_aux_split(tmp_path):
 
 
 def test_zoo_checkpoint_loads_identical_logits(tmp_path):
-    """The VERDICT r2 item 8 acceptance: an upstream-format file written
+    """Acceptance: an upstream-format file written
     under a DIFFERENT scope prefix (as another process would produce)
     loads into resnet18_v1 and reproduces the exact logits of direct
     set_data."""

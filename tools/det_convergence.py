@@ -1,4 +1,4 @@
-"""Detection convergence evidence (VERDICT r4 item 8): train each
+"""Detection convergence evidence: train each
 detector for a few hundred steps on a LEARNABLE synthetic dataset
 (rendered colored rectangles — class == color), record the loss curve,
 and sanity-check decoded predictions on held-out scenes.
@@ -6,7 +6,7 @@ and sanity-check decoded predictions on held-out scenes.
 Usage:  python tools/det_convergence.py [--model ssd|rcnn]
             [--steps N] [--batch N] [--input N] [--report PATH]
 
-The loss curve + eval stats print as one JSON line for docs/PERF.md.
+The loss curve + eval stats print as one JSON line.
 """
 from __future__ import annotations
 

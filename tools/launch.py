@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Multi-process / multi-host launcher (reference parity: tools/launch.py
-+ dmlc_tracker — VERDICT r3 item 6).
++ dmlc_tracker).
 
 Spawns N copies of a training command with the coordinator/rank
 environment wired for `mxnet_tpu.kvstore.init_distributed`, streams each

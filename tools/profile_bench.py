@@ -5,7 +5,7 @@ Usage:  python tools/profile_bench.py [--batch N] [--steps N]
 
 Writes the raw trace under /tmp/mxtpu_prof and prints the top-K HLO ops by
 total device time (aggregated over the steps inside the trace), which is the
-evidence base for bench tuning (VERDICT r1 next-step #1).
+evidence base for bench tuning.
 """
 from __future__ import annotations
 

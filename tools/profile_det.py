@@ -1,12 +1,12 @@
-"""Device profile of the detection bench train steps (VERDICT r4 item 2:
-give SSD/Faster-RCNN the ResNet profile treatment).
+"""Device profile of the detection bench train steps (give
+SSD/Faster-RCNN the ResNet profile treatment).
 
 Usage:  python tools/profile_det.py [--model ssd|rcnn] [--batch N]
                                     [--steps N] [--input N]
 
 Reuses bench_det's exact step builders (so the profile measures the
 benched program, not a lookalike) and profile_bench's xplane parser for
-the per-HLO table that goes into docs/PERF.md.
+the per-HLO table (a finding for the root PERF.md).
 """
 from __future__ import annotations
 
