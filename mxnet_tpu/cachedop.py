@@ -376,6 +376,50 @@ class CachedStep:
 
     # ------------------------------------------------------ captured path
     def _captured(self, batch_nd, batch_size):
+        # the step's host phases are child spans of Trainer.captured_step:
+        # step_key here, step_stage / step_launch / step_writeback in
+        # _dispatch (a cache miss's build lies between, under no child)
+        with _tracer.span("Trainer.step_key", cat="trainer"):
+            (key, diff, state_nds, scaler, scale_mode, spec, plan,
+             sparse_info, tiered_ks) = self._step_key(batch_nd)
+        entry = self._cache.get(key)
+        if entry is None:
+            _miss(self._miss_reason(key))
+            profiler.record_jit_cache(False)
+            self._last_key = key
+            try:
+                entry = self._build(batch_nd, diff, state_nds, scale_mode,
+                                    spec, plan, sparse_info, tiered_ks)
+            except _CaptureUnsupported as e:
+                # negative-cache the failure: later steps with the same
+                # signature skip straight to the imperative path instead
+                # of re-running the abstract pre-pass every step
+                self._store(key, ("unsupported", e.reason))
+                raise
+            self._store(key, entry)
+        elif entry[0] == "unsupported":
+            self._cache.move_to_end(key)
+            self._last_key = key
+            raise _CaptureUnsupported(entry[1])
+        else:
+            self._cache.move_to_end(key)
+            _hits.inc()
+            profiler.record_jit_cache(True)
+            self._last_key = key
+        jfn, meta = entry
+        try:
+            return self._dispatch(jfn, meta, batch_nd, diff, state_nds,
+                                  batch_size, scaler, scale_mode)
+        except _CaptureUnsupported as e:
+            # a first-dispatch compile failure is as permanent as a build
+            # failure: negative-cache it so later steps skip straight to
+            # the imperative path
+            self._store(key, ("unsupported", e.reason))
+            raise
+
+    def _step_key(self, batch_nd):
+        """Eligibility checks, optimizer-state lookup and the cache key
+        of this call: everything `_captured` needs before the lookup."""
         tr = self._trainer
         opt = tr._optimizer
         from . import amp
@@ -480,40 +524,8 @@ class CachedStep:
             tuple(sorted((k, v["axis"]) for k, v in sparse_info.items())),
             tuple(sorted(tiered_ks)),
         )
-        entry = self._cache.get(key)
-        if entry is None:
-            _miss(self._miss_reason(key))
-            profiler.record_jit_cache(False)
-            self._last_key = key
-            try:
-                entry = self._build(batch_nd, diff, state_nds, scale_mode,
-                                    spec, plan, sparse_info, tiered_ks)
-            except _CaptureUnsupported as e:
-                # negative-cache the failure: later steps with the same
-                # signature skip straight to the imperative path instead
-                # of re-running the abstract pre-pass every step
-                self._store(key, ("unsupported", e.reason))
-                raise
-            self._store(key, entry)
-        elif entry[0] == "unsupported":
-            self._cache.move_to_end(key)
-            self._last_key = key
-            raise _CaptureUnsupported(entry[1])
-        else:
-            self._cache.move_to_end(key)
-            _hits.inc()
-            profiler.record_jit_cache(True)
-            self._last_key = key
-        jfn, meta = entry
-        try:
-            return self._dispatch(jfn, meta, batch_nd, diff, state_nds,
-                                  batch_size, scaler, scale_mode)
-        except _CaptureUnsupported as e:
-            # a first-dispatch compile failure is as permanent as a build
-            # failure: negative-cache it so later steps skip straight to
-            # the imperative path
-            self._store(key, ("unsupported", e.reason))
-            raise
+        return (key, diff, state_nds, scaler, scale_mode, spec, plan,
+                sparse_info, tiered_ks)
 
     def _miss_reason(self, key):
         last = self._last_key
@@ -965,11 +977,18 @@ class CachedStep:
                 return (tuple(w_locals),
                         tuple(tuple(sv) for sv in sv_locals), ogs)
 
-            if guard:
-                new_ws, new_ss, out_gs = jax.lax.cond(
-                    flag > 0, skip_update, do_update, None)
-            else:
-                new_ws, new_ss, out_gs = do_update(None)
+            # a named jitted function (XLA inlines the call), so that the
+            # optimizer's ops carry `jit(mx_update)` in their `op_name`
+            # and the name is in the compile cache's key: compilex
+            # `op_scopes` says why a `jax.named_scope` would not do, and
+            # maps the device's ops to it for `update_share_pct`
+            def mx_update():
+                if guard:
+                    return jax.lax.cond(flag > 0, skip_update, do_update,
+                                        None)
+                return do_update(None)
+
+            new_ws, new_ss, out_gs = jax.jit(mx_update)()
 
             if mesh is not None and any(shard_ok):
                 # sharded params: all-gather the new weights IN-PROGRAM;
@@ -1162,6 +1181,70 @@ class CachedStep:
     # --------------------------------------------------------- dispatch
     def _dispatch(self, jfn, meta, batch_nd, diff, state_nds, batch_size,
                   scaler, scale_mode):
+        with _tracer.span("Trainer.step_stage", cat="trainer"):
+            args, snapshot = self._stage(meta, batch_nd, diff, state_nds,
+                                         batch_size, scaler)
+        fresh = meta.pop("fresh", False)
+        try:
+            with _tracer.span("Trainer.step_launch", cat="trainer"):
+                if fresh:
+                    # buffer donation is a no-op on CPU test meshes; jax
+                    # warns at compile time — suppress it HERE, not
+                    # process-wide
+                    with warnings.catch_warnings():
+                        warnings.filterwarnings(
+                            "ignore",
+                            message="Some donated buffers were not")
+                        out = jfn(*args)
+                else:
+                    out = jfn(*args)
+        except Exception as e:
+            # no update ran: un-bump the optimistic update counts so lr
+            # schedules stay aligned with what was actually applied
+            self._restore_update_counts(snapshot)
+            # donation hazard: if the program EXECUTED far enough to
+            # consume its donated inputs before failing, the param/state
+            # buffers are gone — falling back would read deleted arrays
+            # and silently train garbage. Only a failure that left every
+            # donated buffer alive (trace/compile-stage errors) may take
+            # the transparent imperative fallback.
+            donated_dead = any(
+                getattr(a, "is_deleted", lambda: False)()
+                for group in (args[1], args[3])      # diff, state values
+                for leaf in group
+                for a in (leaf if isinstance(leaf, tuple) else (leaf,)))
+            if donated_dead:
+                raise MXNetError(
+                    "captured step failed AFTER its donated parameter/"
+                    "state buffers were consumed — model state is lost; "
+                    "restore from a checkpoint (see docs/PERFORMANCE.md "
+                    f"donation rules). Cause: {type(e).__name__}: {e}"
+                ) from e
+            if fresh and not isinstance(e, _CaptureUnsupported):
+                # first call = trace/compile of the backward+update stages
+                # (the forward-only prepass cannot see those): treat like
+                # any other capture failure — transparent fallback
+                raise _CaptureUnsupported(
+                    f"compile_error:{type(e).__name__}") from e
+            raise
+        with _tracer.span("Trainer.step_writeback", cat="trainer"):
+            return self._writeback(meta, diff, state_nds, out, snapshot,
+                                   scaler, scale_mode)
+
+    def _restore_update_counts(self, snapshot):
+        opt = self._trainer._optimizer
+        opt.num_update, counts = snapshot
+        for i, c in counts.items():
+            if c is None:
+                opt._index_update_count.pop(i, None)
+            else:
+                opt._index_update_count[i] = c
+
+    def _stage(self, meta, batch_nd, diff, state_nds, batch_size, scaler):
+        """Everything between the cache hit and the launch: update
+        counts, the per-step scalars, the rng key, value lists and their
+        placement. Returns (the program's arguments, the update-count
+        snapshot a skipped or failed step rolls back to)."""
         tr = self._trainer
         opt = tr._optimizer
         tr._optimizer.rescale_grad = tr._scale / batch_size
@@ -1264,55 +1347,16 @@ class CachedStep:
                         f"exactly this step's index batch, once")
                 tiered_vals.extend(prod)
             args = args + (tuple(tiered_vals),)
-        fresh = meta.pop("fresh", False)
-        try:
-            if fresh:
-                # buffer donation is a no-op on CPU test meshes; jax warns
-                # at compile time — suppress it HERE, not process-wide
-                with warnings.catch_warnings():
-                    warnings.filterwarnings(
-                        "ignore", message="Some donated buffers were not")
-                    loss_leaves, aux_vals, new_ws, new_ss, out_gs, flag = \
-                        jfn(*args)
-            else:
-                loss_leaves, aux_vals, new_ws, new_ss, out_gs, flag = \
-                    jfn(*args)
-        except Exception as e:
-            # no update ran: un-bump the optimistic update counts so lr
-            # schedules stay aligned with what was actually applied
-            num_update, counts = snapshot
-            opt.num_update = num_update
-            for i, c in counts.items():
-                if c is None:
-                    opt._index_update_count.pop(i, None)
-                else:
-                    opt._index_update_count[i] = c
-            # donation hazard: if the program EXECUTED far enough to
-            # consume its donated inputs before failing, the param/state
-            # buffers are gone — falling back would read deleted arrays
-            # and silently train garbage. Only a failure that left every
-            # donated buffer alive (trace/compile-stage errors) may take
-            # the transparent imperative fallback.
-            donated_dead = any(
-                getattr(a, "is_deleted", lambda: False)()
-                for group in (diff_vals, state_vals)
-                for leaf in group
-                for a in (leaf if isinstance(leaf, tuple) else (leaf,)))
-            if donated_dead:
-                raise MXNetError(
-                    "captured step failed AFTER its donated parameter/"
-                    "state buffers were consumed — model state is lost; "
-                    "restore from a checkpoint (see docs/PERFORMANCE.md "
-                    f"donation rules). Cause: {type(e).__name__}: {e}"
-                ) from e
-            if fresh and not isinstance(e, _CaptureUnsupported):
-                # first call = trace/compile of the backward+update stages
-                # (the forward-only prepass cannot see those): treat like
-                # any other capture failure — transparent fallback
-                raise _CaptureUnsupported(
-                    f"compile_error:{type(e).__name__}") from e
-            raise
+        return args, snapshot
 
+    def _writeback(self, meta, diff, state_nds, out, snapshot, scaler,
+                   scale_mode):
+        """From the launch's return to the step's: rebind every handle
+        to its post-step buffer, read the guard flag, settle the update
+        counts, wrap the loss."""
+        tr = self._trainer
+        loss_leaves, aux_vals, new_ws, new_ss, out_gs, flag = out
+        sh = meta.get("shardings")
         # Interop rule for mesh captures: anything eager code may consume
         # (params, aux, replicated grads, the loss) is rebound to a ZERO-
         # COPY device-0 shard view of the replicated mesh output, so
@@ -1394,13 +1438,7 @@ class CachedStep:
         if applied:
             tr._note_applied()
         else:
-            num_update, counts = snapshot
-            opt.num_update = num_update
-            for i, c in counts.items():
-                if c is None:
-                    opt._index_update_count.pop(i, None)
-                else:
-                    opt._index_update_count[i] = c
+            self._restore_update_counts(snapshot)
             tr._note_skip("AMP overflow" if scale_mode == "amp"
                           else "nonfinite gradients")
         tr._tick_step()

@@ -117,16 +117,8 @@ class Dropout(HybridBlock):
         if not autograd.is_training() or self._rate <= 0:
             return x
         key = _layer_rng()
-
-        def fn(a, _key=key, _p=self._rate, _axes=self._axes):
-            import jax
-            shape = list(a.shape)
-            for ax in _axes:
-                shape[ax] = 1
-            keep = 1.0 - _p
-            mask = jax.random.bernoulli(_key, keep, tuple(shape))
-            return jnp.where(mask, a / keep, 0).astype(a.dtype)
-        return _apply(fn, [x])
+        return _apply(lambda a, _key=key, _p=self._rate, _axes=self._axes:
+                      K.dropout(a, _key, _p, True, _axes), [x])
 
     def __repr__(self):
         return f"Dropout(p = {self._rate}, axes={self._axes})"

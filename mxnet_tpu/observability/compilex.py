@@ -66,8 +66,8 @@ from .metrics_registry import registry as _registry
 __all__ = ["instrument", "InstrumentedJit", "inspect_hlo_text",
            "analyze_jit", "analyze_compiled", "set_compilation_cache",
            "entry_compilation_cache", "compilation_cache_dir", "compile_cache_stats", "executables",
-           "instrumented", "COLLECTIVE_OPS", "set_dispatch_hook",
-           "dispatch_hook"]
+           "instrumented", "last_inspections", "op_scopes",
+           "COLLECTIVE_OPS", "set_dispatch_hook", "dispatch_hook"]
 
 # HLO collective opcodes tallied into hlo_collectives{op=}; async
 # ("-start") forms count toward the same op, "-done" halves do not.
@@ -144,10 +144,63 @@ _listeners_ok = _register_listeners()
 _OP_RE = re.compile(r"=\s*[\w\[\],{}<>()/:. ]*?\s([a-z][a-z0-9\-]*)\(")
 
 
+# scopes the framework puts on device ops: `mx_update`, the captured
+# step's optimizer update (cachedop.py), and `mx_dropout` (ops.nn_ops).
+# Each is a named jitted function inside the program, not a
+# `jax.named_scope`: the persistent compile cache keys a program with its
+# debug metadata stripped, so an executable that a build without the
+# scope compiled is loaded with its own, scope-less op names; a
+# function's symbol is part of the key. XLA inlines the call, and a
+# transformed op keeps the name inside its wrappers:
+# `transpose(jvp(jit(mx_dropout)))`.
+_SCOPE_RE = re.compile(r"(?<!\w)mx_[a-z0-9_]+")
+_INSTR_RE = re.compile(r"\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
+
+
+def op_scopes(text):
+    """{instruction name: sorted tuple of the `mx_*` scopes it holds} over
+    every instruction of one optimized-HLO module text (names are unique
+    within a module) that holds any: the scopes in the instruction's own
+    metadata `op_name` and, for a fusion, in the instructions of the
+    computation it calls. A device trace names its events by instruction
+    (`fusion.1178`) and keeps no metadata; this map is the join. A fusion
+    that mixes two scopes is listed under both."""
+    own, calls, in_comp = {}, {}, {}
+    comp = None
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            if line.endswith("{"):          # `[ENTRY ]%name (params) -> .. {`
+                comp = line.split(" (", 1)[0].split()[-1].lstrip("%")
+            continue
+        scoped, caller = "mx_" in line, "calls=" in line
+        if not (scoped or caller):
+            continue
+        m = _INSTR_RE.match(line)
+        if m is None:
+            continue
+        if scoped:
+            meta = _OP_NAME_RE.search(line)
+            found = set(_SCOPE_RE.findall(meta.group(1))) if meta else ()
+            if found:
+                own[m.group(1)] = found
+                in_comp.setdefault(comp, set()).update(found)
+        if caller:
+            calls[m.group(1)] = _CALLS_RE.search(line).group(1)
+    out = {}
+    for name in own.keys() | calls.keys():
+        found = own.get(name, set()) | in_comp.get(calls.get(name), set())
+        if found:
+            out[name] = tuple(sorted(found))
+    return out
+
+
 def inspect_hlo_text(text):
     """Count the structure of one optimized-HLO module text: fusions,
     collectives (per op + total), copies, donated-input aliases, module
-    byte size, and the full opcode histogram. Pure function — the gate
+    byte size, the full opcode histogram, and `op_scopes` (which
+    instructions hold which `mx_*` named scope). Pure function — the gate
     and tests call it on any `compiled.as_text()`."""
     ops = {}
     for m in _OP_RE.finditer(text):
@@ -166,6 +219,7 @@ def inspect_hlo_text(text):
         "aliased_inputs": text.count("may-alias") + text.count("must-alias"),
         "module_bytes": len(text),
         "ops": ops,
+        "op_scopes": op_scopes(text),
     }
 
 
@@ -253,7 +307,7 @@ class InstrumentedJit:
     jit function, so `.lower()` / `.clear_cache()` keep working."""
 
     __slots__ = ("_jfn", "executable", "_csize", "_called", "_compiles",
-                 "_seconds", "last_hlo", "last_compile_seconds",
+                 "_seconds", "_last_hlo", "last_compile_seconds",
                  "last_abstract", "__weakref__")
 
     def __init__(self, jfn, executable):
@@ -280,6 +334,18 @@ class InstrumentedJit:
     @property
     def compile_count(self):
         return int(self._compiles.value)
+
+    @property
+    def last_hlo(self):
+        """The last inspection of this executable's optimized HLO
+        (`inspect_hlo_text`'s dict), None before one."""
+        return self._last_hlo
+
+    @last_hlo.setter
+    def last_hlo(self, info):
+        self._last_hlo = info
+        if info is not None:
+            _inspections[self.executable] = info
 
     def __getattr__(self, name):
         return getattr(self._jfn, name)
@@ -381,6 +447,9 @@ def executables():
 
 _instances = weakref.WeakValueDictionary()   # executable -> live wrapper
                                              # (latest instance wins)
+_inspections = {}    # executable -> its last inspection; held strongly,
+                     # because a wrapper dies with the step or runtime
+                     # that owns it and a trace is reduced after that
 
 
 def instrumented():
@@ -389,6 +458,13 @@ def instrumented():
     analysis/graphlint.py / tools/check_static.py iterate to lint the
     framework's real programs instead of hand-kept fixtures."""
     return dict(_instances)
+
+
+def last_inspections():
+    """{executable name: the last `inspect_hlo_text` dict published under
+    it} — outlives the wrappers, so a device trace taken from a step
+    that is gone by now can still be joined with its `op_scopes`."""
+    return dict(_inspections)
 
 
 # -------------------------------------------- persistent compile cache

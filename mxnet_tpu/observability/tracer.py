@@ -125,11 +125,16 @@ class _Span:
 
 
 def start(buffer_size=None):
-    """Begin recording. Clears the buffer and re-anchors the epoch."""
+    """Begin recording. Clears the buffer and re-anchors the epoch. The
+    ring holds `buffer_size` events, or the configured default: a size
+    one caller asked for does not outlive its recording."""
     global ACTIVE, _buf, _epoch_ns
     with _lock:
-        cap = int(buffer_size) if buffer_size else _buf.maxlen
+        cap = int(buffer_size) if buffer_size else _DEFAULT_CAP
         _buf = deque(maxlen=cap)
+        # idents are reused once a thread exits: a name captured for an
+        # earlier recording would label this one's thread
+        _thread_names.clear()
         _epoch_ns = perf_counter_ns()
         ACTIVE = True
 

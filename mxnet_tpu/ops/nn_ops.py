@@ -393,11 +393,26 @@ def leaky_relu(x, act_type="leaky", slope=0.25, alpha=None):
     raise ValueError(f"unknown leaky_relu act_type {act_type}")
 
 
-def dropout(x, key, p=0.5, training=True):
+def dropout(x, key, p=0.5, training=True, axes=()):
+    """Inverted dropout; along `axes` one draw is shared (the mask has
+    extent 1 there)."""
     if not training or p <= 0:
         return x
-    keep = 1.0 - p
-    mask = jax.random.bernoulli(key, keep, x.shape)
+    shape = list(x.shape)
+    for ax in axes:
+        shape[ax] = 1
+    return mx_dropout(x, key, 1.0 - p, tuple(shape))
+
+
+@partial(jax.jit, static_argnames=("keep", "shape"))
+def mx_dropout(x, key, keep, shape):
+    """The bits and the mask multiply. A named jitted function, so that
+    forward and backward carry `mx_dropout` in their ops' `op_name`
+    (`jvp(jit(mx_dropout))`, `transpose(jvp(jit(mx_dropout)))`) and in the
+    compile cache's key; XLA inlines the call. compilex `op_scopes` says
+    why a `jax.named_scope` would not do, and maps the device's ops to
+    it for the benchmark's `dropout_share_pct`."""
+    mask = jax.random.bernoulli(key, keep, shape)
     return jnp.where(mask, x / keep, 0).astype(x.dtype)
 
 

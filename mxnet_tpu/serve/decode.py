@@ -379,14 +379,21 @@ class DecodeRuntime:
         padded[0, :src.size] = src
         profiler.record_dispatch("serve_prefill")
         old = (self.mem_k, self.mem_v, self.mem_vl)
+
+        def launch():
+            self.mem_k, self.mem_v, self.mem_vl = self._prefill_fn(
+                self.mem_k, self.mem_v, self.mem_vl,
+                jnp.asarray(padded), jnp.asarray([src_len], jnp.int32),
+                jnp.int32(slot))
+
         try:
-            with _tracer.span("serve.prefill", cat="serve",
-                              args={"slot": int(slot),
-                                    "src_len": int(src_len)}):
-                self.mem_k, self.mem_v, self.mem_vl = self._prefill_fn(
-                    self.mem_k, self.mem_v, self.mem_vl,
-                    jnp.asarray(padded), jnp.asarray([src_len], jnp.int32),
-                    jnp.int32(slot))
+            if _tracer.ACTIVE:
+                with _tracer.span("serve.prefill", cat="serve",
+                                  args={"slot": int(slot),
+                                        "src_len": int(src_len)}):
+                    launch()
+            else:
+                launch()
         except Exception as e:
             # donation hazard (same rule as cachedop): a failure that
             # consumed the donated memory buffers loses EVERY slot's

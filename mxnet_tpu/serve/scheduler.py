@@ -112,6 +112,7 @@ class Request:
         self.retries = 0            # fault retries (budget: max_retries)
         self.preemptions = 0        # page-pressure requeues (own budget)
         self.t_submit = time.perf_counter()
+        self.t_admit = None         # stamped at each admission into a slot
         self.t_first_token = None
         self.t_done = None
         self._slot = None
@@ -311,6 +312,7 @@ class Scheduler:
         self._m_preempt = reg.counter("serve_page_preemptions")
         self._m_deadline = reg.counter("serve_deadline_expired")
         self._m_ttft = reg.histogram("serve_ttft_seconds")
+        self._m_queue_wait = reg.histogram("serve_queue_wait_seconds")
         self._m_latency = reg.histogram("serve_request_seconds")
         self._m_step = reg.histogram("serve_decode_step_seconds")
         # speculative decoding telemetry (ISSUE 12): the acceptance
@@ -426,9 +428,21 @@ class Scheduler:
             return self._step_locked()
 
     def _step_locked(self):
+        # the turn's phases are child spans of `serve.turn` (admit, plan,
+        # decode_step, commit); what no child covers is the deadline
+        # sweep, the active lists and `_decode`'s array building
+        if _tracer.ACTIVE:
+            with _tracer.span("serve.turn", cat="serve",
+                              args={"turn": self.decode_turns,
+                                    "queued": len(self._queue)}):
+                return self._turn()
+        return self._turn()
+
+    def _turn(self):
         res = StepResult()
         self._expire_deadlines()
-        res.admitted = self._admit(res)
+        with _tracer.span("serve.admit", cat="serve"):
+            res.admitted = self._admit(res)
         active = [(s, r) for s, r in enumerate(self._slots)
                   if r is not None]
         if not active:
@@ -439,7 +453,8 @@ class Scheduler:
             if _finj.ENABLED:
                 _finj.check("serve.decode",
                             context=f"{len(active)} active")
-            plans = self._plan_turn(active, res)
+            with _tracer.span("serve.plan", cat="serve"):
+                plans = self._plan_turn(active, res)
             active = [(s, r) for s, r in enumerate(self._slots)
                       if r is not None]
             if not active:
@@ -454,6 +469,14 @@ class Scheduler:
         self._m_step.observe(time.perf_counter() - t0)
         res.decoded = len(active)
         self.decode_turns += 1
+        with _tracer.span("serve.commit", cat="serve"):
+            self._commit(active, plans, next_tok, res)
+        return res
+
+    def _commit(self, active, plans, next_tok, res):
+        """The turn's host tail: commit each slot's accepted tokens, emit
+        them, offer finished prompt pages to the prefix cache, evict the
+        requests that are done."""
         now = time.perf_counter()
         for s, r in active:
             window, f = plans[s]
@@ -506,7 +529,6 @@ class Scheduler:
                 self._evict(s, r, "done")
                 res.completed += 1
         self._m_active.set(self.active_count())
-        return res
 
     def defrag(self):
         """Compact the page pool: renumber live pages into the low ids,
@@ -707,6 +729,16 @@ class Scheduler:
             req._n_table = len(pages)
             self._lens[s] = len(hit) * psize
             admitted += 1
+            # queue wait, measured where the request leaves the queue:
+            # submit to holding a slot, its own prefill dispatch included
+            req.t_admit = time.perf_counter()
+            wait = req.t_admit - req.t_submit
+            self._m_queue_wait.observe(wait)
+            if _tracer.ACTIVE:
+                _tracer.instant("serve.admitted", cat="serve", args={
+                    "id": req.id, "slot": s,
+                    "queue_wait_ms": wait * 1e3,
+                    "cached_tokens": req.prompt_cached_tokens})
         if admitted:
             self._m_active.set(self.active_count())
         return admitted
@@ -963,6 +995,7 @@ class Scheduler:
         r.known = None              # rebuilt (and re-adopted) at admission
         r._cache_done = False
         r.prompt_cached_tokens = 0
+        r.t_admit = None
         r.t_first_token = None
         with r._chunk_cv:
             r._chunks.clear()

@@ -517,3 +517,123 @@ def test_nan_detector_scans_without_host_pull():
     p._grad._rebind(p._grad._data * np.nan)
     with pytest.raises(mx.MXNetError, match="_grad"):
         det.check()
+
+
+# ------------------------------------- ISSUE 26: step phases and op scopes
+def _captured_dropout_step():
+    rng = np.random.RandomState(5)
+    X = nd.array(rng.randn(8, 16).astype(np.float32))
+    y = nd.array(rng.randint(0, 4, 8).astype(np.float32))
+    lossf = gluon.loss.SoftmaxCrossEntropyLoss()
+    mx.random.seed(0)
+    net = gluon.nn.Sequential()
+    net.add(gluon.nn.Dense(16, activation="relu"), gluon.nn.Dropout(0.5),
+            gluon.nn.Dense(4))
+    net.initialize(mx.init.Xavier())
+    net(X)
+    tr = gluon.Trainer(net.collect_params(), "adamw",
+                       {"learning_rate": 1e-3, "wd": 0.01})
+    return tr.capture(lambda a, b: lossf(net(a), b).mean()), X, y
+
+
+def test_captured_step_host_phases_lie_inside_the_step_span():
+    step, X, y = _captured_dropout_step()
+    step(X, y)                                   # compile outside the trace
+    tracer.start()
+    step(X, y)
+    step(X, y)
+    tracer.stop()
+    events = [e for e in tracer.to_chrome_trace()["traceEvents"]
+              if e["ph"] in "BE"]
+    spans, stack = [], []
+    for e in events:
+        if e["ph"] == "B":
+            stack.append(e)
+        else:
+            b = stack.pop()
+            spans.append((b["name"], b["ts"], e["ts"], len(stack)))
+    outer = [s for s in spans if s[0] == "Trainer.captured_step"]
+    assert len(outer) == 2
+    phases = ["Trainer.step_key", "Trainer.step_stage",
+              "Trainer.step_launch", "Trainer.step_writeback"]
+    for _, t0, t1, depth in outer:
+        kids = sorted((s for s in spans
+                       if s[3] == depth + 1 and t0 <= s[1] and s[2] <= t1),
+                      key=lambda s: s[1])
+        assert [k[0] for k in kids] == phases
+        assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+    tracer.clear()
+    step(X, y)                                   # tracer off: nothing kept
+    assert tracer.events_recorded() == 0
+
+
+def test_op_scopes_name_the_update_and_dropout_of_a_captured_step(
+        monkeypatch):
+    from mxnet_tpu.observability import compilex
+    monkeypatch.setenv("MXTPU_HLO_TELEMETRY", "always")
+    step, X, y = _captured_dropout_step()
+    step(X, y)
+    scopes = step.hlo_info()["op_scopes"]
+    held = {s for v in scopes.values() for s in v}
+    assert held == {"mx_update", "mx_dropout"}
+    assert all(v == tuple(sorted(v)) and v for v in scopes.values())
+    # the wrapper dies with the step; the inspection outlives it
+    del step
+    assert compilex.last_inspections()["captured_step"]["op_scopes"] \
+        == scopes
+
+
+def test_op_scopes_gives_a_fusion_the_scopes_of_what_it_calls():
+    from mxnet_tpu.observability import compilex
+    text = """HloModule jit_program, entry_computation_layout={()->f32[4]}
+
+%fused_computation.7 (param_0.1: f32[4], param_1.2: u32[4]) -> f32[4] {
+  %param_0.1 = f32[4]{0} parameter(0)
+  %param_1.2 = u32[4]{0} parameter(1)
+  %convert.3 = f32[4]{0} convert(%param_1.2), metadata={op_name="jit(program)/jvp(mx_dropout)/jit(_bernoulli)/convert"}
+  %mul.9 = f32[4]{0} multiply(%param_0.1, %convert.3), metadata={op_name="jit(program)/transpose(jvp(mx_dropout))/mul" source_file="/x/mx_other.py"}
+  ROOT %add.2 = f32[4]{0} add(%mul.9, %param_0.1), metadata={op_name="jit(program)/mx_update/cond/branch_1_fun/add"}
+}
+
+%fused_computation.8 (param_0.3: f32[4]) -> f32[4] {
+  %param_0.3 = f32[4]{0} parameter(0)
+  ROOT %neg.1 = f32[4]{0} negate(%param_0.3), metadata={op_name="jit(program)/jvp(dense)/neg"}
+}
+
+ENTRY %main.20 (p0: f32[4], p1: u32[4]) -> f32[4] {
+  %p0 = f32[4]{0:T(128)} parameter(0)
+  %p1 = u32[4]{0:T(128)} parameter(1)
+  %fusion.16 = f32[4]{0:T(128)} fusion(%p0, %p1), kind=kLoop, calls=%fused_computation.7, metadata={op_name="jit(program)/mx_update/cond/branch_1_fun/add"}
+  %fusion.17 = f32[4]{0:T(128)} fusion(%fusion.16), kind=kLoop, calls=%fused_computation.8
+  %copy.4 = f32[4]{0:T(128)} copy(%fusion.17), metadata={op_name="jit(program)/mx_update/copy"}
+  ROOT %tanh.5 = f32[4]{0:T(128)} tanh(%copy.4), metadata={op_name="jit(program)/tanh" source_file="/x/mx_update.py"}
+}
+"""
+    got = compilex.inspect_hlo_text(text)
+    assert got["fusions"] == 2
+    assert got["op_scopes"] == {
+        "fusion.16": ("mx_dropout", "mx_update"),    # mixed: under both
+        "convert.3": ("mx_dropout",), "mul.9": ("mx_dropout",),
+        "add.2": ("mx_update",), "copy.4": ("mx_update",)}
+    assert compilex.op_scopes("") == {}
+
+
+def test_a_recording_starts_with_its_own_ring_and_thread_names():
+    # neither the ring size one caller asked for nor the name of a thread
+    # that has exited (idents are reused) outlives its recording
+    tracer.start(buffer_size=8)
+    t = threading.Thread(target=lambda: tracer.instant("early"),
+                         name="gone-by-then")
+    t.start()
+    t.join()
+    tracer.stop()
+    tracer.start()
+    for i in range(100):
+        tracer.instant(f"i{i}")
+    tracer.stop()
+    assert tracer.events_recorded() == 100
+    names = [e["args"]["name"] for e in
+             tracer.to_chrome_trace()["traceEvents"]
+             if e["ph"] == "M" and e["name"] == "thread_name"]
+    assert names and "gone-by-then" not in names
+    tracer.clear()

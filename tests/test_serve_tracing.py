@@ -1,0 +1,171 @@
+"""The serve turn's spans (ISSUE 26): `serve.turn` and its phases, the
+request's `submit -> admitted -> request_done` chain, queue wait where the
+request leaves the queue, and nothing recorded with the tracer off."""
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu.fault import injection as finj
+from mxnet_tpu.observability import registry, tracer
+
+PHASES = ["serve.admit", "serve.plan", "serve.decode_step", "serve.commit"]
+
+
+def _server(**kw):
+    from mxnet_tpu.models.transformer import TransformerNMT
+    mx.random.seed(11)
+    m = TransformerNMT(50, units=32, hidden=64, num_layers=2, num_heads=4,
+                       max_length=32, dropout=0.0)
+    m.initialize()
+    kw.setdefault("engine_driven", False)
+    return mx.serve.Server(m, slots=2, page_size=4, max_src_len=16,
+                           max_new_tokens=6, **kw)
+
+
+def _sources(n=5):
+    rng = np.random.RandomState(3)
+    return [rng.randint(4, 50, (int(k),)) for k in rng.randint(3, 9, n)]
+
+
+@pytest.fixture(autouse=True)
+def _quiet():
+    finj.clear()
+    tracer.stop()
+    tracer.clear()
+    yield
+    finj.clear()
+    tracer.stop()
+    tracer.clear()
+
+
+def _events():
+    return [e for e in tracer.to_chrome_trace()["traceEvents"]
+            if e["ph"] != "M"]
+
+
+def _spans(events):
+    """[(name, start, end, tid, args)] of the B/E pairs, by start."""
+    out, stack = [], {}
+    for e in events:
+        if e["ph"] == "B":
+            stack.setdefault(e["tid"], []).append(e)
+        elif e["ph"] == "E":
+            b = stack[e["tid"]].pop()
+            out.append((b["name"], b["ts"], e["ts"], b["tid"],
+                        b.get("args")))
+    return sorted(out, key=lambda s: s[1])
+
+
+def test_every_turn_holds_its_phases_in_order_on_one_thread():
+    srv = _server()
+    tracer.start()
+    hs = [srv.submit(s) for s in _sources()]
+    srv.scheduler.run_until_idle()
+    tracer.stop()
+    spans = _spans(_events())
+    turns = [s for s in spans if s[0] == "serve.turn"]
+    assert len(turns) == srv.scheduler.decode_turns > 0
+    assert [t[4]["turn"] for t in turns] == list(range(len(turns)))
+    assert turns[0][4]["queued"] == len(hs)
+    for _, t0, t1, tid, _ in turns:
+        inside = [s for s in spans if s[0] != "serve.turn"
+                  and t0 <= s[1] and s[2] <= t1 and s[3] == tid]
+        phases = [s for s in inside if s[0] in PHASES]
+        assert [s[0] for s in phases] == PHASES
+        # nested in time: each phase ends before the next begins
+        assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
+        admit = phases[0]
+        for s in inside:
+            if s[0] == "serve.prefill":
+                assert admit[1] <= s[1] and s[2] <= admit[2]
+    assert len({t[3] for t in turns}) == 1
+    assert {s[0] for s in spans} <= set(PHASES) | {"serve.turn",
+                                                   "serve.prefill"}
+    srv.close()
+
+
+def test_a_request_is_one_chain_of_instants_and_queue_wait_is_its_own():
+    srv = _server()
+    tracer.start()
+    hs = [srv.submit(s) for s in _sources()]
+    srv.scheduler.run_until_idle()
+    tracer.stop()
+    inst = [e for e in _events() if e["ph"] == "i"]
+    for h in hs:
+        mine = [e for e in inst if e["args"]["id"] == h.id]
+        assert [e["name"] for e in mine] == [
+            "serve.submit", "serve.admitted", "serve.request_done"]
+        adm = mine[1]["args"]
+        assert adm["queue_wait_ms"] == (h.t_admit - h.t_submit) * 1e3
+        assert adm["slot"] in (0, 1) and adm["cached_tokens"] == 0
+        assert h.t_submit <= h.t_admit <= h.t_first_token <= h.t_done
+    # two slots, five requests: the later ones waited for a slot
+    waits = [h.t_admit - h.t_submit for h in hs]
+    assert max(waits[2:]) > max(waits[:2])
+    srv.close()
+
+
+def test_a_requeued_request_is_stamped_again():
+    srv = _server(max_retries=2)
+    finj.inject("serve.decode", at=[2])      # die after one emitted token
+    tracer.start()
+    h = srv.submit(_sources(1)[0])
+    sched = srv.scheduler
+    sched.step()
+    first = h.t_admit
+    assert first is not None
+    sched.step()                             # fault -> requeue
+    assert h.state == "queued" and h.t_admit is None
+    sched.run_until_idle(max_steps=200)
+    tracer.stop()
+    assert h.state == "done" and h.t_admit > first
+    admitted = [e for e in _events() if e["name"] == "serve.admitted"]
+    assert len(admitted) == 2
+    assert admitted[1]["args"]["queue_wait_ms"] \
+        == (h.t_admit - h.t_submit) * 1e3 > admitted[0]["args"][
+            "queue_wait_ms"]
+    srv.close()
+
+
+def test_tracer_off_records_nothing_and_the_counters_still_fill():
+    hist = registry().histogram("serve_queue_wait_seconds")
+    n0, sum0 = hist.count, hist.sum
+    srv = _server()
+    hs = [srv.submit(s) for s in _sources()]
+    srv.scheduler.run_until_idle()
+    off = [h.result() for h in hs]
+    assert tracer.events_recorded() == 0
+    assert all(h.t_admit is not None for h in hs)
+    assert hist.count == n0 + len(hs)
+    assert hist.sum - sum0 == pytest.approx(
+        sum(h.t_admit - h.t_submit for h in hs))
+    srv.close()
+
+    srv = _server()
+    tracer.start()
+    hs = [srv.submit(s) for s in _sources()]
+    srv.scheduler.run_until_idle()
+    tracer.stop()
+    assert tracer.events_recorded() > 0
+    assert [h.result() for h in hs] == off   # greedy tokens identical
+    srv.close()
+
+
+def test_an_engine_driven_turn_lies_inside_the_engines_own_task_span():
+    # the engine already records one span a burst of the serve loop
+    # (`engine:<module.fn>`): the turns need no loop span of their own
+    srv = _server(engine_driven=True)
+    tracer.start()
+    hs = [srv.submit(s) for s in _sources()]
+    assert srv.wait(hs, timeout=60)
+    srv.wait(timeout=60)
+    tracer.stop()
+    spans = _spans(_events())
+    bursts = [s for s in spans if s[0].startswith("engine:")
+              and s[0].endswith("EngineLoop._loop_task")]
+    turns = [s for s in spans if s[0] == "serve.turn"]
+    assert bursts and turns
+    for t in turns:
+        assert any(b[1] <= t[1] and t[2] <= b[2] and b[3] == t[3]
+                   for b in bursts)
+    srv.close()
