@@ -411,8 +411,17 @@ def mx_dropout(x, key, keep, shape):
     (`jvp(jit(mx_dropout))`, `transpose(jvp(jit(mx_dropout)))`) and in the
     compile cache's key; XLA inlines the call. compilex `op_scopes` says
     why a `jax.named_scope` would not do, and maps the device's ops to
-    it for the benchmark's `dropout_share_pct`."""
-    mask = jax.random.bernoulli(key, keep, shape)
+    it for the benchmark's `dropout_share_pct`.
+
+    The bits are XLA's `RngBitGenerator`, not threefry: on the TPU one
+    instruction that the compiler neither fuses into the mask's consumers
+    nor repeats, so a site's bits are made once and the backward reads the
+    stored one-byte mask. `key` is the site's two-word threefry key,
+    widened to the generator's four words; the same key gives the same
+    mask. The keep probability is exact to 2**-32 (an integer compare)."""
+    state = jnp.concatenate([key, key ^ jnp.uint32(0x9E3779B9)])
+    _, bits = jax.lax.rng_bit_generator(state, shape, dtype=jnp.uint32)
+    mask = bits < jnp.uint32(min(int(keep * 2 ** 32), 2 ** 32 - 1))
     return jnp.where(mask, x / keep, 0).astype(x.dtype)
 
 

@@ -183,8 +183,7 @@ def _dropout_train(x, p=0.5, _rng=None):
     per-node fold of the step key the Executor draws each forward."""
     if not p or _rng is None:
         return x, {}
-    keep = jax.random.bernoulli(_rng, 1 - p, x.shape)
-    return jnp.where(keep, x / (1 - p), 0).astype(x.dtype), {}
+    return K.dropout(x, _rng, p), {}
 
 
 register_train_op("Dropout", _dropout_train)

@@ -4,6 +4,7 @@ instrumentation, satellites (pause/resume, Scope tally, Monitor handles,
 device-side numeric checks), and the disabled-path overhead smoke test."""
 import json
 import os
+import re
 import subprocess
 import sys
 import threading
@@ -520,19 +521,23 @@ def test_nan_detector_scans_without_host_pull():
 
 
 # ------------------------------------- ISSUE 26: step phases and op scopes
-def _captured_dropout_step():
+def _captured_dropout_step(rate=0.5, mesh=None):
     rng = np.random.RandomState(5)
     X = nd.array(rng.randn(8, 16).astype(np.float32))
     y = nd.array(rng.randint(0, 4, 8).astype(np.float32))
     lossf = gluon.loss.SoftmaxCrossEntropyLoss()
     mx.random.seed(0)
     net = gluon.nn.Sequential()
-    net.add(gluon.nn.Dense(16, activation="relu"), gluon.nn.Dropout(0.5),
+    net.add(gluon.nn.Dense(16, activation="relu"), gluon.nn.Dropout(rate),
             gluon.nn.Dense(4))
     net.initialize(mx.init.Xavier())
     net(X)
-    tr = gluon.Trainer(net.collect_params(), "adamw",
-                       {"learning_rate": 1e-3, "wd": 0.01})
+    opt = {"learning_rate": 1e-3, "wd": 0.01}
+    if mesh is None:
+        tr = gluon.Trainer(net.collect_params(), "adamw", opt)
+    else:
+        tr = gluon.Trainer(net.collect_params(), "adamw", opt, kvstore="ici")
+        tr.shard(mesh=mesh)
     return tr.capture(lambda a, b: lossf(net(a), b).mean()), X, y
 
 
@@ -581,6 +586,48 @@ def test_op_scopes_name_the_update_and_dropout_of_a_captured_step(
     del step
     assert compilex.last_inspections()["captured_step"]["op_scopes"] \
         == scopes
+
+
+def _lowered_text(step):
+    """The program as jax hands it to XLA: there the bit generator is still
+    one op (the CPU's compiler expands it; the TPU's keeps it)."""
+    ij = step._cache[step._last_key][0]
+    args, kwargs = ij.last_abstract
+    return ij.lower(*args, **kwargs).as_text()
+
+
+def test_mx_dropout_holds_the_bit_generator_and_no_threefry():
+    step, X, y = _captured_dropout_step()
+    step(X, y)
+    funcs = dict(re.findall(
+        r"func\.func \w+ @([\w.]+)\((.*?)\n  \}", _lowered_text(step), re.S))
+    # forward and backward of the one site, and what they call
+    held = [n for n in funcs if n.startswith("mx_dropout")]
+    assert len(held) == 2
+    for n in held:
+        held += [c for c in re.findall(r"call @([\w.]+)", funcs[n])
+                 if c not in held]
+    body = "\n".join(funcs[n] for n in held)
+    assert body.count("stablehlo.rng_bit_generator") == 1
+    assert "threefry" not in body
+    # the site's key still comes from the step's by a threefry split
+    assert any("threefry" in n for n in funcs)
+
+
+def test_dropout_adds_no_collective_to_a_dp4_step(monkeypatch):
+    import jax
+    from mxnet_tpu.shard import as_mesh
+    monkeypatch.setenv("MXTPU_HLO_TELEMETRY", "always")
+
+    def collectives(rate):
+        step, X, y = _captured_dropout_step(
+            rate, as_mesh((4, 1), devices=jax.devices()[:4]))
+        assert np.isfinite(float(step(X, y).asnumpy()))
+        assert ("stablehlo.rng_bit_generator" in _lowered_text(step)) \
+            == (rate > 0)
+        return step.hlo_info()["collectives"]
+
+    assert collectives(0.1) == collectives(0.0)
 
 
 def test_op_scopes_gives_a_fusion_the_scopes_of_what_it_calls():

@@ -1,5 +1,7 @@
 """Parallelism tests on the virtual 8-device CPU mesh (SURVEY.md §2 #37-41):
 each strategy must match its single-device reference numerically."""
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -15,6 +17,13 @@ from mxnet_tpu.parallel import tensor_parallel as tp
 from mxnet_tpu.parallel import pipeline as pp
 from mxnet_tpu.parallel import moe as moe_mod
 from mxnet_tpu.ops.pallas_kernels import attention_reference
+
+
+def _layer_order(name):
+    """Sort key that reads a layer's number as a number: as text, dense10
+    sorts before dense9, and two nets' parameters then pair up wrongly
+    whenever the process-wide layer counter crosses a power of ten."""
+    return [int(t) if t.isdigit() else t for t in re.split(r"(\d+)", name)]
 
 
 def test_make_mesh_and_shard_batch():
@@ -145,7 +154,8 @@ def test_data_parallel_step_matches_single_device():
                     jax.random.PRNGKey(0))
     assert abs(float(l1) - float(l8)) < 1e-5
     # the two nets carry different auto-prefixes; match params positionally
-    for n1, n8 in zip(sorted(s1[0]), sorted(s8[0])):
+    for n1, n8 in zip(sorted(s1[0], key=_layer_order),
+                      sorted(s8[0], key=_layer_order)):
         np.testing.assert_allclose(np.asarray(s1[0][n1]),
                                    np.asarray(s8[0][n8]), rtol=1e-5,
                                    atol=1e-6, err_msg=f"{n1} vs {n8}")
@@ -256,7 +266,8 @@ def test_data_parallel_remat_matches():
     (p0, l0), (p1, l1) = outs
     assert np.isclose(l0, l1, rtol=1e-6)
     # the two nets carry different auto-prefixes; compare positionally
-    for k0, k1 in zip(sorted(p0), sorted(p1)):
+    for k0, k1 in zip(sorted(p0, key=_layer_order),
+                      sorted(p1, key=_layer_order)):
         np.testing.assert_allclose(p0[k0], p1[k1], rtol=1e-6, atol=1e-7)
 
 
