@@ -149,7 +149,9 @@ def check_serve_kernels(seed=0, slots=8, heads=8, dh=64, page_size=16):
     rng = np.random.RandomState(seed)
     npages, pool = 4, 4 * slots + 1
     q = jnp.asarray(rng.randn(slots, heads, dh).astype(np.float32))
-    shape = (pool, page_size, heads, dh)
+    # head-major, as the server keeps its pools: on the chip, rows of
+    # whole lane tiles, and the lanes past the head hold noise
+    shape = (heads, pool, page_size, pk.pool_lanes(dh))
     pt = jnp.asarray(1 + rng.permutation(pool - 1)[:slots * npages]
                      .reshape(slots, npages).astype(np.int32))
     lens = jnp.asarray(rng.randint(1, npages * page_size + 1, (slots,))
@@ -160,7 +162,7 @@ def check_serve_kernels(seed=0, slots=8, heads=8, dh=64, page_size=16):
            pk._paged_attention_lax(q, kp, vp, pt, lens))
     kq, vq = (jnp.asarray(rng.randint(-127, 128, shape).astype(np.int8))
               for _ in range(2))
-    sc = [jnp.asarray((rng.rand(pool, heads) * 0.05 + 1e-3)
+    sc = [jnp.asarray((rng.rand(heads, pool) * 0.05 + 1e-3)
                       .astype(np.float32)) for _ in range(2)]
     _agree("paged attention int8",
            pk.ragged_paged_attention(q, kq, vq, pt, lens,

@@ -43,6 +43,7 @@ from ..tune import overrides as _tune_overrides
 __all__ = ["flash_attention", "flash_block_attention", "fused_layer_norm",
            "attention_reference", "on_tpu", "conv1x1_bn_stats",
            "single_query_cached_attention", "ragged_paged_attention",
+           "pool_lanes",
            "kernel_mesh"]
 
 
@@ -825,14 +826,29 @@ def single_query_cached_attention(qh, kc, vc, mask=None):
     return jnp.einsum("bhqk,bhkd->bhqd", p, vc)
 
 
-def _dequant_gathered(pages, page_tables, scales, dtype):
-    """Gather (S, npages, psize, H, dh) pages; with per-page (P, H)
-    `scales` (int8 KV mode, ISSUE 14) dequantize the gathered context —
-    never the whole pool — into `dtype`."""
-    ctx = pages[page_tables]
+def pool_lanes(head_dim):
+    """The row width to keep a head-major (H, P, psize, lanes) pool at.
+    On the TPU the head size rounded up to whole 128-lane tiles: the
+    client's default layout for such an array is row-major, which is
+    what `_rpa_kernel`'s (psize, lanes) blocks read; an array whose
+    minor dimension is under 128 it lays out with another dimension in
+    the lanes (to save the padding), and every program over it then
+    copies the pool into the kernel's layout and back. Elsewhere there
+    is no such layout, and the head size itself."""
+    return -(-head_dim // 128) * 128 if on_tpu() else head_dim
+
+
+def _dequant_gathered(pages, page_tables, scales, dtype, dh):
+    """Each slot's pages out of a head-major (H, P, psize, lanes) pool
+    as the dense (S, H, npages * psize, dh) context the shared math
+    takes; with per-page (H, P) `scales` (int8 KV mode, ISSUE 14)
+    dequantize the gathered context — never the whole pool — into
+    `dtype`."""
+    ctx = pages[:, page_tables][..., :dh]     # (H, S, npages, psize, dh)
     if scales is not None:
-        ctx = ctx.astype(dtype) * scales[page_tables][:, :, None, :, None]
-    return ctx
+        ctx = ctx.astype(dtype) * scales[:, page_tables][..., None, None]
+    H, S, npages, psize, _ = ctx.shape
+    return ctx.reshape(H, S, npages * psize, dh).transpose(1, 0, 2, 3)
 
 
 def _paged_attention_lax(q, k_pages, v_pages, page_tables, lengths,
@@ -841,20 +857,16 @@ def _paged_attention_lax(q, k_pages, v_pages, page_tables, lengths,
     then run the SAME shared math as the dense decoder (so CPU serving is
     bitwise-parity with `decode_step` on equal context width).
 
-    q: (S, H, dh); k_pages/v_pages: (P, psize, H, dh);
+    q: (S, H, dh); k_pages/v_pages: (H, P, psize, lanes >= dh);
     page_tables: (S, npages) int32; lengths: (S,) int32 valid positions
-    (including the current token). k_scales/v_scales: optional (P, H)
-    per-page/per-head dequant scales for int8 page pools (ISSUE 14) —
+    (including the current token). k_scales/v_scales: optional (H, P)
+    per-head/per-page dequant scales for int8 page pools (ISSUE 14) —
     only the GATHERED context dequantizes, never the pool. Returns
     (S, H, dh)."""
-    S, H, dh = q.shape
-    psize = k_pages.shape[1]
-    npages = page_tables.shape[1]
-    L = npages * psize
-    kc = _dequant_gathered(k_pages, page_tables, k_scales, q.dtype) \
-        .reshape(S, L, H, dh).transpose(0, 2, 1, 3)
-    vc = _dequant_gathered(v_pages, page_tables, v_scales, q.dtype) \
-        .reshape(S, L, H, dh).transpose(0, 2, 1, 3)
+    dh = q.shape[-1]
+    kc = _dequant_gathered(k_pages, page_tables, k_scales, q.dtype, dh)
+    vc = _dequant_gathered(v_pages, page_tables, v_scales, q.dtype, dh)
+    L = kc.shape[2]
     mask = (jnp.arange(L)[None, :] < lengths[:, None])[:, None, None, :]
     return single_query_cached_attention(q[:, :, None, :], kc, vc,
                                          mask)[:, :, 0]
@@ -871,14 +883,11 @@ def _paged_attention_lax_multi(q, k_pages, v_pages, page_tables, lengths,
     position); query i sees exactly `lengths + i` keys, which is the
     ragged-per-slot-query-length shape speculative verification and
     chunked prompt prefill need. Returns (S, W, H, dh)."""
-    S, W, H, dh = q.shape
-    psize = k_pages.shape[1]
-    npages = page_tables.shape[1]
-    L = npages * psize
-    kc = _dequant_gathered(k_pages, page_tables, k_scales, q.dtype) \
-        .reshape(S, L, H, dh).transpose(0, 2, 1, 3)
-    vc = _dequant_gathered(v_pages, page_tables, v_scales, q.dtype) \
-        .reshape(S, L, H, dh).transpose(0, 2, 1, 3)
+    W = q.shape[1]
+    dh = q.shape[-1]
+    kc = _dequant_gathered(k_pages, page_tables, k_scales, q.dtype, dh)
+    vc = _dequant_gathered(v_pages, page_tables, v_scales, q.dtype, dh)
+    L = kc.shape[2]
     vis = lengths[:, None] + jnp.arange(W, dtype=lengths.dtype)[None, :]
     mask = (jnp.arange(L)[None, None, :]
             < vis[:, :, None])[:, None, :, :]        # (S, 1, W, L)
@@ -978,9 +987,14 @@ def _rpa_kernel(*refs, psize, block_k, num_heads, window, sm_scale,
 
 def _rpa_pallas(q, k_pages, v_pages, page_tables, lengths, sm_scale,
                 k_scales=None, v_scales=None):
-    """q: (S, W, H, dh); returns (S, W, H, dh)."""
+    """q: (S, W, H, dh); pools (H, P, psize, lanes), the shape the block
+    specs read: one (slot, head, page) block is a (psize, lanes) tile of
+    the pool where it lies, so nothing of a pool's size is made around
+    the kernel; int8 scales (H, P). The kernel sees a head `lanes` wide:
+    the query's lanes past dh are zero, the output's are dropped.
+    Returns (S, W, H, dh)."""
     S, W, H, dh = q.shape
-    psize = k_pages.shape[1]
+    psize, lanes = k_pages.shape[2:]
     npages = page_tables.shape[1]
     quant = k_scales is not None
     bk = _rpa_block_k(psize)
@@ -989,12 +1003,8 @@ def _rpa_pallas(q, k_pages, v_pages, page_tables, lengths, sm_scale,
     # tuner sublane count); the extra rows are sliced away below
     wp = _rpa_sublanes(W)
     qr = q.transpose(0, 2, 1, 3).reshape(S * H, W, dh)
-    if wp != W:
-        qr = jnp.pad(qr, ((0, 0), (0, wp - W), (0, 0)))
-    # page-major layout for the kernel: (H, P, psize, dh) so one (slot,
-    # head, page) block is a contiguous (psize, dh) tile
-    kr = k_pages.transpose(2, 0, 1, 3)
-    vr = v_pages.transpose(2, 0, 1, 3)
+    if wp != W or lanes != dh:
+        qr = jnp.pad(qr, ((0, 0), (0, wp - W), (0, lanes - dh)))
     grid = (S * H, npages * npb)
     kern = functools.partial(_rpa_kernel, psize=psize, block_k=bk,
                              num_heads=H, window=W, sm_scale=sm_scale,
@@ -1004,41 +1014,42 @@ def _rpa_pallas(q, k_pages, v_pages, page_tables, lengths, sm_scale,
         num_scalar_prefetch=nsp,        # page tables + lengths (+ scales)
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, wp, dh), lambda g, j, pt, ln, *_: (g, 0, 0)),
+            pl.BlockSpec((1, wp, lanes),
+                         lambda g, j, pt, ln, *_: (g, 0, 0)),
             # the paged gather: the page id comes from the scalar-
             # prefetched table, so the DMA fetches exactly the pages the
             # slot owns — never a dense (S, Lmax) context; with bk <
             # psize the dim-2 block index walks the npb tiles of a page
-            pl.BlockSpec((1, 1, bk, dh),
+            pl.BlockSpec((1, 1, bk, lanes),
                          lambda g, j, pt, ln, *_, _h=H, _b=npb:
                          (g % _h, pt[g // _h, j // _b], j % _b, 0)),
-            pl.BlockSpec((1, 1, bk, dh),
+            pl.BlockSpec((1, 1, bk, lanes),
                          lambda g, j, pt, ln, *_, _h=H, _b=npb:
                          (g % _h, pt[g // _h, j // _b], j % _b, 0)),
         ],
-        out_specs=pl.BlockSpec((1, wp, dh),
+        out_specs=pl.BlockSpec((1, wp, lanes),
                                lambda g, j, pt, ln, *_: (g, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((wp, 128), jnp.float32),
             pltpu.VMEM((wp, 128), jnp.float32),
-            pltpu.VMEM((wp, dh), jnp.float32),
+            pltpu.VMEM((wp, lanes), jnp.float32),
         ],
     )
     scal = (page_tables.astype(jnp.int32), lengths.astype(jnp.int32))
     if quant:
         # (H, P) f32 in SMEM: the kernel reads one scalar per grid step
-        scal += (k_scales.astype(jnp.float32).T,
-                 v_scales.astype(jnp.float32).T)
+        scal += (k_scales.astype(jnp.float32),
+                 v_scales.astype(jnp.float32))
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=_sds((S * H, wp, dh), q.dtype, q, k_pages, v_pages),
+        out_shape=_sds((S * H, wp, lanes), q.dtype, q, k_pages, v_pages),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
         name="mxtpu_rpa",
-    )(*scal, qr, kr, vr)
-    return out[:, :W].reshape(S, H, W, dh).transpose(0, 2, 1, 3)
+    )(*scal, qr, k_pages, v_pages)
+    return out[:, :W, :dh].reshape(S, H, W, dh).transpose(0, 2, 1, 3)
 
 
 def _rpa_flat_kernel(*refs, psize, pps, kv_heads, rows, window, group,
@@ -1167,10 +1178,15 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lengths,
     ragged per-slot-query-length shape speculative verification and
     chunked prompt prefill use (query i of a slot sees `lengths + i`
     keys; rows past a slot's real window compute garbage nobody reads).
-    k_pages/v_pages: (P, psize, H, dh) fixed-size page pools, or the same
-    with the last two dims merged, (P, psize, H * dh): the shape to KEEP
-    a pool in when dh is a multiple of 128, because `_rpa_flat_kernel`
-    then reads it where it lies, a slot's heads and several pages a step;
+    k_pages/v_pages: fixed-size page pools in the shape their kernel
+    reads where they lie, so that a decode program makes nothing of a
+    pool's size: head-major (H, P, psize, lanes) for `_rpa_kernel` (one
+    (slot, head, page) block a step; a row holds the head's dh values
+    and zeros up to `lanes`: KEEP a pool at `pool_lanes(dh)`, any width
+    from dh up is read), or page-major with the heads merged into the
+    lanes, (P, psize, H * dh), the shape to KEEP a pool in when dh is a
+    multiple of 128, because `_rpa_flat_kernel` then takes a slot's
+    heads and several pages a step;
     page_tables: (S, npages) int32 page ids per slot (unused entries
     must point at a valid page — the pool's reserved null page 0);
     lengths: (S,) int32 valid cached positions per slot INCLUDING the
@@ -1181,12 +1197,12 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lengths,
     the groups as they are; every other form repeats the KV heads and
     takes the plain path.
 
-    k_scales/v_scales (ISSUE 14): per-page/per-head (P, H) f32 dequant
-    scales for int8 page pools. The Pallas kernels carry them through
-    scalar prefetch (f32 in SMEM) and dequantize each page block in
-    VMEM after the DMA — HBM traffic stays int8, the dequant rides free
-    inside the kernel; the lax fallback dequantizes only the GATHERED
-    context.
+    k_scales/v_scales (ISSUE 14): per-head/per-page (H, P) f32 dequant
+    scales for int8 head-major page pools. The Pallas kernels carry them
+    through scalar prefetch (f32 in SMEM) and dequantize each page block
+    in VMEM after the DMA — HBM traffic stays int8, the dequant rides
+    free inside the kernel; the lax fallback dequantizes only the
+    GATHERED context.
 
     On TPU (or MXTPU_PALLAS_INTERPRET=1) runs the Pallas kernel: the page
     table rides in scalar-prefetch SMEM and the BlockSpec index maps read
@@ -1211,18 +1227,19 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lengths,
                                    k_pages, v_pages, page_tables, lengths,
                                    sm_scale)
             return out if q.ndim == 4 else out[:, 0]
-        k_pages, v_pages = (p.reshape(*p.shape[:2], -1, q.shape[-1])
-                            for p in (k_pages, v_pages))
+        k_pages, v_pages = (
+            p.reshape(*p.shape[:2], -1, q.shape[-1]).transpose(2, 0, 1, 3)
+            for p in (k_pages, v_pages))
     # grouped KV heads outside the flat kernel (a head size under 128, or
     # head-major pools): the plain path over repeated heads, until a
     # configuration needs a kernel there
-    rep = q.shape[-2] // k_pages.shape[2]
+    rep = q.shape[-2] // k_pages.shape[0]
     if rep > 1:
-        k_pages, v_pages = (jnp.repeat(p, rep, 2) for p in (k_pages, v_pages))
+        k_pages, v_pages = (jnp.repeat(p, rep, 0) for p in (k_pages, v_pages))
         if k_scales is not None:
-            k_scales, v_scales = (jnp.repeat(sc, rep, 1)
+            k_scales, v_scales = (jnp.repeat(sc, rep, 0)
                                   for sc in (k_scales, v_scales))
-    if rep > 1 or not _rpa_pallas_ok(k_pages.shape[1]):
+    if rep > 1 or not _rpa_pallas_ok(k_pages.shape[2]):
         lax_fn = (_paged_attention_lax_multi if q.ndim == 4
                   else _paged_attention_lax)
         return lax_fn(q, k_pages, v_pages, page_tables, lengths,

@@ -16,7 +16,10 @@ Pieces (docs/SERVING.md has the full design):
     prefill (pure encoder + cross-attention K/V into a slot, donated
     buffers) and decode (in-place paged K/V writes + ONE shared
     `ragged_paged_attention` launch for all slots, static
-    (slots, page_budget) shapes, zero retraces across occupancy).
+    (slots, page_budget) shapes, zero retraces across occupancy); the
+    K/V pools are one head-major (H, P, psize, lanes) array a layer,
+    the shape the attention kernel reads in place, so a turn copies
+    none.
   * `scheduler.Scheduler` — continuous batching: admit into free slots
     every step, evict finished requests immediately, bounded admission
     queue with `ServeOverloaded` backpressure, page-exhaustion

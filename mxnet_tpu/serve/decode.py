@@ -12,6 +12,23 @@ DONATED to the executable, so the per-step page writes are in-place
 scatters into the same device buffers — the paged cache never doubles in
 HBM.
 
+The pools are ONE array a layer, kept head-major `(H, P, psize, lanes)`:
+the shape `mxtpu_rpa`'s block specs read (one (slot, head, page) block
+is a `(psize, lanes)` tile), so the kernel takes a pool where it lies
+and a decode or verify program holds no operation whose result has a
+pool's size (tests/test_tpu_compile.py pins that on the chip's own
+compiler). Two things keep it so. A row is `pool_lanes(dh)` wide, the
+head's values and zeros up to whole 128-lane tiles: the device then
+lays the array out row-major, as the kernel needs it (a minor dimension
+under 128 it lays out of the lanes, and the program copies every pool
+into the kernel's layout and back, every turn); the price is HBM, twice
+the logical bytes for 64-wide heads. And a page write scatters one
+head's row at a time (`_rows`), because XLA's scatter wants its window
+minor-most and would otherwise copy the pool page-major and back around
+each write. A page id indexes axis 1 of every pool and of the int8
+scales; `serve.lm_runtime.LMRuntime` keeps its pools on the same
+principle, in the flat shape its kernel reads.
+
 Slot conventions (shared with serve.scheduler):
 
   * inactive slots route their scatter writes to the pool's reserved null
@@ -30,10 +47,12 @@ bitwise-identical to the dense-cache `decode_step` on equal context
 width (tests/test_serve.py pins this).
 
 Int8 KV cache (ISSUE 14, ``kv_dtype="int8"``): the page pools store
-int8 with PER-PAGE / PER-HEAD f32 scales in parallel ``(L, P, H)``
-arrays, so a fixed HBM page budget holds ~4x the tokens of fp32 pages
-(~2x bf16) — directly more concurrent requests per chip on the
-bandwidth-bound decode loop. Writes keep a RUNNING-MAX scale per page:
+int8 with PER-PAGE / PER-HEAD f32 scales in parallel ``(H, P)``
+arrays, one a layer, so a fixed HBM page budget holds ~4x the tokens
+of fp32 pages (~2x bf16) — directly more concurrent requests per chip
+on the bandwidth-bound decode loop (on the TPU an int8 tile holds 32
+rows, so pages under 32 tokens reach 2x, not 4x). Writes keep a
+RUNNING-MAX scale per page:
 a token whose |K| exceeds the page's current range grows the scale and
 requantises the page's existing rows in the same fused scatter (exact
 no-op when the scale doesn't move — ratio 1.0 round-trips int8
@@ -63,47 +82,57 @@ from ..models.transformer import (decode_embed, decode_project,
 from ..observability import registry as _obs_registry
 from ..observability import tracer as _tracer
 from ..observability import compilex as _compilex
-from ..ops.pallas_kernels import ragged_paged_attention
+from ..ops.pallas_kernels import pool_lanes, ragged_paged_attention
 from .kv_pages import NULL_PAGE
 
 __all__ = ["DecodeRuntime", "MemoryStateLost"]
 
 
-def _quant_page_write(pages, scales, li, page, off, vals):
+def _rows(pages, page, off=None):
+    """The index of every head's rows at `page` (and `off`) of one
+    head-major pool, the head a scatter index of its own: the update
+    window is then ONE head's (psize, lanes) block or (lanes,) row,
+    minor-most where it lies. (`pages.at[:, page, off]` names the same
+    elements with a window across the heads, which XLA's TPU scatter
+    serves by copying the pool page-major and back.)"""
+    heads = jnp.arange(pages.shape[0]).reshape((-1,) + (1,) * page.ndim)
+    return (heads, page[None]) if off is None else (heads, page[None],
+                                                     off[None])
+
+
+def _quant_page_write(pages, scales, page, off, vals):
     """Quantised paged K/V write with running-max per-page/per-head
-    scales (ISSUE 14). pages: (L, P, psize, H, dh) int8; scales:
-    (L, P, H) f32; page/off: (...,) int32 target page ids/offsets
-    (inactive rows routed to the null page by the caller); vals:
-    (..., H, dh) fp token projections. Leading dims are (S,) for the
-    1-wide decode program and (S, W) for the widened verify program —
-    duplicate page ids within a window are safe because every duplicate
-    computes identical update values (scatter-max for scales, identical
-    requantised blocks for content). Returns (pages, scales)."""
+    scales (ISSUE 14). pages: one layer's (H, P, psize, lanes) int8
+    pool; scales: its (H, P) f32; page/off: (...,) int32 target page
+    ids/offsets (inactive rows routed to the null page by the caller);
+    vals: (H, ..., lanes) fp token projections. The dims between are
+    (S,) for the 1-wide decode program and (S, W) for the widened verify
+    program — duplicate page ids within a window are safe because every
+    duplicate computes identical update values (scatter-max for scales,
+    identical requantised blocks for content). Returns (pages, scales)."""
     f32 = scales.dtype
-    amax = jnp.max(jnp.abs(vals.astype(f32)), axis=-1)       # (..., H)
+    amax = jnp.max(jnp.abs(vals.astype(f32)), axis=-1)       # (H, ...)
     # a write at offset 0 starts the page's life: zero the stale content
     # AND scale a previous owner left behind (scales only ever grow
     # within a life, so without the reset a hot former tenant would
     # permanently coarsen the page's quantisation grid)
     fresh_page = jnp.zeros((pages.shape[1],), bool).at[
         jnp.where(off == 0, page, NULL_PAGE)].set(True)
-    sc = scales[li]                                          # (P, H)
-    sc0 = jnp.where(fresh_page[:, None], jnp.float32(0), sc)
-    new_sc = sc0.at[page].max(amax / 127.0)
-    old_g = sc[page]                                         # (..., H)
-    new_g = new_sc[page]
+    at_page = _rows(pages, page)
+    sc0 = jnp.where(fresh_page, jnp.float32(0), scales)      # (H, P)
+    new_sc = sc0.at[at_page].max(amax / 127.0)
+    old_g = scales[at_page]                                  # (H, ...)
+    new_g = new_sc[at_page]
     safe = jnp.maximum(new_g, 1e-30)
     ratio = jnp.where(new_g > 0, old_g / safe, jnp.float32(1))
-    blk = pages[li, page].astype(f32)                # (..., psize, H, dh)
-    blk = jnp.round(blk * ratio[..., None, :, None])
-    blk = jnp.where(fresh_page[page][..., None, None, None],
-                    jnp.float32(0), blk)
+    blk = pages[at_page].astype(f32)              # (H, ..., psize, lanes)
+    blk = jnp.round(blk * ratio[..., None, None])
+    blk = jnp.where(fresh_page[page][..., None, None], jnp.float32(0), blk)
     tok = jnp.clip(jnp.round(vals.astype(f32) / safe[..., None]),
                    -127, 127)
-    pages = pages.at[li, page].set(blk.astype(jnp.int8))
-    pages = pages.at[li, page, off].set(tok.astype(jnp.int8))
-    scales = scales.at[li].set(new_sc)
-    return pages, scales
+    pages = pages.at[at_page].set(blk.astype(jnp.int8))
+    pages = pages.at[_rows(pages, page, off)].set(tok.astype(jnp.int8))
+    return pages, new_sc
 
 
 class MemoryStateLost(MXNetError):
@@ -175,30 +204,19 @@ class DecodeRuntime:
         # recompiles against these counters). int8-KV runtimes publish
         # under their own *_int8 names so the quantized-serve budgets
         # (check_fusion) and the fp budgets never shadow each other.
-        if self.kv_quant:
-            self._decode_fn = _compilex.instrument(
-                jax.jit(self._decode_program_q,
-                        donate_argnums=(0, 1, 2, 3)),
-                "serve_decode_int8")
-        else:
-            self._decode_fn = _compilex.instrument(
-                jax.jit(self._decode_program, donate_argnums=(0, 1)),
-                "serve_decode")
+        int8 = "_int8" if self.kv_quant else ""
+        self._decode_fn = _compilex.instrument(
+            jax.jit(self._decode_program, donate_argnums=(0,)),
+            "serve_decode" + int8)
         self._prefill_fn = _compilex.instrument(
             jax.jit(self._prefill_program, donate_argnums=(0, 1, 2)),
             "serve_prefill")
-        if self.kv_quant:
-            self._remap_fn = _compilex.instrument(
-                jax.jit(lambda kp, vp, ks, vs, perm:
-                        (kp[:, perm], vp[:, perm],
-                         ks[:, perm], vs[:, perm]),
-                        donate_argnums=(0, 1, 2, 3)),
-                "serve_page_remap")
-        else:
-            self._remap_fn = _compilex.instrument(
-                jax.jit(lambda kp, vp, perm: (kp[:, perm], vp[:, perm]),
-                        donate_argnums=(0, 1)),
-                "serve_page_remap")
+        # every pool and every scale array has its pages on axis 1 (the
+        # full-precision runtime's scales are None: no leaves)
+        self._remap_fn = _compilex.instrument(
+            jax.jit(lambda pools, perm: jax.tree_util.tree_map(
+                lambda p: p[:, perm], pools), donate_argnums=(0,)),
+            "serve_page_remap")
         # the WIDENED verify executable (ISSUE 12): width > 1 servers run
         # every decode turn through one (slots, width) program — drafted
         # tokens verified by a single batched target pass, chunked prompt
@@ -207,15 +225,9 @@ class DecodeRuntime:
         # draft acceptance never retraces (verify_traces stays 1).
         self._verify_fn = None
         if self.width > 1:
-            if self.kv_quant:
-                self._verify_fn = _compilex.instrument(
-                    jax.jit(self._verify_program_q,
-                            donate_argnums=(0, 1, 2, 3)),
-                    "serve_verify_int8")
-            else:
-                self._verify_fn = _compilex.instrument(
-                    jax.jit(self._verify_program, donate_argnums=(0, 1)),
-                    "serve_verify")
+            self._verify_fn = _compilex.instrument(
+                jax.jit(self._verify_program, donate_argnums=(0,)),
+                "serve_verify" + int8)
         # autotune (ISSUE 20): greedy decode is bitwise-contracted — a
         # compile-space candidate that moves ONE logit bit is rejected
         # by the search guard regardless of speed; these executables are
@@ -239,20 +251,46 @@ class DecodeRuntime:
         return [bos_id], 0
 
     # ------------------------------------------------------- programs
-    # ONE decode/verify core each, shared by the fp and int8-KV entry
-    # points (`k_scales is None` selects the write/attention form at
-    # TRACE time — the fp programs lower to exactly the pre-ISSUE-14
-    # HLO, so a decode-loop fix can never reach one precision and miss
-    # the other).
-    def _page_write(self, pages, scales, li, page, off, vals):
-        if scales is None:
-            return pages.at[li, page, off].set(vals), None
-        return _quant_page_write(pages, scales, li, page, off, vals)
+    # The decode and verify executables are `program(pools, inputs) ->
+    # (pools, outputs)` with pools = (k_pages, v_pages, k_scales,
+    # v_scales), donated so that page writes are in place, the scales
+    # None without int8 KV (`k_scales is None` selects the
+    # write/attention form at TRACE time — the fp programs hold nothing
+    # of the int8 ones, and a decode-loop fix can never reach one
+    # precision and miss the other).
+    def _pools(self):
+        return self.k_pages, self.v_pages, self.k_scales, self.v_scales
 
-    def _decode_core(self, k_pages, v_pages, k_scales, v_scales,
-                     page_tables, lens, tok, active, mem_k, mem_v,
-                     mem_vl):
+    def _cached_attention(self, pools, li, page, off, qh, kh, vh,
+                          page_tables, lens):
+        """Layer `li`'s share of a turn: the tokens' K/V rows (..., H, dh)
+        into its pools at (page, off), in place in the lists of `pools`,
+        then the shared attention launch over those pools where they
+        lie."""
+        k_pages, v_pages, k_scales, v_scales = pools
+        pad = [(0, 0)] * kh.ndim
+        pad[-1] = (0, k_pages[li].shape[-1] - self._dh)
+        for pages, scales, vals in ((k_pages, k_scales, kh),
+                                    (v_pages, v_scales, vh)):
+            vals = jnp.pad(jnp.moveaxis(vals, -2, 0), pad)  # (H, ..., lanes)
+            if scales is None:
+                pages[li] = pages[li].at[_rows(pages[li], page, off)].set(
+                    vals)
+            else:
+                pages[li], scales[li] = _quant_page_write(
+                    pages[li], scales[li], page, off, vals)
+        return ragged_paged_attention(
+            qh, k_pages[li], v_pages[li], page_tables, lens + 1,
+            k_scales=None if k_scales is None else k_scales[li],
+            v_scales=None if v_scales is None else v_scales[li])
+
+    def _decode_program(self, pools, inputs):
+        """One token a slot. inputs: (page_tables, lens, tok, active,
+        mem_k, mem_v, mem_vl); outputs: (next_tok, logits)."""
+        self.decode_traces += 1
+        page_tables, lens, tok, active, mem_k, mem_v, mem_vl = inputs
         w, h, psize = self._w, self._h, self.page_size
+        pools = [None if a is None else list(a) for a in pools]
         s_n = tok.shape[0]
         x = decode_embed(w, tok, lens)                       # (S, U)
         rows = jnp.arange(s_n)
@@ -264,32 +302,30 @@ class DecodeRuntime:
             qh = q.reshape(s_n, h, self._dh)
             kh = k.reshape(s_n, h, self._dh)
             vh = v.reshape(s_n, h, self._dh)
-            k_pages, k_scales = self._page_write(
-                k_pages, k_scales, li, page, off, kh)
-            v_pages, v_scales = self._page_write(
-                v_pages, v_scales, li, page, off, vh)
-            a = ragged_paged_attention(
-                qh, k_pages[li], v_pages[li], page_tables, lens + 1,
-                k_scales=None if k_scales is None else k_scales[li],
-                v_scales=None if v_scales is None else v_scales[li])
+            a = self._cached_attention(pools, li, page, off, qh, kh, vh,
+                                       page_tables, lens)
             x = decoder_layer_self_post(L, x, a.reshape(s_n, h * self._dh))
             x = decoder_layer_cross(L, h, x, mem_k[li], mem_v[li], mem_vl)
             x = decoder_layer_ffn(L, x)
         logits = decode_project(w, x)
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return k_pages, v_pages, k_scales, v_scales, next_tok, logits
+        return tuple(pools), (next_tok, logits)
 
-    def _verify_core(self, k_pages, v_pages, k_scales, v_scales,
-                     page_tables, lens, toks, qlens, active, mem_k,
-                     mem_v, mem_vl):
-        """The widened decode step: toks (S, W) window tokens per slot at
-        positions lens..lens+W-1, qlens (S,) valid window lengths (ragged
-        — rows past qlen scatter to the null page and their outputs are
-        garbage the scheduler never commits). Returns logits for EVERY
-        window position, so one dispatch verifies a whole drafted run.
+    def _verify_program(self, pools, inputs):
+        """The widened decode step. inputs: (page_tables, lens, toks,
+        qlens, active, mem_k, mem_v, mem_vl): toks (S, W) window tokens
+        per slot at positions lens..lens+W-1, qlens (S,) valid window
+        lengths (ragged — rows past qlen scatter to the null page and
+        their outputs are garbage the scheduler never commits). outputs:
+        (next_tok, logits) for EVERY window position, so one dispatch
+        verifies a whole drafted run.
         int8 mode: window writes that share a page combine through the
         quantised write helper's scatter-max scales."""
+        self.verify_traces += 1
+        (page_tables, lens, toks, qlens, active, mem_k, mem_v,
+         mem_vl) = inputs
         w, h, psize = self._w, self._h, self.page_size
+        pools = [None if a is None else list(a) for a in pools]
         s_n, width = toks.shape
         npages = page_tables.shape[1]
         rows = jnp.arange(s_n)
@@ -306,16 +342,10 @@ class DecodeRuntime:
             qh = q.reshape(s_n, width, h, self._dh)
             kh = k.reshape(s_n, width, h, self._dh)
             vh = v.reshape(s_n, width, h, self._dh)
-            k_pages, k_scales = self._page_write(
-                k_pages, k_scales, li, page, off, kh)
-            v_pages, v_scales = self._page_write(
-                v_pages, v_scales, li, page, off, vh)
             # query i sees positions 0..lens+i (its own included): the
             # ragged-query-length form of the shared paged attention
-            a = ragged_paged_attention(
-                qh, k_pages[li], v_pages[li], page_tables, lens + 1,
-                k_scales=None if k_scales is None else k_scales[li],
-                v_scales=None if v_scales is None else v_scales[li])
+            a = self._cached_attention(pools, li, page, off, qh, kh, vh,
+                                       page_tables, lens)
             x = decoder_layer_self_post(
                 L, x, a.reshape(s_n, width, h * self._dh))
             x = decoder_layer_cross_multi(L, h, x, mem_k[li], mem_v[li],
@@ -323,45 +353,7 @@ class DecodeRuntime:
             x = decoder_layer_ffn(L, x)
         logits = decode_project(w, x)                    # (S, W, V)
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return k_pages, v_pages, k_scales, v_scales, next_tok, logits
-
-    def _decode_program(self, k_pages, v_pages, page_tables, lens, tok,
-                        active, mem_k, mem_v, mem_vl):
-        self.decode_traces += 1
-        k_pages, v_pages, _, _, next_tok, logits = self._decode_core(
-            k_pages, v_pages, None, None, page_tables, lens, tok,
-            active, mem_k, mem_v, mem_vl)
-        return k_pages, v_pages, next_tok, logits
-
-    def _verify_program(self, k_pages, v_pages, page_tables, lens, toks,
-                        qlens, active, mem_k, mem_v, mem_vl):
-        self.verify_traces += 1
-        k_pages, v_pages, _, _, next_tok, logits = self._verify_core(
-            k_pages, v_pages, None, None, page_tables, lens, toks,
-            qlens, active, mem_k, mem_v, mem_vl)
-        return k_pages, v_pages, next_tok, logits
-
-    def _decode_program_q(self, k_pages, v_pages, k_scales, v_scales,
-                          page_tables, lens, tok, active, mem_k, mem_v,
-                          mem_vl):
-        """The int8-KV decode step (ISSUE 14): the shared core with
-        page writes through the running-max quantiser and the attention
-        launch dequantising with the per-page scales. All four pool
-        arrays are donated — still ONE dispatch, still zero retraces
-        across occupancy."""
-        self.decode_traces += 1
-        return self._decode_core(k_pages, v_pages, k_scales, v_scales,
-                                 page_tables, lens, tok, active, mem_k,
-                                 mem_v, mem_vl)
-
-    def _verify_program_q(self, k_pages, v_pages, k_scales, v_scales,
-                          page_tables, lens, toks, qlens, active, mem_k,
-                          mem_v, mem_vl):
-        """The int8-KV widened verify step (see `_verify_core`)."""
-        self.verify_traces += 1
-        return self._verify_core(k_pages, v_pages, k_scales, v_scales,
-                                 page_tables, lens, toks, qlens, active,
-                                 mem_k, mem_v, mem_vl)
+        return tuple(pools), (next_tok, logits)
 
     def _prefill_program(self, mem_k, mem_v, mem_vl, src, src_len, slot):
         self.prefill_traces += 1
@@ -427,18 +419,15 @@ class DecodeRuntime:
         ragged-paged-attention launch, returns (next_tok (S,) host int32,
         logits (S, V) device array)."""
         profiler.record_dispatch("serve_decode")
-        args = (jnp.asarray(page_tables, jnp.int32),
-                jnp.asarray(lens, jnp.int32), jnp.asarray(tok, jnp.int32),
-                jnp.asarray(active, jnp.int32),
-                self.mem_k, self.mem_v, self.mem_vl)
-        if self.kv_quant:
-            (self.k_pages, self.v_pages, self.k_scales, self.v_scales,
-             next_tok, logits) = self._decode_fn(
-                self.k_pages, self.v_pages, self.k_scales, self.v_scales,
-                *args)
-        else:
-            self.k_pages, self.v_pages, next_tok, logits = \
-                self._decode_fn(self.k_pages, self.v_pages, *args)
+        inputs = (jnp.asarray(page_tables, jnp.int32),
+                  jnp.asarray(lens, jnp.int32), jnp.asarray(tok, jnp.int32),
+                  jnp.asarray(active, jnp.int32),
+                  self.mem_k, self.mem_v, self.mem_vl)
+        return self._turn(self._decode_fn, inputs)
+
+    def _turn(self, fn, inputs):
+        (self.k_pages, self.v_pages, self.k_scales,
+         self.v_scales), (next_tok, logits) = fn(self._pools(), inputs)
         return np.asarray(next_tok), logits
 
     def decode_multi(self, page_tables, lens, toks, qlens, active):
@@ -453,20 +442,13 @@ class DecodeRuntime:
             raise MXNetError("decode_multi needs width > 1 (construct "
                              "DecodeRuntime(width=k+1))")
         profiler.record_dispatch("serve_decode")
-        args = (jnp.asarray(page_tables, jnp.int32),
-                jnp.asarray(lens, jnp.int32), jnp.asarray(toks, jnp.int32),
-                jnp.asarray(qlens, jnp.int32),
-                jnp.asarray(active, jnp.int32),
-                self.mem_k, self.mem_v, self.mem_vl)
-        if self.kv_quant:
-            (self.k_pages, self.v_pages, self.k_scales, self.v_scales,
-             next_tok, logits) = self._verify_fn(
-                self.k_pages, self.v_pages, self.k_scales, self.v_scales,
-                *args)
-        else:
-            self.k_pages, self.v_pages, next_tok, logits = \
-                self._verify_fn(self.k_pages, self.v_pages, *args)
-        return np.asarray(next_tok), logits
+        inputs = (jnp.asarray(page_tables, jnp.int32),
+                  jnp.asarray(lens, jnp.int32),
+                  jnp.asarray(toks, jnp.int32),
+                  jnp.asarray(qlens, jnp.int32),
+                  jnp.asarray(active, jnp.int32),
+                  self.mem_k, self.mem_v, self.mem_vl)
+        return self._turn(self._verify_fn, inputs)
 
     def remap_pages(self, mapping):
         """Apply a `PagePool.defrag()` renumbering to the device pools
@@ -479,37 +461,34 @@ class DecodeRuntime:
         for old, new in mapping.items():
             perm[new] = old
         profiler.record_dispatch("serve_page_remap")
-        if self.kv_quant:
-            (self.k_pages, self.v_pages, self.k_scales,
-             self.v_scales) = self._remap_fn(
-                self.k_pages, self.v_pages, self.k_scales, self.v_scales,
-                jnp.asarray(perm))
-        else:
-            self.k_pages, self.v_pages = self._remap_fn(
-                self.k_pages, self.v_pages, jnp.asarray(perm))
+        (self.k_pages, self.v_pages, self.k_scales,
+         self.v_scales) = self._remap_fn(self._pools(), jnp.asarray(perm))
 
     def reset_pages(self):
         """Drop ALL cached KV state, scales included (construction, and
         the scheduler's catastrophic failure path after an executable
         error, when page contents can no longer be trusted)."""
-        shape = (self._n_layers, self.num_pages, self.page_size, self._h,
-                 self._dh)
+        def per_layer(shape, dtype):
+            return [jnp.zeros(shape, dtype) for _ in range(self._n_layers)]
+
+        pool = (self._h, self.num_pages, self.page_size,
+                pool_lanes(self._dh))
+        dtype = jnp.int8 if self.kv_quant else self._dtype
+        self.k_pages, self.v_pages = (per_layer(pool, dtype)
+                                      for _ in range(2))
+        self.k_scales = self.v_scales = None
         if self.kv_quant:
-            self.k_pages = jnp.zeros(shape, jnp.int8)
-            self.v_pages = jnp.zeros(shape, jnp.int8)
-            sshape = (self._n_layers, self.num_pages, self._h)
-            self.k_scales = jnp.zeros(sshape, jnp.float32)
-            self.v_scales = jnp.zeros(sshape, jnp.float32)
+            self.k_scales, self.v_scales = (
+                per_layer(pool[:2], jnp.float32) for _ in range(2))
             _obs_registry().gauge("kv_page_scale_bytes").set(
-                2 * self.k_scales.size * 4)
-        else:
-            self.k_pages = jnp.zeros(shape, self._dtype)
-            self.v_pages = jnp.zeros(shape, self._dtype)
-            self.k_scales = self.v_scales = None
+                2 * self._n_layers * self._h * self.num_pages * 4)
 
     def kv_bytes_per_page(self):
-        """Device bytes one page costs in THIS runtime's layout (K + V
-        across layers; int8 mode includes the per-page scale rows)."""
+        """Bytes of the values one page holds (K + V across layers; int8
+        mode includes the per-page scale rows): what a page costs in a
+        pool of `head_dim`-wide rows. The device pools keep a row at
+        whole 128-lane tiles (`pool_lanes`), so heads under 128 wide
+        cost HBM in proportion."""
         from .quant import kv_page_bytes
         return kv_page_bytes(
             self._n_layers, self.page_size, self._h, self._dh,

@@ -227,8 +227,8 @@ def test_search_rejects_dead_pallas_override():
     try:
         rng = np.random.RandomState(0)
         q = jnp.asarray(rng.randn(2, 2, 8).astype(np.float32))
-        kp = jnp.asarray(rng.randn(5, 16, 2, 8).astype(np.float32))
-        vp = jnp.asarray(rng.randn(5, 16, 2, 8).astype(np.float32))
+        kp = jnp.asarray(rng.randn(2, 5, 16, 8).astype(np.float32))
+        vp = jnp.asarray(rng.randn(2, 5, 16, 8).astype(np.float32))
         pt = jnp.asarray(np.array([[1, 2], [3, 0]], np.int32))
         ln = jnp.asarray(np.array([20, 7], np.int32))
 

@@ -387,6 +387,8 @@ def test_grouped_matmul_kernel_equals_the_row_by_row_product(interpret):
 
 # ------------------------------------------- grouped-KV paged attention
 def _paged_case(rng, dtype, dh=128):
+    """Pools page by page, (P, psize, H, dh): what `_dense_attention`
+    reads; `_flat` and `_head_major` give the two forms a pool is kept in."""
     s, hq, h, psize, npg, pool = 4, 8, 2, 16, 11, 60
     q = jnp.asarray(rng.normal(size=(s, hq, dh)), dtype)
     kp = jnp.asarray(rng.normal(size=(pool, psize, h, dh)), dtype)
@@ -399,6 +401,14 @@ def _paged_case(rng, dtype, dh=128):
         tables[i, :n] = perm[c:c + n]
         c += n
     return q, kp, vp, jnp.asarray(tables), jnp.asarray(lens)
+
+
+def _flat(*pools):
+    return [p.reshape(*p.shape[:2], -1) for p in pools]
+
+
+def _head_major(*pools):
+    return [p.transpose(2, 0, 1, 3) for p in pools]
 
 
 def _dense_attention(q, kp, vp, tables, lens):
@@ -428,9 +438,8 @@ def test_paged_attention_with_grouped_kv_heads(form, monkeypatch):
     q, kp, vp, tables, lens = _paged_case(np.random.default_rng(15),
                                           jnp.float32)
     want = _dense_attention(q, kp, vp, tables, lens)
-    if form == "kernel-flat-pool":
-        kp, vp = (p.reshape(*p.shape[:2], -1) for p in (kp, vp))
-    got = pk.ragged_paged_attention(q, kp, vp, tables, lens)
+    pools = (_flat if form == "kernel-flat-pool" else _head_major)(kp, vp)
+    got = pk.ragged_paged_attention(q, *pools, tables, lens)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
@@ -439,10 +448,9 @@ def test_flat_pool_kernel_takes_a_window_and_bfloat16(interpret,
     rng = np.random.default_rng(16)
     q, kp, vp, tables, lens = _paged_case(rng, jnp.bfloat16)
     q4 = jnp.asarray(rng.normal(size=(4, 3, 8, 128)), jnp.bfloat16)
-    flat = [p.reshape(*p.shape[:2], -1) for p in (kp, vp)]
-    got = pk.ragged_paged_attention(q4, *flat, tables, lens)
+    got = pk.ragged_paged_attention(q4, *_flat(kp, vp), tables, lens)
     monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "0")
-    want = pk.ragged_paged_attention(q4, kp, vp, tables, lens)
+    want = pk.ragged_paged_attention(q4, *_head_major(kp, vp), tables, lens)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=0.05)
 

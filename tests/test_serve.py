@@ -106,50 +106,63 @@ def test_page_pool_defrag_mapping():
 
 
 # ----------------------------------------------- ragged paged attention
-def _paged_fixture(seed=0, S=3, H=2, dh=8, P=9, psize=8, npages=2):
+def _paged_fixture(seed=0, S=3, H=2, dh=8, P=9, psize=8, npages=2,
+                   lanes=None):
+    """Head-major pools (H, P, psize, lanes): the one 4-D pool contract.
+    `lanes` past the head size hold what a reader must never see."""
     import jax.numpy as jnp
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(S, H, dh).astype(np.float32))
-    kp = jnp.asarray(rng.randn(P, psize, H, dh).astype(np.float32))
-    vp = jnp.asarray(rng.randn(P, psize, H, dh).astype(np.float32))
+    kp = jnp.asarray(rng.randn(H, P, psize, dh).astype(np.float32))
+    vp = jnp.asarray(rng.randn(H, P, psize, dh).astype(np.float32))
+    if lanes:
+        kp, vp = (jnp.pad(p, [(0, 0)] * 3 + [(0, lanes - dh)],
+                          constant_values=7.0) for p in (kp, vp))
     pt = jnp.asarray(np.array([[1, 2], [3, 0], [4, 5]], np.int32))
     lens = jnp.asarray(np.array([12, 5, 16], np.int32))
     return q, kp, vp, pt, lens
 
 
-def test_paged_attention_lax_matches_shared_math():
+@pytest.mark.parametrize("lanes", [None, 128], ids=["lanes=dh", "lanes=128"])
+def test_paged_attention_lax_matches_shared_math(lanes):
     """The gather fallback must be EXACTLY the shared single-query math
     over the gathered context (that is what buys decode-path parity)."""
     import jax.numpy as jnp
     from mxnet_tpu.ops.pallas_kernels import (
         _paged_attention_lax, single_query_cached_attention)
-    q, kp, vp, pt, lens = _paged_fixture()
+    q, kp, vp, pt, lens = _paged_fixture(lanes=lanes)
     out = _paged_attention_lax(q, kp, vp, pt, lens)
     S, H, dh = q.shape
-    L = pt.shape[1] * kp.shape[1]
-    kc = kp[pt].reshape(S, L, H, dh).transpose(0, 2, 1, 3)
-    vc = vp[pt].reshape(S, L, H, dh).transpose(0, 2, 1, 3)
+    L = pt.shape[1] * kp.shape[2]
+    # a slot's context, position by position: page pt[s, j]'s rows
+    kc = jnp.stack([kp[:, pt[s], :, :dh].reshape(H, L, dh)
+                    for s in range(S)])
+    vc = jnp.stack([vp[:, pt[s], :, :dh].reshape(H, L, dh)
+                    for s in range(S)])
     mask = (jnp.arange(L)[None, :] < lens[:, None])[:, None, None, :]
     ref = single_query_cached_attention(q[:, :, None, :], kc, vc,
                                         mask)[:, :, 0]
     assert np.array_equal(np.asarray(out), np.asarray(ref))
 
 
-@pytest.mark.parametrize("cfg", [{}, {"rpa_block_k": 8}],
-                         ids=["default", "block_k=8"])
+@pytest.mark.parametrize("cfg", [{}, {"rpa_block_k": 8}, {"lanes": 128}],
+                         ids=["default", "block_k=8", "lanes=128"])
 def test_paged_attention_kernel_interpret(monkeypatch, cfg):
     """The Pallas ragged-paged kernel numerics, pinned on CPU via
     interpret mode (same harness as the flash-kernel tests) — at the
     default block config AND under the ISSUE 20 `rpa_block_k` tuning
-    knob (psize=16 fixture so a sub-page tile is legal): every
-    reachable block config must reproduce the lax fallback."""
+    knob (psize=16 fixture so a sub-page tile is legal), AND over pools
+    whose rows are whole lane tiles, as the server keeps them (the
+    lanes past the head hold sevens): every reachable block config
+    must reproduce the lax fallback."""
     monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
     from mxnet_tpu.ops.pallas_kernels import (_paged_attention_lax,
                                               ragged_paged_attention)
     from mxnet_tpu.tune import overrides
-    q, kp, vp, pt, lens = (_paged_fixture() if not cfg else
-                           _paged_fixture(psize=16))
-    if cfg:
+    cfg = dict(cfg)
+    q, kp, vp, pt, lens = _paged_fixture(psize=16 if "rpa_block_k" in cfg
+                                         else 8, lanes=cfg.pop("lanes", 0))
+    if "rpa_block_k" in cfg:
         lens = lens * 2              # reach into the second K block
     with overrides.scope(cfg):
         out_k = ragged_paged_attention(q, kp, vp, pt, lens)
@@ -202,15 +215,17 @@ def test_paged_decode_bitwise_parity():
     tok = np.array([2, 0], np.int32)        # BOS
     # the eager core keeps its own copy of the page state (the jitted
     # runtime call donates rt.k_pages/v_pages)
-    kp, vp = jnp.array(rt.k_pages), jnp.array(rt.v_pages)
+    kp, vp = ([jnp.array(p) for p in pools]
+              for pools in (rt.k_pages, rt.v_pages))
 
     for t in range(8):
         logits_d, caches = decode_step(
             w, caches, mem_kv, mem_vl, jnp.asarray(tok[:1]), t)
         # the shared core, executed eagerly: bitwise
-        kp, vp, _, logits_e = rt._decode_program(
-            kp, vp, pt_dev, jnp.asarray(lens), jnp.asarray(tok), active,
-            rt.mem_k, rt.mem_v, rt.mem_vl)
+        (kp, vp, _, _), (_, logits_e) = rt._decode_program(
+            (kp, vp, None, None),
+            (pt_dev, jnp.asarray(lens), jnp.asarray(tok), active,
+             rt.mem_k, rt.mem_v, rt.mem_vl))
         assert np.array_equal(np.asarray(logits_e)[0],
                               np.asarray(logits_d)[0]), f"step {t}"
         # the jitted production path: same token choice, logits ~1 ULP
@@ -1267,9 +1282,9 @@ def test_int8_pages_carry_scales_through_radix_cache():
     cache = srv.prefix_cache
     pages = [n.page for n in cache._nodes]
     assert pages, "prompt pages were not cached"
-    ks = np.asarray(srv.runtime.k_scales)      # (L, P, H)
+    ks = np.asarray(srv.runtime.k_scales)      # (L, H, P)
     vs = np.asarray(srv.runtime.v_scales)
-    assert np.all(ks[:, pages, :] > 0) and np.all(vs[:, pages, :] > 0)
+    assert np.all(ks[:, :, pages] > 0) and np.all(vs[:, :, pages] > 0)
     hits0 = cache.hits
     warm = _drain(srv, (src, 8, prompt))[0]
     assert cache.hits == hits0 + 1
@@ -1375,12 +1390,12 @@ def test_paged_attention_quant_kernel_interpret(monkeypatch):
     rng = np.random.RandomState(0)
     S, H, dh, P, psize = 3, 2, 8, 9, 8
     q = jnp.asarray(rng.randn(S, H, dh).astype(np.float32))
-    kp = jnp.asarray(rng.randint(-127, 128, (P, psize, H, dh))
+    kp = jnp.asarray(rng.randint(-127, 128, (H, P, psize, dh))
                      .astype(np.int8))
-    vp = jnp.asarray(rng.randint(-127, 128, (P, psize, H, dh))
+    vp = jnp.asarray(rng.randint(-127, 128, (H, P, psize, dh))
                      .astype(np.int8))
-    ks = jnp.asarray((rng.rand(P, H) * 0.05 + 1e-3).astype(np.float32))
-    vs = jnp.asarray((rng.rand(P, H) * 0.05 + 1e-3).astype(np.float32))
+    ks = jnp.asarray((rng.rand(H, P) * 0.05 + 1e-3).astype(np.float32))
+    vs = jnp.asarray((rng.rand(H, P) * 0.05 + 1e-3).astype(np.float32))
     pt = jnp.asarray(np.array([[1, 2], [3, 0], [4, 5]], np.int32))
     lens = jnp.asarray(np.array([12, 5, 16], np.int32))
     out = ragged_paged_attention(q, kp, vp, pt, lens,
@@ -1421,3 +1436,57 @@ def test_quant_degrade_honours_deadline():
     assert len(out) >= 1
     srv.close()
     assert srv.pool.in_use() == 0
+
+
+# ------------------------------- the pools' layout is invisible (PR 29)
+# what the (L, P, psize, H, dh) pools of PR 28 generated on this journey
+_JOURNEY_TOKENS = [[30, 33, 33, 33, 30, 30, 30, 33, 33, 33], [16, 16],
+                   [33, 33, 30, 30, 30, 30],
+                   [30, 33, 33, 33, 30, 30, 30, 33, 33, 33]]
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["fp32", "int8"])
+@pytest.mark.parametrize("k", [0, 2], ids=["width1", "widened"])
+@pytest.mark.parametrize("lanes", [None, 128], ids=["lanes=dh", "lanes=128"])
+def test_pool_layout_keeps_the_greedy_tokens(monkeypatch, lanes, kv_dtype,
+                                             k):
+    """A server driven through admissions into both slots, a queued
+    request, `defrag` moving live pages mid-flight (`remap_pages`) and
+    a prefix-cache hit generates the tokens pinned from the parent's
+    pool layout: where a page's rows live on the device shows nowhere,
+    be they as wide as the head (off the TPU) or whole lane tiles (what
+    `pool_lanes` gives on it)."""
+    if lanes:
+        from mxnet_tpu.serve import decode
+        monkeypatch.setattr(decode, "pool_lanes", lambda dh: lanes)
+    model = _tiny_model(max_length=48, seed=4)
+    # louder layers under a quieter embedding, so that the argmax follows
+    # the attended context and not the token fed back
+    for name, p in model.collect_params().items():
+        if p.data().ndim >= 2:
+            p.set_data(p.data() * (0.3 if "embed" in name else 6.0))
+    srv = _server(model, page_size=2, max_new_tokens=10, max_prompt_len=12,
+                  num_pages=40, kv_dtype=kv_dtype, speculative_k=k)
+    rng = np.random.RandomState(3)
+    src = [rng.randint(4, 50, (n,)).astype(np.int32) for n in (7, 4, 6)]
+    prompt = rng.randint(4, 50, (9,)).astype(np.int32)
+    handles = [srv.submit(src[0], max_new_tokens=10, prompt_tokens=prompt),
+               srv.submit(src[1], max_new_tokens=2),
+               srv.submit(src[2], max_new_tokens=6)]
+    sched, moved = srv.scheduler, 0
+    for _ in range(200):
+        if not sched.pending_work():
+            break
+        sched.step()
+        moved += sched.defrag()
+    out = [h.result(timeout=60) for h in handles]
+    hits0 = srv.prefix_cache.hits
+    out += _drain(srv, (src[0], 10, prompt))
+    assert moved > 0 and srv.prefix_cache.hits == hits0 + 1
+    assert srv.pool.in_use() == srv.prefix_cache.pages_held()
+    rt = srv.runtime
+    assert rt.k_pages[0].shape[-1] == (lanes or 8)
+    assert (rt.verify_traces if k else rt.decode_traces) == 1
+    srv.close()
+    assert srv.pool.in_use() == 0
+    assert out == _JOURNEY_TOKENS

@@ -113,10 +113,10 @@ def test_ragged_paged_attention_compiles(chip_compile, window, q_dtype,
     """`window=None` is the one-token decode turn `serve.Server` runs
     every step — the form Mosaic refused before PR 22."""
     q = ((S, H, DH) if window is None else (S, window, H, DH), q_dtype)
-    pages = ((POOL, PSIZE, H, DH), kv_dtype)
+    pages = ((H, POOL, PSIZE, pk.pool_lanes(DH)), kv_dtype)
     avals = [q, pages, pages, ((S, NPAGES), I32), ((S,), I32)]
     if kv_dtype == I8:
-        avals += [((POOL, H), F32)] * 2
+        avals += [((H, POOL), F32)] * 2
 
         def attn(q, k, v, pt, ln, ks, vs):
             return pk.ragged_paged_attention(q, k, v, pt, ln,
@@ -125,6 +125,97 @@ def test_ragged_paged_attention_compiles(chip_compile, window, q_dtype,
         attn = pk.ragged_paged_attention
     assert kernel_calls(chip_compile(attn, *avals),
                         ("mxtpu_rpa",)) == {"mxtpu_rpa": 1}
+
+
+# ------------------------------------------- the pools stay where they lie
+def pool_sized_results(text, elems):
+    """{opcode: count} over the instructions of one optimized-HLO text
+    whose result holds at least `elems` elements (a layer pool's), a
+    fusion named by its root: `fusion:scatter` updates its operand in
+    place, any other fusion of that size writes a pool anew."""
+    import collections
+    import math
+    import re
+    instr = re.compile(r"\s+(ROOT\s+)?%?[\w.\-]+ = \w+\[([\d,]*)\][^ ]* "
+                       r"([a-z][a-z0-9\-]*)\((.*)")
+    roots, comp, found = {}, None, []
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            if line.endswith("{"):
+                comp = line.split(" (", 1)[0].split()[-1].lstrip("%")
+            continue
+        m = instr.match(line)
+        if m is None:
+            continue
+        if m.group(1):
+            roots[comp] = m.group(3)
+        if math.prod(int(d) for d in m.group(2).split(",") if d) >= elems:
+            found.append((m.group(3), m.group(4)))
+    out = collections.Counter()
+    for op, rest in found:
+        if op == "fusion":
+            op += ":" + roots[re.search(r"calls=%?([\w.\-]+)", rest).group(1)]
+        out[op] += 1
+    return dict(out)
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["fp32", "int8"])
+@pytest.mark.parametrize("width", [1, 4], ids=["decode", "verify"])
+def test_serve_programs_make_nothing_of_a_pools_size(one_chip, chip_compile,
+                                                     width, kv_dtype):
+    """`DecodeRuntime`'s decode and verify programs at the server's head
+    shapes (8 heads of 64, 16-token pages): every pool is donated into
+    its result, and besides the parameters, their bitcasts and the
+    in-place scatters of the page writes no instruction's result has a
+    layer pool's size: no copy, slice, transpose or fusion stands
+    between the resident pool and `mxtpu_rpa`. (PR 28's pools, (L, P,
+    psize, H, dh) in the device's default layout, fail this with 16
+    `copy`, 12 `slice` and 12 `fusion:bitcast` results at the server's
+    size. What may remain is a `copy-start`/`copy-done` pair: the
+    compiler's own move of a pool into fast memory and back in the same
+    layout, which it chooses for an int8 pool of these 33 MB and cannot
+    for the float32 server's 134 MB.)"""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models.transformer import (TransformerNMT,
+                                              decoder_weights,
+                                              encoder_weights)
+    from mxnet_tpu.observability import compilex
+    from mxnet_tpu.serve.decode import DecodeRuntime
+    # the benchmark server's 2049 pages: a pool of more elements than
+    # any weight or slot memory here, and of more bytes than the
+    # compiler would move into fast memory whole
+    layers, slots, pages = 2, S, 2049
+    mx.random.seed(0)
+    model = TransformerNMT(96, units=H * DH, hidden=256, num_layers=layers,
+                           num_heads=H, max_length=128, dropout=0.0)
+    model.initialize()
+    rt = DecodeRuntime(decoder_weights(model), encoder_weights(model),
+                       slots=slots, num_pages=pages, page_size=PSIZE,
+                       max_pages_per_slot=8, max_src_len=32, width=width,
+                       kv_dtype=kv_dtype)
+
+    def aval(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    ints = [((slots, 8), I32), ((slots,), I32),
+            ((slots,) if width == 1 else (slots, width), I32)]
+    ints += [((slots,), I32)] * (1 if width == 1 else 2)
+    inputs = tuple(jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                   for s, d in ints)
+    inputs += jax.tree_util.tree_map(aval, (rt.mem_k, rt.mem_v, rt.mem_vl))
+    fn = rt._decode_fn if width == 1 else rt._verify_fn
+    text = fn._jfn.lower(jax.tree_util.tree_map(aval, rt._pools()),
+                         inputs).compile().as_text()
+    assert kernel_calls(text, ("mxtpu_rpa",)) == {"mxtpu_rpa": layers}
+    info = compilex.inspect_hlo_text(text)
+    assert info["aliased_inputs"] == (4 if kv_dtype else 2) * layers
+    made = pool_sized_results(text, H * pages * PSIZE * DH)
+    assert made["fusion:scatter"] == (4 if kv_dtype else 2) * layers, made
+    rewrites = {op: n for op, n in made.items()
+                if op in ("copy", "transpose", "slice", "dynamic-slice",
+                          "concatenate", "reshape", "pad", "convert")
+                or op.startswith("fusion:") and op != "fusion:scatter"}
+    assert not rewrites, made
 
 
 # the decoder-only server's shapes (benchmarks/configs/solar_open2_ep8.json):
@@ -148,7 +239,7 @@ def test_grouped_kv_paged_attention_compiles_head_major(chip_compile):
     path over repeated heads, no kernel, until a configuration needs one."""
     text = chip_compile(
         pk.ragged_paged_attention, ((S, 4 * H, DH), BF16),
-        ((POOL, PSIZE, H, DH), BF16), ((POOL, PSIZE, H, DH), BF16),
+        ((H, POOL, PSIZE, DH), BF16), ((H, POOL, PSIZE, DH), BF16),
         ((S, NPAGES), I32), ((S,), I32))
     assert kernel_calls(text, ("mxtpu_rpa",)) == {"mxtpu_rpa": 0}
 
