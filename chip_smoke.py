@@ -31,8 +31,6 @@ import os
 import sys
 import time
 
-from bench_util import device_record
-
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
 # pallas_call names (ops/pallas_kernels.py) as they appear in compiled HLO
@@ -46,6 +44,14 @@ TRAIN_SHAPE = dict(batch=16, seq=512, masked=76)
 NMT_BASE = dict(vocab_size=36548, units=512, hidden=2048, num_layers=6,
                 num_heads=8)
 SERVE_SHAPE = dict(slots=8, page_size=16, max_src_len=32, max_new_tokens=32)
+
+
+def device_record():
+    """The device as jax reports it; goes into the result line."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
 
 
 def say(msg):

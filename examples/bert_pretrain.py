@@ -2,7 +2,7 @@
 
 Usage: python examples/bert_pretrain.py [--smoke]
 The attention path rides the Pallas flash kernels on TPU (padding masks
-as per-row kv lengths). Matches bench_bert.py's step construction.
+as per-row kv lengths).
 """
 import os as _os
 import sys as _sys

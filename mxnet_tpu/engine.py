@@ -275,7 +275,7 @@ class _PyEngine:
         a strong ref to the engine, so a discarded instance that is
         never closed leaks its threads for the process lifetime — the
         global facade engine deliberately never closes, but transient
-        instances (tools, tests, benches) must."""
+        instances (tools, tests) must."""
         with self._rcv:
             self._stopped = True
             self._rcv.notify_all()
@@ -1105,8 +1105,8 @@ def get_aging_ms():
 
 def set_qos(on):
     """Enable/disable priority scheduling at the facade. Disabled maps
-    every push to PRIORITY_NORMAL — pure FIFO, the `bench_serve.py
-    --background-train` baseline. Returns the previous setting."""
+    every push to PRIORITY_NORMAL — pure FIFO, the control arm of
+    `tools/check_qos.py`. Returns the previous setting."""
     global _qos_on
     prev = _qos_on
     _qos_on = bool(on)

@@ -497,7 +497,7 @@ def set_compilation_cache(path, min_compile_seconds=0.0):
 
 
 def entry_compilation_cache(checkout):
-    """The cache rule of the entry scripts (`chip_smoke.py`, `bench*.py`):
+    """The cache rule of the entry scripts (`chip_smoke.py`, `benchmarks/run.py`):
     the directory `JAX_COMPILATION_CACHE_DIR` names when it is set, else
     the fixed `<checkout>/.jax_cache` — never a temporary, pid- or
     time-derived path, which no later run could hit. A plain

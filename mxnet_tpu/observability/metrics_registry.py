@@ -158,7 +158,7 @@ class Histogram(_Metric):
 
     def quantiles(self, qs=(0.5, 0.95, 0.99)):
         """Several quantiles in ONE bucket walk: {q: estimate}. The
-        serving latency reporters (serve.Server, bench_serve) read
+        serving latency reporters (serve.Server) read
         p50/p95/p99 per snapshot — walking the buckets once instead of
         len(qs) times keeps the per-step reporting cost flat."""
         if not self.count:

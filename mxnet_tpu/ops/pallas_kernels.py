@@ -41,7 +41,7 @@ from ..observability.metrics_registry import registry as _metrics_registry
 from ..tune import overrides as _tune_overrides
 
 __all__ = ["flash_attention", "flash_block_attention", "fused_layer_norm",
-           "attention_reference", "on_tpu", "conv1x1_bn_stats",
+           "attention_reference", "on_tpu",
            "single_query_cached_attention", "ragged_paged_attention",
            "pool_lanes",
            "kernel_mesh"]
@@ -266,7 +266,7 @@ def _flash_fwd_kernel(*refs, sm_scale, causal, block_q, block_k,
     vl = vl_ref[pl.program_id(0) // num_heads] if has_lengths else None
 
     def compute():
-        # dots run in the INPUT dtype (bf16 on the bench path — 2x MXU rate
+        # dots run in the INPUT dtype (bf16 on the training path — 2x MXU rate
         # vs f32) with fp32 accumulation; softmax math stays fp32
         q = q_ref[0]                               # (bq, d)
         k = k_ref[0]                               # (bk, d)
@@ -1353,65 +1353,3 @@ def fused_layer_norm(x, gamma, beta, eps=1e-5):
     fallback elsewhere) with a closed-form custom-vjp backward, so it is
     trainable on the Pallas path too."""
     return _fused_ln(x, gamma, beta, float(eps))
-
-
-# ---------------------------------------------------------------------------
-# experimental: 1x1-conv (matmul) with BN-stats epilogue
-# ---------------------------------------------------------------------------
-def _conv1x1_stats_kernel(x_ref, w_ref, y_ref, s_ref, q_ref):
-    i = pl.program_id(0)
-    x = x_ref[...]                                          # (bm, K)
-    w = w_ref[...]                                          # (K, N)
-    y = jnp.dot(x, w, preferred_element_type=jnp.float32)   # (bm, N) f32
-    y_ref[...] = y.astype(y_ref.dtype)
-    s = jnp.sum(y, axis=0)
-    q = jnp.sum(y * y, axis=0)
-
-    @pl.when(i == 0)
-    def _init():
-        s_ref[...] = jnp.zeros_like(s_ref)
-        q_ref[...] = jnp.zeros_like(q_ref)
-
-    # (8, N) accumulator: every row carries the full total (a (1, N)
-    # block would violate Mosaic's (8, 128) min tile); row 0 is read
-    s_ref[...] += jnp.broadcast_to(s[None, :], s_ref.shape)
-    q_ref[...] += jnp.broadcast_to(q[None, :], q_ref.shape)
-
-
-def conv1x1_bn_stats(x2d, w, bm=1024):
-    """EXPERIMENTAL (perf probe, not wired into models): 1x1-conv as a
-    (M, K) @ (K, N) matmul that computes the per-channel fp32 BN stats
-    (mean, E[y^2]) WHILE each output tile is still in VMEM — deleting
-    the separate stats pass's full HBM read of y. tools/
-    probe_fused_convbn.py carries the keep-or-reject timings vs XLA
-    conv + fused reduce; numerics pinned in
-    tests/test_pallas.py. Returns (y (M, N) in x's dtype, mean (N,) f32,
-    meansq (N,) f32)."""
-    if not (on_tpu() or _interpret()):
-        # match the kernel's numerics: fp32 accumulate + fp32 stats,
-        # THEN cast y — bf16-rounded stats would diverge from the TPU
-        # path (and meansq - mean^2 could even go slightly negative)
-        yf = jnp.dot(x2d, w, preferred_element_type=jnp.float32)
-        return (yf.astype(x2d.dtype), jnp.mean(yf, 0),
-                jnp.mean(yf * yf, 0))
-    m, k = x2d.shape
-    n = w.shape[1]
-    bm = min(bm, m)
-    pad = (-m) % bm
-    xp = jnp.pad(x2d, ((0, pad), (0, 0))) if pad else x2d
-    y, s, q = pl.pallas_call(
-        _conv1x1_stats_kernel,
-        grid=(xp.shape[0] // bm,),
-        in_specs=[pl.BlockSpec((bm, k), lambda i: (i, 0)),
-                  pl.BlockSpec((k, n), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((bm, n), lambda i: (i, 0)),
-                   pl.BlockSpec((8, n), lambda i: (0, 0)),
-                   pl.BlockSpec((8, n), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((xp.shape[0], n), x2d.dtype),
-                   jax.ShapeDtypeStruct((8, n), jnp.float32),
-                   jax.ShapeDtypeStruct((8, n), jnp.float32)],
-        interpret=_interpret(),
-        name="mxtpu_conv1x1_bn_stats",
-    )(xp, w)
-    inv = 1.0 / m
-    return y[:m], s[0] * inv, q[0] * inv

@@ -12,9 +12,7 @@ Every `step()` is one turn of the serving crank:
   3. EVICT — requests that emitted EOS or hit their token budget leave
      their slot and return every page to the pool immediately, so the
      NEXT step can admit into the freed capacity. No drain barriers:
-     short requests never wait for long ones (`static_batching=True`
-     flips exactly this off — admission only into an EMPTY batch — and is
-     the baseline `bench_serve.py` beats).
+     short requests never wait for long ones.
 
 Backpressure: the admission queue is bounded (`max_queue`); a submit into
 a full queue raises `ServeOverloaded` (counted) instead of buffering
@@ -249,8 +247,8 @@ class StepResult:
 
 class Scheduler:
     def __init__(self, runtime, pool, bos_id=2, eos_id=3, max_queue=64,
-                 max_retries=1, max_preemptions=8, static_batching=False,
-                 prefix_cache=True, spec_ngram=2, quant_fallback=None):
+                 max_retries=1, max_preemptions=8, prefix_cache=True,
+                 spec_ngram=2, quant_fallback=None):
         import numpy as np
         self._np = np
         self._rt = runtime
@@ -279,7 +277,6 @@ class Scheduler:
         # they get their own (laxer) restart budget so transient capacity
         # pressure cannot burn a request's fault retries
         self.max_preemptions = int(max_preemptions)
-        self.static_batching = bool(static_batching)
         # low-precision degradation path (ISSUE 14): on a `serve.quant`
         # fault, a quantized server routes THAT request through this
         # full-precision callback instead of the int8 executables —
@@ -592,7 +589,7 @@ class Scheduler:
         self._m_active.set(0)
 
     def run_until_idle(self, max_steps=100000):
-        """Drive `step()` until queue and slots drain (tests/bench)."""
+        """Drive `step()` until queue and slots drain (tests, tools)."""
         for _ in range(max_steps):
             if not self.pending_work():
                 return
@@ -647,12 +644,6 @@ class Scheduler:
     def _admit(self, res=None):
         admitted = 0
         while True:
-            # static mode: admit only into an EMPTY batch — but fill the
-            # whole batch in that one turn (requests admitted THIS call
-            # don't close the window, or "static" would degenerate to
-            # sequential batch-size-1 decoding)
-            if self.static_batching and self.active_count() > admitted:
-                break
             free = [s for s, r in enumerate(self._slots) if r is None]
             if not free:
                 break

@@ -15,7 +15,7 @@ executables (`serve.decode.DecodeRuntime`), the page allocator
 engine-driven decode loop (`serve.engine_bridge.EngineLoop`). Submissions
 from any thread kick the loop; decoding happens on engine workers.
 `engine_driven=False` runs the crank inline in `result()`/`stream()`
-instead — deterministic single-threaded mode for tests and benches.
+instead — deterministic single-threaded mode for tests and tools.
 
 Observability: per-request TTFT/latency histograms with p50/p95/p99
 (`serve_ttft_seconds`, `serve_request_seconds`), `serve_tokens` and
@@ -73,7 +73,7 @@ class Server:
     def __init__(self, model, slots=8, page_size=16, num_pages=None,
                  max_src_len=32, max_new_tokens=32, max_prompt_len=0,
                  speculative_k=0, prefix_cache=True, bos_id=2, eos_id=3,
-                 max_queue=64, max_retries=1, static_batching=False,
+                 max_queue=64, max_retries=1,
                  engine_driven=True, kv_dtype=None, weight_dtype=None,
                  kv_hbm_bytes=None):
         if max_new_tokens < 1:
@@ -107,7 +107,6 @@ class Server:
         self._sched = Scheduler(self._rt, self._pool, bos_id=bos_id,
                                 eos_id=eos_id, max_queue=max_queue,
                                 max_retries=max_retries,
-                                static_batching=static_batching,
                                 prefix_cache=prefix_cache,
                                 quant_fallback=quant_fallback)
         self._engine_driven = bool(engine_driven)

@@ -6,6 +6,8 @@ slots (same pattern as chaos_check / check_dispatch / check_trace)."""
 import os
 import sys
 
+import pytest
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 import check_qos  # noqa: E402
 
@@ -29,3 +31,20 @@ def test_qos_fairness_and_chaos_soak():
 def test_check_qos_cli_smoke():
     assert callable(check_qos.main)
     assert check_qos.STARVE_BOUND_S > 0
+
+
+def test_background_load_raises_when_its_producer_died():
+    """A flood whose producer thread died would let the soak pass its
+    contention assertions vacuously: leaving the block raises instead."""
+    from mxnet_tpu import engine
+
+    def push_fails(*args, **kwargs):
+        raise ValueError("push refused")
+
+    load = check_qos.BackgroundEngineLoad(4)
+    load.group.push = push_fails
+    with pytest.raises(RuntimeError, match="flood thread died.*push refused"):
+        with load:
+            load._thread.join(timeout=10)
+    assert not load._thread.is_alive()
+    assert engine.active_groups() == 0

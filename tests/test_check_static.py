@@ -1,9 +1,8 @@
 """Graft-lint gate wired into tier-1 (ISSUE 13; same pattern as
 test_check_dispatch / test_check_fusion): zero non-baselined findings
 at HEAD, every AST and graph rule demonstrably fires on its seeded
-control, MXTPU-E01 runs baseline-free, and the whole gate completes
-inside its declared runtime ceiling — so a static regression (a raw env
-parse, a swallowed cancellation, a donation leak, a dead collective)
+control, and MXTPU-E01 runs baseline-free — so a static regression (a
+raw env parse, a swallowed cancellation, a donation leak, a dead collective)
 fails CI instead of costing a landing-pass review cycle."""
 import os
 import sys
@@ -41,8 +40,6 @@ def test_static_gate_clean_at_head_and_controls_fire():
         want.add("sharded_step")
     assert want <= set(res["graph_executables"]), \
         res["graph_executables"]
-    # runtime ceiling: the gate failing SLOW is a failure too
-    assert res["seconds"] <= check_static.RUNTIME_CEILING_S
 
 
 def test_e01_is_baseline_free_by_construction():
@@ -84,7 +81,6 @@ def test_static_row_lands_in_profiler_dumps():
 
 def test_check_static_cli_smoke():
     assert callable(check_static.main)
-    assert check_static.RUNTIME_CEILING_S <= 60.0
     assert set(check_static.AST_CONTROLS) == {
         "MXTPU-E01", "MXTPU-E02", "MXTPU-E03", "MXTPU-E04", "MXTPU-E05",
         "MXTPU-E06"}

@@ -43,6 +43,15 @@ def test_shard_phase_on_four_virtual_devices(interpret):
     assert len(out["one"]) == len(out["sharded"]) == 3
 
 
+def test_device_record_names_the_device_jax_reports():
+    import jax
+    rec = chip_smoke.device_record()
+    assert set(rec) == {"platform", "kind", "count"}
+    assert rec == {"platform": jax.devices()[0].platform,
+                   "kind": jax.devices()[0].device_kind,
+                   "count": len(jax.devices())}
+
+
 @pytest.mark.parametrize("argv", [[], ["--chips", "4"]],
                          ids=["one-chip", "four-chips"])
 def test_script_refuses_without_a_tpu(argv):

@@ -126,8 +126,10 @@ def test_nd_rnn_matches_gluon_layer():
     params = layer.collect_params()
     pnames, pvals = [], []
     for name, p in params.items():
-        pnames.append(name.split("lstm0_")[-1] if "lstm0_" in name
-                      else name)
+        # the layer's own prefix: "lstm<n>_", n counting the LSTMs this
+        # process has built before
+        pnames.append(name[len(layer.prefix):]
+                      if name.startswith(layer.prefix) else name)
         pvals.append(p.data())
     # imperative fused op with the same weights
     res = nd.RNN(x, *pvals, mode="lstm", num_layers=1, num_dir=1,

@@ -1,6 +1,7 @@
 """Pallas kernel tests (SURVEY.md §2 #42). On the CPU test mesh the kernels
 fall back to the XLA reference path — these tests pin the numerics and the
-custom-vjp wiring; the Pallas fast path is exercised on real TPU by bench.py."""
+custom-vjp wiring; the kernels are compiled for the chip in
+test_tpu_compile.py and run on it by chip_smoke.py and benchmarks/run.py."""
 import numpy as np
 import pytest
 import jax
@@ -218,26 +219,3 @@ def test_fused_ln_kernel_interpret(_pallas_interpret):
     want = layer_norm(x, g, b, axis=-1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4,
                                atol=1e-5)
-
-
-def test_conv1x1_bn_stats_numerics(_pallas_interpret):
-    """The experimental matmul+BN-stats epilogue KERNEL (interpret mode,
-    not the XLA fallback) matches the two-pass reference exactly in fp32
-    stats, including an M that doesn't divide the block (zero-padding
-    must not leak into stats)."""
-    import jax
-    import jax.numpy as jnp
-    from mxnet_tpu.ops.pallas_kernels import conv1x1_bn_stats
-    key = jax.random.PRNGKey(3)
-    m, k, n = 300, 64, 128        # m % bm != 0 on purpose
-    x = jax.random.normal(key, (m, k), jnp.float32)
-    w = jax.random.normal(key, (k, n), jnp.float32) * 0.1
-    y, mean, meansq = conv1x1_bn_stats(x, w, bm=256)
-    ref = x @ w
-    np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(mean), np.asarray(ref.mean(0)),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(meansq),
-                               np.asarray((ref * ref).mean(0)),
-                               rtol=1e-5, atol=1e-6)

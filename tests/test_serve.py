@@ -286,47 +286,34 @@ def test_continuous_batching_admits_midflight_and_frees_pages():
     srv.close()
 
 
-def test_static_batching_needs_more_steps():
-    """Same mixed-length workload: static batching (admit only into an
-    empty batch) must take strictly more scheduler turns than continuous
-    batching — the bench's speedup, in deterministic step counts."""
-    def run(static):
-        model = _tiny_model(seed=13)
-        srv = _server(model, slots=2, max_new_tokens=12,
-                      static_batching=static)
-        rng = np.random.RandomState(5)
-        for budget in (12, 2, 6, 3):
-            srv.submit(rng.randint(4, 50, (6,)), max_new_tokens=budget)
-        steps = 0
-        while srv.scheduler.pending_work():
-            srv.scheduler.step()
-            steps += 1
-            assert steps < 200
-        assert srv.pool.in_use() == 0
-        srv.close()
-        return steps
-
-    s_static = run(True)
-    s_cont = run(False)
-    assert s_cont < s_static, (s_cont, s_static)
-
-
-def test_static_batching_fills_whole_batch_per_window():
-    """static_batching admits into an EMPTY batch only, but fills ALL
-    free slots in that one admission turn (regression: the window used
-    to close after the first admission, degenerating to batch-size-1)."""
-    model = _tiny_model(seed=23)
-    srv = _server(model, slots=3, max_new_tokens=4, static_batching=True)
-    rng = np.random.RandomState(21)
-    for _ in range(4):
-        srv.submit(rng.randint(4, 50, (5,)), max_new_tokens=4)
-    r = srv.scheduler.step()
-    assert r.admitted == 3          # whole batch, one window
-    assert srv.scheduler.active_count() == 3
-    # mid-flight: no admission until the batch drains
-    r = srv.scheduler.step()
-    assert r.admitted == 0
-    srv.scheduler.run_until_idle()
+def test_continuous_batching_admits_into_running_batch():
+    """A mixed-length queue on 2 slots: a `step()` admits into a batch
+    that is part full, and the queue drains in fewer turns than running
+    it one whole batch after another would need. `eos_id=-1`: every
+    request runs its budget, one token a turn, so both bounds follow
+    from the budgets alone."""
+    budgets = (12, 2, 6, 3)
+    srv = _server(_tiny_model(seed=13), slots=2, max_new_tokens=12,
+                  eos_id=-1)
+    sched = srv.scheduler
+    rng = np.random.RandomState(5)
+    handles = [srv.submit(rng.randint(4, 50, (6,)), max_new_tokens=b)
+               for b in budgets]
+    steps, admitted_midflight = 0, 0
+    while sched.pending_work():
+        running_before = sched.active_count()
+        r = sched.step()
+        steps += 1
+        assert steps < 200
+        if running_before and r.admitted:
+            admitted_midflight += r.admitted
+    assert [len(h.result()) for h in handles] == list(budgets)
+    # the first turn fills both slots; the other two requests can only
+    # have gone into a batch that was still running
+    assert admitted_midflight == 2
+    # batch after batch, each pair of slots waits for its longer request
+    batch_after_batch = max(budgets[:2]) + max(budgets[2:])
+    assert max(budgets) <= steps < batch_after_batch, steps
     assert srv.pool.in_use() == 0
     srv.close()
 

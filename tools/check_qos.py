@@ -44,7 +44,7 @@ import threading
 import time
 
 _REPO_ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
-if _REPO_ROOT not in sys.path:   # mxnet_tpu + bench_util, however invoked
+if _REPO_ROOT not in sys.path:   # mxnet_tpu, however invoked
     sys.path.insert(0, _REPO_ROOT)
 
 AGING_MS = 100
@@ -97,12 +97,56 @@ def _phase_fairness(errors):
     return {"fairness_engines": [n for n, _ in engines]}
 
 
-def _background_flood(target):
-    """The soak/control backlog: `bench_util.BackgroundEngineLoad` (one
-    shared generator with `bench_serve.py --background-train`, so the
-    gate and the bench measure the same contention)."""
-    from bench_util import BackgroundEngineLoad
-    return BackgroundEngineLoad(target, task_s=BG_TASK_S)
+class BackgroundEngineLoad:
+    """Sustained background dependency-engine flood (ISSUE 7): a producer
+    thread keeps `target` short sleep tasks live in one cancellable
+    TaskGroup at PRIORITY_BACKGROUND — the stand-in for a co-tenant
+    training loop's host-side work (prefetch staging, async checkpoint
+    IO). The FIFO control and the soak run under the same one."""
+
+    def __init__(self, target):
+        from mxnet_tpu import engine
+        self._engine = engine
+        self.group = engine.TaskGroup("background_load")
+        self.target = int(target)
+        self._stop = threading.Event()
+        self.error = None     # a dead flood thread makes any "no
+                              # starvation under load" assertion vacuous:
+                              # __exit__ raises it
+        self._thread = threading.Thread(target=self._produce, daemon=True)
+
+    def _produce(self):
+        while not self._stop.is_set():
+            short = self.target - self.group.live()
+            try:
+                for _ in range(max(0, short)):
+                    self.group.push(
+                        lambda: time.sleep(BG_TASK_S),
+                        priority=self._engine.PRIORITY_BACKGROUND)
+            except self._engine.EngineQueueFull:
+                pass          # bounded background class: back off, keep
+                              # flooding — the load stays sustained
+            except BaseException as exc:  # noqa: BLE001
+                self.error = exc
+                return
+            time.sleep(0.005)
+
+    def __enter__(self):
+        self._thread.start()
+        return self
+
+    def __exit__(self, *exc):
+        self._stop.set()
+        self._thread.join(timeout=10)
+        self.group.cancel()
+        self.group.drain(timeout=60)
+        if self.error is not None and not any(exc):
+            # surface a dead producer thread: a run "under load" whose
+            # flood silently stopped would pass its contention
+            # assertions vacuously
+            raise RuntimeError(
+                f"background flood thread died: {self.error!r}")
+        return False
 
 
 def _probe_wait(engine):
@@ -126,7 +170,7 @@ def _phase_fifo_control(errors):
     workers = engine.num_workers()
     prev_qos = engine.set_qos(False)
     try:
-        with _background_flood(workers * BG_BACKLOG_PER_WORKER):
+        with BackgroundEngineLoad(workers * BG_BACKLOG_PER_WORKER):
             time.sleep(0.3)             # let the backlog build
             waits = [w for w in (_probe_wait(engine) for _ in range(3))
                      if w is not None]
@@ -192,7 +236,7 @@ def _phase_soak(errors):
     waits = []
     handles = []
     try:
-        with _background_flood(workers * BG_BACKLOG_PER_WORKER):
+        with BackgroundEngineLoad(workers * BG_BACKLOG_PER_WORKER):
             time.sleep(0.2)
             # seeded faults: random engine-task kills (hit background
             # tasks, probes AND serve loop tasks — the loop must re-arm)

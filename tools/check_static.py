@@ -22,8 +22,10 @@ Three phases, one verdict:
     gate measures something, not that the numbers were copied from a
     passing run.
 
-A hard runtime ceiling (RUNTIME_CEILING_S) keeps the 870 s tier-1
-window safe: the gate failing SLOW is a failure too.
+A standalone run that takes longer than RUNTIME_CEILING_S exits non-zero
+(`main`). `run()` reports `seconds` and judges none: under tier-1 the
+gate shares its CPUs with five other workers, and a time read there says
+nothing of the gate.
 
 Baseline: tools/static_baseline.json (see docs/STATIC_ANALYSIS.md for
 the suppression/baseline workflow). Stale entries — ones matching no
@@ -88,7 +90,7 @@ BUDGETS = {
 }
 DEFAULT_COPIES_ALLOW = 8      # a new executable gets this until reviewed
 
-RUNTIME_CEILING_S = 60.0      # hard wall on the whole gate (1-CPU VM)
+RUNTIME_CEILING_S = 60.0      # a standalone run's wall (`main`)
 
 BASELINE_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                              "static_baseline.json")
@@ -400,12 +402,7 @@ def run(graph=True):
             else:
                 os.environ["MXTPU_HLO_TELEMETRY"] = prev_pol
 
-    # ---- ceiling -----------------------------------------------------
     seconds = time.monotonic() - t0
-    if seconds > RUNTIME_CEILING_S:
-        errors.append(f"gate took {seconds:.1f}s > ceiling "
-                      f"{RUNTIME_CEILING_S:.0f}s — trim the fixtures or "
-                      f"raise the ceiling in review")
 
     rules_run = len(astlint.RULES) + (len(graphlint.GRAPH_RULES)
                                       if graph else 0)
@@ -443,6 +440,12 @@ def main(argv=None):
         import jax
         jax.config.update("jax_platforms", "cpu")
     res = run(graph="--ast-only" not in argv)
+    if res["seconds"] > RUNTIME_CEILING_S:
+        res["errors"].append(
+            f"gate took {res['seconds']:.1f}s > ceiling "
+            f"{RUNTIME_CEILING_S:.0f}s — trim the fixtures or raise the "
+            f"ceiling in review")
+        res["ok"] = False
     print(json.dumps(res))
     for err in res["errors"]:
         print(f"check_static: {err}", file=sys.stderr)
