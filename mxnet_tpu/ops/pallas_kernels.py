@@ -120,13 +120,15 @@ def _block_sizes(sq, sk):
 
 
 def _rpa_block_k(psize):
-    """Sub-page K block of the ragged-paged-attention kernels (ISSUE
-    20): the inner grid walks `psize // block` steps per page, each
-    DMA-ing a (block, dh) tile — smaller blocks overlap compute with
-    more, smaller DMAs; the default (= psize) keeps one page per step.
-    MXTPU_RPA_BLOCK_K / tune override `rpa_block_k`; must divide the
-    page size and keep the 8-sublane tile, else the default is used
-    loudly."""
+    """Forced K tile of `_rpa_kernel`'s body (ISSUE 20). A grid step
+    holds a slot's heads and several whole pages (`_rpa_plan`) and by
+    default (= psize) takes a head's keys of the step as one tile; a
+    forced value walks them in tiles of `block` rows of a page, inside
+    the step: more, narrower softmax updates over the same blocks, no
+    more grid steps or DMAs (a step a sub-page tile is what it asked
+    for before PR 31). MXTPU_RPA_BLOCK_K / tune override `rpa_block_k`;
+    must divide the page size and keep the 8-sublane tile, else the
+    default is used loudly."""
     forced, src = _knob("rpa_block_k", "MXTPU_RPA_BLOCK_K")
     if not forced:
         return psize
@@ -830,7 +832,7 @@ def pool_lanes(head_dim):
     """The row width to keep a head-major (H, P, psize, lanes) pool at.
     On the TPU the head size rounded up to whole 128-lane tiles: the
     client's default layout for such an array is row-major, which is
-    what `_rpa_kernel`'s (psize, lanes) blocks read; an array whose
+    what `_rpa_kernel`'s (heads, 1, psize, lanes) blocks read; an array whose
     minor dimension is under 128 it lays out with another dimension in
     the lanes (to save the padding), and every program over it then
     copies the pool into the kernel's layout and back. Elsewhere there
@@ -896,15 +898,57 @@ def _paged_attention_lax_multi(q, k_pages, v_pages, page_tables, lengths,
     return out.transpose(0, 2, 1, 3)
 
 
-def _rpa_kernel(*refs, psize, block_k, num_heads, window, sm_scale,
+# what the page blocks of one grid step may hold of the chip's fast memory,
+# both buffers of the pipeline counted: half of the 16 MiB a kernel gets
+_RPA_VMEM_BUDGET = 8 << 20
+
+
+def _rpa_plan(H, npages, psize, lanes, itemsize):
+    """(heads, pages) one grid step of `_rpa_kernel` takes, from the
+    shapes alone: all `H` heads of a slot and `min(8, npages)` of its
+    pages, as long as the K and V blocks of the step, each
+    double-buffered and in whole sublane tiles, fit `_RPA_VMEM_BUDGET`;
+    a longer page or more heads take fewer pages a step, then a divisor
+    of the heads. The grid is `_rpa_steps` of these."""
+    tile = 8 * max(1, 4 // itemsize)        # sublane rows of a tile
+    page = 2 * 2 * -(-psize // tile) * tile * lanes * itemsize
+    heads = max(h for h in range(1, H + 1)
+                if H % h == 0 and (h == 1 or h * page <= _RPA_VMEM_BUDGET))
+    pages = max(1, min(8, npages, _RPA_VMEM_BUDGET // (heads * page)))
+    return heads, pages
+
+
+def _rpa_steps(S, H, npages, heads, pages):
+    """The grid of one `mxtpu_rpa` call: a row a (slot, group of
+    `heads`), a step for each `pages` of the table's width."""
+    return S * (H // heads), -(-npages // pages)
+
+
+def _rpa_row(g, groups):
+    """(slot, group of heads) of grid row `g`: the row itself and 0 where
+    a slot is one row, else `lax.div` / `lax.rem`, one instruction each
+    (`//` and `%` lower to sign corrections that Mosaic traces anew in
+    every one of a step's 17 index maps: 3 s of a server's set-up)."""
+    if groups == 1:
+        return g, 0
+    return lax.div(g, jnp.int32(groups)), lax.rem(g, jnp.int32(groups))
+
+
+def _rpa_kernel(*refs, psize, pps, block_k, heads, groups, window, sm_scale,
                 quant=False):
-    """Ragged paged attention, one (slot, head) per grid row, one
-    (block_k, dh) KV tile per inner step — `psize // block_k` steps per
-    page (block_k == psize is the one-page-per-step default; the
-    autotuner searches smaller tiles, `_rpa_block_k`). The page id for
-    (slot, page_slot) was already consumed by the BlockSpec index maps
-    (scalar prefetch); here we only need the slot's valid length for
-    masking and dead-page skipping.
+    """Ragged paged attention over head-major (H, P, psize, lanes) pools:
+    one SLOT per grid row with `heads` of its heads (all of them, unless
+    `_rpa_plan` had to split: `groups` rows a slot then) and `pps` pages
+    per inner step, one block spec a page, so the pipeline gathers them
+    together: a page's block is the (heads, 1, psize, lanes) of the pool
+    where it lies. A short context at small pages is bound by the count
+    of grid steps, not by bytes, and this form takes heads x pps fewer
+    than one (slot, head, page) a step. The page ids were already
+    consumed by the BlockSpec index maps (scalar prefetch); here we only
+    need the slot's valid length for masking and for skipping the steps
+    past it. The body walks the heads; a head's keys of the step are one
+    (pps * psize, lanes) tile, or `block_k`-row tiles where a forced
+    `_rpa_block_k` asks for them.
 
     `window` (ISSUE 12) real query rows per slot, padded to the
     8-sublane tile: query row i masks keys at `len_ref[slot] + i` —
@@ -917,29 +961,26 @@ def _rpa_kernel(*refs, psize, block_k, num_heads, window, sm_scale,
     Rows beyond a slot's real window produce garbage nobody commits.
 
     quant (ISSUE 14): the page pools are int8 and two extra scalar-
-    prefetch refs carry the per-page/per-head f32 dequant scales — the
-    page block dequantizes in VMEM right after the DMA, so HBM only ever
-    moves int8 bytes."""
-    if quant:
-        (pt_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-    else:
-        ks_ref = vs_ref = None
-        (pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-    npb = psize // block_k                  # sub-page blocks per page
-    g = pl.program_id(0)                    # slot * num_heads + head
-    j = pl.program_id(1)                    # page slot * npb + block
-    nj = pl.num_programs(1)
-    s_idx = g // num_heads
+    prefetch refs carry the per-page/per-head f32 dequant scales — a
+    page's block dequantizes in VMEM right after the DMA, one scalar a
+    head and page, so HBM only ever moves int8 bytes."""
+    pt_ref, len_ref = refs[:2]
+    ks_ref, vs_ref = refs[2:4] if quant else (None, None)
+    refs = refs[4 if quant else 2:]
+    q_ref, k_refs, v_refs = refs[0], refs[1:1 + pps], refs[1 + pps:1 + 2 * pps]
+    o_ref, m_scr, l_scr, acc_scr = refs[1 + 2 * pps:]
+    # grid row slot * groups + head group, step of pps pages
+    (s_idx, hg), j = _rpa_row(pl.program_id(0), groups), pl.program_id(1)
     length = len_ref[s_idx]                 # keys visible to query row 0
-    k_start = j * block_k
-    wp = q_ref.shape[1]                     # padded query rows (>= 8)
-    if quant:
-        page = pt_ref[s_idx, j // npb]
-        h_idx = g % num_heads
-        ks = ks_ref[h_idx, page]
-        vs = vs_ref[h_idx, page]
+    k_start = j * pps * psize
+    wp = q_ref.shape[2]                     # padded query rows (>= 8)
+    npages = pt_ref.shape[1]
+    # the step's key tiles: [(page of the step, rows of it), ...] each
+    if block_k == psize:
+        tiles = [[(i, slice(None)) for i in range(pps)]]
+    else:
+        tiles = [[(i, slice(b, b + block_k))]
+                 for i in range(pps) for b in range(0, psize, block_k)]
 
     @pl.when(j == 0)
     def _init():
@@ -947,109 +988,133 @@ def _rpa_kernel(*refs, psize, block_k, num_heads, window, sm_scale,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # blocks beyond what the LAST real query row sees are skipped — the
-    # ragged part: a 3-token request costs one block of work while its
+    # steps beyond what the LAST real query row sees are skipped — the
+    # ragged part: a 3-token request costs one step of work while its
     # 300-token neighbour walks its whole table, in the same launch
     @pl.when(k_start < length + window - 1)
     def _compute():
-        q = q_ref[0]                        # (wp, dh)
-        k = k_ref[0, 0]                     # (block_k, dh)
-        v = v_ref[0, 0]                     # (block_k, dh)
         if quant:
-            # dequantize in VMEM, same element-wise form as the lax
-            # fallback's gathered dequant (parity pinned in interpret)
-            k = k.astype(jnp.float32) * ks
-            v = v.astype(jnp.float32) * vs
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        qi = lax.broadcasted_iota(jnp.int32, (wp, block_k), 0)
-        kj = k_start + lax.broadcasted_iota(jnp.int32, (wp, block_k), 1)
-        s = jnp.where(kj < length + qi, s, -1e30)
-        m_prev = m_scr[:, :1]               # (wp, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)              # (wp, block_k) fp32
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+            page_ids = [pt_ref[s_idx, jnp.minimum(j * pps + i, npages - 1)]
+                        for i in range(pps)]
 
-    @pl.when(j == nj - 1)
+        def rows_of(pages, scales, h, parts):
+            out = []
+            for i, rows in parts:
+                x = pages[i][h, 0, rows, :]
+                if quant:
+                    # dequantize in VMEM, same element-wise form as the
+                    # lax fallback's gathered dequant (parity pinned in
+                    # interpret)
+                    x = x.astype(jnp.float32) * scales[
+                        hg * heads + h, page_ids[i]]
+                out.append(x)
+            return jnp.concatenate(out, 0)
+
+        for h in range(heads):
+            q = q_ref[0, h]                 # (wp, lanes)
+            for t, parts in enumerate(tiles):
+                k = rows_of(k_refs, ks_ref, h, parts)
+                v = rows_of(v_refs, vs_ref, h, parts)
+                nk = k.shape[0]
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                qi = lax.broadcasted_iota(jnp.int32, (wp, nk), 0)
+                kj = k_start + t * nk + lax.broadcasted_iota(
+                    jnp.int32, (wp, nk), 1)
+                keep = kj < length + qi
+                if npages % pps:
+                    # the last step's pages past the table's width
+                    keep &= kj < npages * psize
+                s = jnp.where(keep, s, -1e30)
+                m_prev = m_scr[h, :, :1]    # (wp, 1)
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)      # (wp, nk) fp32
+                l_new = alpha * l_scr[h, :, :1] + jnp.sum(
+                    p, axis=-1, keepdims=True)
+                acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_scr[h] = jnp.broadcast_to(m_new, (wp, 128))
+                l_scr[h] = jnp.broadcast_to(l_new, (wp, 128))
+
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
         # a slot with length 0 (empty decode slot) has l == 0: guard the
         # divide; its output is garbage the scheduler never reads
         o_ref[0] = (acc_scr[:] /
-                    jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
+                    jnp.maximum(l_scr[:, :, :1], 1e-30)).astype(o_ref.dtype)
 
 
-def _rpa_pallas(q, k_pages, v_pages, page_tables, lengths, sm_scale,
-                k_scales=None, v_scales=None):
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "plan", "block_k", "wp", "interpret"))
+def _rpa_pallas(q, k_pages, v_pages, page_tables, lengths, k_scales=None,
+                v_scales=None, *, sm_scale, plan, block_k, wp, interpret):
     """q: (S, W, H, dh); pools (H, P, psize, lanes), the shape the block
-    specs read: one (slot, head, page) block is a (psize, lanes) tile of
-    the pool where it lies, so nothing of a pool's size is made around
-    the kernel; int8 scales (H, P). The kernel sees a head `lanes` wide:
-    the query's lanes past dh are zero, the output's are dropped.
-    Returns (S, W, H, dh)."""
+    specs read: a page's block is its (heads, 1, psize, lanes) of the
+    pool where it lies (a strided DMA of one tile a head), so nothing of
+    a pool's size is made around the kernel; int8 scales (H, P). The
+    grid is `_rpa_steps` of `plan`, what `_rpa_plan` gives a step. The
+    kernel sees a head `lanes` wide: the query's lanes past dh are zero,
+    the output's are dropped. Returns (S, W, H, dh).
+
+    Jitted, so that a decoder's layers, which call it at one shape,
+    trace and lower the body and its unrolled heads once and not once a
+    layer. Whatever is decided while tracing comes in as a static
+    argument for that: the plan, the forced knobs (`block_k`,
+    `_rpa_block_k`; `wp`, `_rpa_sublanes`: the query rows padded to the
+    8-sublane tile) and interpret mode."""
     S, W, H, dh = q.shape
     psize, lanes = k_pages.shape[2:]
     npages = page_tables.shape[1]
     quant = k_scales is not None
-    bk = _rpa_block_k(psize)
-    npb = psize // bk               # sub-page K blocks per page
-    # pad the query-row dim to the Mosaic 8-sublane tile (or the forced
-    # tuner sublane count); the extra rows are sliced away below
-    wp = _rpa_sublanes(W)
-    qr = q.transpose(0, 2, 1, 3).reshape(S * H, W, dh)
+    heads, pps = plan
+    groups = H // heads
+    qr = q.transpose(0, 2, 1, 3)
     if wp != W or lanes != dh:
-        qr = jnp.pad(qr, ((0, 0), (0, wp - W), (0, lanes - dh)))
-    grid = (S * H, npages * npb)
-    kern = functools.partial(_rpa_kernel, psize=psize, block_k=bk,
-                             num_heads=H, window=W, sm_scale=sm_scale,
-                             quant=quant)
-    nsp = 4 if quant else 2
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=nsp,        # page tables + lengths (+ scales)
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, wp, lanes),
-                         lambda g, j, pt, ln, *_: (g, 0, 0)),
-            # the paged gather: the page id comes from the scalar-
-            # prefetched table, so the DMA fetches exactly the pages the
-            # slot owns — never a dense (S, Lmax) context; with bk <
-            # psize the dim-2 block index walks the npb tiles of a page
-            pl.BlockSpec((1, 1, bk, lanes),
-                         lambda g, j, pt, ln, *_, _h=H, _b=npb:
-                         (g % _h, pt[g // _h, j // _b], j % _b, 0)),
-            pl.BlockSpec((1, 1, bk, lanes),
-                         lambda g, j, pt, ln, *_, _h=H, _b=npb:
-                         (g % _h, pt[g // _h, j // _b], j % _b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, wp, lanes),
-                               lambda g, j, pt, ln, *_: (g, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((wp, 128), jnp.float32),
-            pltpu.VMEM((wp, 128), jnp.float32),
-            pltpu.VMEM((wp, lanes), jnp.float32),
-        ],
-    )
+        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, wp - W), (0, lanes - dh)))
+    qr = qr.reshape(S * groups, heads, wp, lanes)
+
+    def page_at(i):
+        # the paged gather: the page id comes from the scalar-prefetched
+        # table, so the DMA fetches exactly the pages the slot owns —
+        # never a dense (S, Lmax) context; entries past a slot's length
+        # are the null page, and a block whose index stays is not
+        # fetched again
+        def index(g, j, pt, ln, *_):
+            s_idx, hg = _rpa_row(g, groups)
+            return hg, pt[s_idx, jnp.minimum(j * pps + i, npages - 1)], 0, 0
+        return index
+    pages = [pl.BlockSpec((heads, 1, psize, lanes), page_at(i))
+             for i in range(pps)]
+    block = pl.BlockSpec((1, heads, wp, lanes),
+                         lambda g, j, pt, ln, *_: (g, 0, 0, 0))
     scal = (page_tables.astype(jnp.int32), lengths.astype(jnp.int32))
     if quant:
-        # (H, P) f32 in SMEM: the kernel reads one scalar per grid step
+        # (H, P) f32 in SMEM: the kernel reads one scalar a head and page
         scal += (k_scales.astype(jnp.float32),
                  v_scales.astype(jnp.float32))
     out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=_sds((S * H, wp, lanes), q.dtype, q, k_pages, v_pages),
+        functools.partial(_rpa_kernel, psize=psize, pps=pps,
+                          block_k=block_k, heads=heads, groups=groups,
+                          window=W, sm_scale=sm_scale, quant=quant),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scal),  # page tables + lengths (+ scales)
+            grid=_rpa_steps(S, H, npages, heads, pps),
+            in_specs=[block] + pages + pages, out_specs=block,
+            scratch_shapes=[pltpu.VMEM((heads, wp, 128), jnp.float32),
+                            pltpu.VMEM((heads, wp, 128), jnp.float32),
+                            pltpu.VMEM((heads, wp, lanes), jnp.float32)]),
+        out_shape=_sds((S * groups, heads, wp, lanes), q.dtype,
+                       q, k_pages, v_pages),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=interpret,
         name="mxtpu_rpa",
-    )(*scal, qr, k_pages, v_pages)
-    return out[:, :W, :dh].reshape(S, H, W, dh).transpose(0, 2, 1, 3)
+    )(*scal, qr, *([k_pages] * pps), *([v_pages] * pps))
+    return out.reshape(S, H, wp, lanes)[:, :, :W, :dh].transpose(0, 2, 1, 3)
 
 
 def _rpa_flat_kernel(*refs, psize, pps, kv_heads, rows, window, group,
@@ -1057,9 +1122,10 @@ def _rpa_flat_kernel(*refs, psize, pps, kv_heads, rows, window, group,
     """Ragged paged attention over pools kept as (P, psize, H * dh), for a
     head size that fills the 128 lanes: one SLOT per grid row with all
     its KV heads, `pps` pages per inner step (one block spec a page, so
-    the pipeline gathers them together), where `_rpa_kernel` takes one
-    (slot, head) and one page. A long context at small pages is bound by
-    the count of grid steps, not by bytes: this form takes H x pps fewer.
+    the pipeline gathers them together): the grid `_rpa_kernel` has over
+    head-major pools. A long context at small pages is bound by the
+    count of grid steps, not by bytes: this form takes H x pps fewer
+    than one (slot, head, page) a step.
     A page's block holds every head; head h's keys are its lanes
     h * dh .. (h + 1) * dh, read where they lie. The query block stacks
     the KV heads' `rows` query rows: a head's rows are (window position,
@@ -1180,8 +1246,9 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lengths,
     keys; rows past a slot's real window compute garbage nobody reads).
     k_pages/v_pages: fixed-size page pools in the shape their kernel
     reads where they lie, so that a decode program makes nothing of a
-    pool's size: head-major (H, P, psize, lanes) for `_rpa_kernel` (one
-    (slot, head, page) block a step; a row holds the head's dh values
+    pool's size: head-major (H, P, psize, lanes) for `_rpa_kernel` (a
+    slot's heads and eight of its pages a grid step, a page's block the
+    (H, 1, psize, lanes) of the pool; a row holds the head's dh values
     and zeros up to `lanes`: KEEP a pool at `pool_lanes(dh)`, any width
     from dh up is read), or page-major with the heads merged into the
     lanes, (P, psize, H * dh), the shape to KEEP a pool in when dh is a
@@ -1212,10 +1279,11 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lengths,
     `single_query_cached_attention` (inference-only; no custom vjp).
 
     Tunable knobs (ISSUE 20; MXTPU_RPA_BLOCK_K / MXTPU_RPA_SUBLANES or a
-    tune/overrides.py scope): sub-page K tile size of the inner grid
+    tune/overrides.py scope): a sub-page K tile inside a grid step
     (`_rpa_block_k`) and the padded query-row count of the widened form
     (`_rpa_sublanes`). Invalid values fall back loudly
-    (`pallas_block_override_ignored`)."""
+    (`pallas_block_override_ignored`). The grid itself has no knob: it
+    follows from the shapes (`_rpa_plan`)."""
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     if (k_scales is None) != (v_scales is None):
@@ -1245,9 +1313,14 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lengths,
         return lax_fn(q, k_pages, v_pages, page_tables, lengths,
                       k_scales=k_scales, v_scales=v_scales)
     # the one-token decode turn is the W == 1 window of the same kernel
-    out = _rpa_pallas(q if q.ndim == 4 else q[:, None], k_pages, v_pages,
-                      page_tables, lengths, sm_scale,
-                      k_scales=k_scales, v_scales=v_scales)
+    qw = q if q.ndim == 4 else q[:, None]
+    H, _, psize, lanes = k_pages.shape
+    out = _rpa_pallas(
+        qw, k_pages, v_pages, page_tables, lengths, k_scales, v_scales,
+        sm_scale=float(sm_scale), block_k=_rpa_block_k(psize),
+        wp=_rpa_sublanes(qw.shape[1]), interpret=_interpret(),
+        plan=_rpa_plan(H, page_tables.shape[1], psize, lanes,
+                       k_pages.dtype.itemsize))
     return out if q.ndim == 4 else out[:, 0]
 
 

@@ -13,8 +13,9 @@ scatters into the same device buffers — the paged cache never doubles in
 HBM.
 
 The pools are ONE array a layer, kept head-major `(H, P, psize, lanes)`:
-the shape `mxtpu_rpa`'s block specs read (one (slot, head, page) block
-is a `(psize, lanes)` tile), so the kernel takes a pool where it lies
+the shape `mxtpu_rpa`'s block specs read (a page's block is its
+`(H, 1, psize, lanes)`: a slot's heads and eight of its pages make one
+grid step), so the kernel takes a pool where it lies
 and a decode or verify program holds no operation whose result has a
 pool's size (tests/test_tpu_compile.py pins that on the chip's own
 compiler). Two things keep it so. A row is `pool_lanes(dh)` wide, the

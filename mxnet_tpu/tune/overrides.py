@@ -12,7 +12,8 @@ Knob names (values are ints):
 
   flash_block_q / flash_block_k   flash attention Q/K tile sizes
   rpa_block_k                     ragged-paged-attention sub-page K
-                                  block (divides page size, %8 == 0)
+                                  tile inside a grid step (divides
+                                  page size, %8 == 0)
   rpa_sublanes                    padded query-row count of the WIDENED
                                   (multi-query verify) RPA launch
                                   (>= W, %8 == 0)

@@ -245,9 +245,12 @@ def test_search_rejects_dead_pallas_override():
         ], trials=1)
         by_name = {r.candidate.name: r for r in res.candidates}
         assert by_name["pallas:dead"].rejected == "dead_pallas_override"
-        # the VALID block config compiled and was honestly measured
+        # the VALID block config was read, compiled and honestly judged
+        # (its tile loop inside a grid step is more copies in the
+        # interpreter's HLO, which guard 2 may refuse: not a dead knob)
         assert by_name["pallas:bk8"].rejected in (None,) or \
-            by_name["pallas:bk8"].rejected.startswith("numerics")
+            by_name["pallas:bk8"].rejected.startswith(
+                ("numerics", "hlo_regression: copies"))
     finally:
         if prev is None:
             os.environ.pop("MXTPU_PALLAS_INTERPRET", None)

@@ -123,6 +123,57 @@ def _paged_fixture(seed=0, S=3, H=2, dh=8, P=9, psize=8, npages=2,
     return q, kp, vp, pt, lens
 
 
+# what one grid step's heads and pages do not divide (PR 31): 11 pages a
+# slot under 8 a step, an empty slot beside one that fills every page,
+# five heads; and a fast-memory budget that holds three of six heads and
+# one page a step
+_RAGGED = dict(H=5, npages=11, lens=(0, 88, 37, 1))
+_SPLIT = dict(H=6, npages=3, lens=(24, 0, 9), vmem=3 * 1024)
+
+
+def _ragged_case(monkeypatch, H, npages, lens, psize=8, W=None, int8=False,
+                 vmem=None, dh=8, seed=5):
+    """(q, kp, vp, pt, lens, scales): a slot's live pages in table order,
+    the null page behind them as the scheduler leaves it; `vmem` shrinks
+    the kernel's budget so that `_rpa_plan` has to split."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import pallas_kernels as pk
+    if vmem:
+        monkeypatch.setattr(pk, "_RPA_VMEM_BUDGET", vmem)
+    rng = np.random.RandomState(seed)
+    S = len(lens)
+    live = [-(-n // psize) for n in lens]
+    assert max(live) == npages          # one slot fills every page
+    pt = np.zeros((S, npages), np.int32)
+    ids = iter(rng.permutation(sum(live)) + 1)
+    for s, n in enumerate(live):
+        pt[s, :n] = [next(ids) for _ in range(n)]
+    shape = (H, sum(live) + 1, psize, dh)
+    if int8:
+        kp, vp = (rng.randint(-127, 128, shape).astype(np.int8)
+                  for _ in range(2))
+        scales = tuple(jnp.asarray((rng.rand(*shape[:2]) * 0.05 + 1e-3)
+                                   .astype(np.float32)) for _ in range(2))
+    else:
+        kp, vp = (rng.randn(*shape).astype(np.float32) for _ in range(2))
+        scales = (None, None)
+    q = rng.randn(*((S, H, dh) if W is None else (S, W, H, dh)))
+    return (jnp.asarray(q.astype(np.float32)), jnp.asarray(kp),
+            jnp.asarray(vp), jnp.asarray(pt),
+            jnp.asarray(np.array(lens, np.int32)), scales)
+
+
+def _assert_seen_rows_close(out, ref, lens):
+    """Every query row that sees a key at all: an empty slot's first row
+    is garbage nobody reads, in the kernel and the fallback alike."""
+    out, ref = np.asarray(out), np.asarray(ref)
+    if out.ndim == 3:
+        out, ref = out[:, None], ref[:, None]
+    seen = (np.asarray(lens)[:, None] + np.arange(out.shape[1])) > 0
+    assert seen.sum() >= seen.size - 1
+    np.testing.assert_allclose(out[seen], ref[seen], rtol=2e-6, atol=2e-6)
+
+
 @pytest.mark.parametrize("lanes", [None, 128], ids=["lanes=dh", "lanes=128"])
 def test_paged_attention_lax_matches_shared_math(lanes):
     """The gather fallback must be EXACTLY the shared single-query math
@@ -145,8 +196,12 @@ def test_paged_attention_lax_matches_shared_math(lanes):
     assert np.array_equal(np.asarray(out), np.asarray(ref))
 
 
-@pytest.mark.parametrize("cfg", [{}, {"rpa_block_k": 8}, {"lanes": 128}],
-                         ids=["default", "block_k=8", "lanes=128"])
+@pytest.mark.parametrize("cfg", [
+    {}, {"rpa_block_k": 8}, {"lanes": 128}, {"ragged": _RAGGED},
+    {"ragged": dict(_RAGGED, psize=16, lens=(0, 176, 37, 1)),
+     "rpa_block_k": 8}, {"ragged": _SPLIT}],
+    ids=["default", "block_k=8", "lanes=128", "ragged", "ragged-block_k=8",
+         "split"])
 def test_paged_attention_kernel_interpret(monkeypatch, cfg):
     """The Pallas ragged-paged kernel numerics, pinned on CPU via
     interpret mode (same harness as the flash-kernel tests) — at the
@@ -154,21 +209,27 @@ def test_paged_attention_kernel_interpret(monkeypatch, cfg):
     knob (psize=16 fixture so a sub-page tile is legal), AND over pools
     whose rows are whole lane tiles, as the server keeps them (the
     lanes past the head hold sevens): every reachable block config
-    must reproduce the lax fallback."""
+    must reproduce the lax fallback. So must the shapes a grid step of
+    all a slot's heads and eight of its pages does not divide
+    (`_RAGGED`, `_SPLIT`)."""
     monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
     from mxnet_tpu.ops.pallas_kernels import (_paged_attention_lax,
                                               ragged_paged_attention)
     from mxnet_tpu.tune import overrides
     cfg = dict(cfg)
-    q, kp, vp, pt, lens = _paged_fixture(psize=16 if "rpa_block_k" in cfg
-                                         else 8, lanes=cfg.pop("lanes", 0))
-    if "rpa_block_k" in cfg:
-        lens = lens * 2              # reach into the second K block
+    if "ragged" in cfg:
+        q, kp, vp, pt, lens, _ = _ragged_case(monkeypatch,
+                                              **cfg.pop("ragged"))
+    else:
+        q, kp, vp, pt, lens = _paged_fixture(
+            psize=16 if "rpa_block_k" in cfg else 8,
+            lanes=cfg.pop("lanes", 0))
+        if "rpa_block_k" in cfg:
+            lens = lens * 2          # reach into the second K block
     with overrides.scope(cfg):
         out_k = ragged_paged_attention(q, kp, vp, pt, lens)
     ref = _paged_attention_lax(q, kp, vp, pt, lens)
-    np.testing.assert_allclose(np.asarray(out_k), np.asarray(ref),
-                               rtol=2e-6, atol=2e-6)
+    _assert_seen_rows_close(out_k, ref, lens)
 
 
 # --------------------------------------------------- decode-path parity
@@ -1123,9 +1184,12 @@ def test_paged_attention_multi_rowwise_matches_single():
                                    rtol=2e-6, atol=2e-6, err_msg=str(i))
 
 
-@pytest.mark.parametrize("cfg", [{}, {"rpa_sublanes": 16},
-                                 {"rpa_block_k": 8}],
-                         ids=["default", "sublanes=16", "block_k=8"])
+@pytest.mark.parametrize("cfg", [
+    {}, {"rpa_sublanes": 16}, {"rpa_block_k": 8},
+    {"ragged": dict(_RAGGED, W=3)}, {"ragged": dict(_RAGGED, W=1)},
+    {"ragged": dict(_SPLIT, W=3)}],
+    ids=["default", "sublanes=16", "block_k=8", "ragged-W=3", "ragged-W=1",
+         "split-W=3"])
 def test_paged_attention_multi_kernel_interpret(monkeypatch, cfg):
     """The widened Pallas kernel numerics, pinned on CPU via interpret
     mode against the lax fallback (same harness as the 1-wide test) —
@@ -1136,16 +1200,20 @@ def test_paged_attention_multi_kernel_interpret(monkeypatch, cfg):
     from mxnet_tpu.ops.pallas_kernels import (_paged_attention_lax_multi,
                                               ragged_paged_attention)
     from mxnet_tpu.tune import overrides
-    q1, kp, vp, pt, lens = (_paged_fixture() if "rpa_block_k" not in cfg
-                            else _paged_fixture(psize=16))
-    S, H, dh = q1.shape
-    rng = np.random.RandomState(22)
-    q = jnp.asarray(rng.randn(S, 4, H, dh).astype(np.float32))
+    cfg = dict(cfg)
+    if "ragged" in cfg:
+        q, kp, vp, pt, lens, _ = _ragged_case(monkeypatch,
+                                              **cfg.pop("ragged"))
+    else:
+        q1, kp, vp, pt, lens = (_paged_fixture() if "rpa_block_k" not in cfg
+                                else _paged_fixture(psize=16))
+        S, H, dh = q1.shape
+        rng = np.random.RandomState(22)
+        q = jnp.asarray(rng.randn(S, 4, H, dh).astype(np.float32))
     with overrides.scope(cfg):
         out_k = ragged_paged_attention(q, kp, vp, pt, lens)
     ref = _paged_attention_lax_multi(q, kp, vp, pt, lens)
-    np.testing.assert_allclose(np.asarray(out_k), np.asarray(ref),
-                               rtol=2e-6, atol=2e-6)
+    _assert_seen_rows_close(out_k, ref, lens)
 
 
 def test_cache_aware_admission_prefers_warm_prefix_under_pressure():
@@ -1365,39 +1433,26 @@ def test_weight_int8_serve_matches_fp32():
     assert srv.pool.in_use() == 0
 
 
-def test_paged_attention_quant_kernel_interpret(monkeypatch):
-    """The quantised Pallas kernels' numerics (scales via bitcast
-    scalar prefetch, dequant in VMEM), pinned on CPU via interpret mode
-    against the lax gathered-dequant fallback — 1-wide and widened."""
+@pytest.mark.parametrize("tables", [dict(H=2, npages=2, lens=(12, 5, 16)),
+                                    _RAGGED, _SPLIT],
+                         ids=["fixture", "ragged", "split"])
+@pytest.mark.parametrize("W", [None, 1, 3], ids=["single", "W=1", "W=3"])
+def test_paged_attention_quant_kernel_interpret(monkeypatch, tables, W):
+    """The quantised Pallas kernels' numerics (scales via scalar
+    prefetch, one a head and page inside a step of several heads and
+    pages, dequant in VMEM), pinned on CPU via interpret mode against
+    the lax gathered-dequant fallback — 1-wide and widened."""
     monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
-    import jax.numpy as jnp
     from mxnet_tpu.ops.pallas_kernels import (
         _paged_attention_lax, _paged_attention_lax_multi,
         ragged_paged_attention)
-    rng = np.random.RandomState(0)
-    S, H, dh, P, psize = 3, 2, 8, 9, 8
-    q = jnp.asarray(rng.randn(S, H, dh).astype(np.float32))
-    kp = jnp.asarray(rng.randint(-127, 128, (H, P, psize, dh))
-                     .astype(np.int8))
-    vp = jnp.asarray(rng.randint(-127, 128, (H, P, psize, dh))
-                     .astype(np.int8))
-    ks = jnp.asarray((rng.rand(H, P) * 0.05 + 1e-3).astype(np.float32))
-    vs = jnp.asarray((rng.rand(H, P) * 0.05 + 1e-3).astype(np.float32))
-    pt = jnp.asarray(np.array([[1, 2], [3, 0], [4, 5]], np.int32))
-    lens = jnp.asarray(np.array([12, 5, 16], np.int32))
+    q, kp, vp, pt, lens, (ks, vs) = _ragged_case(monkeypatch, W=W, int8=True,
+                                                 **tables)
     out = ragged_paged_attention(q, kp, vp, pt, lens,
                                  k_scales=ks, v_scales=vs)
-    ref = _paged_attention_lax(q, kp, vp, pt, lens,
-                               k_scales=ks, v_scales=vs)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-6, atol=2e-6)
-    qm = jnp.asarray(rng.randn(S, 3, H, dh).astype(np.float32))
-    outm = ragged_paged_attention(qm, kp, vp, pt, lens,
-                                  k_scales=ks, v_scales=vs)
-    refm = _paged_attention_lax_multi(qm, kp, vp, pt, lens,
-                                      k_scales=ks, v_scales=vs)
-    np.testing.assert_allclose(np.asarray(outm), np.asarray(refm),
-                               rtol=2e-6, atol=2e-6)
+    lax_fn = _paged_attention_lax if W is None else _paged_attention_lax_multi
+    _assert_seen_rows_close(
+        out, lax_fn(q, kp, vp, pt, lens, k_scales=ks, v_scales=vs), lens)
 
 
 def test_quant_degrade_honours_deadline():

@@ -127,6 +127,63 @@ def test_ragged_paged_attention_compiles(chip_compile, window, q_dtype,
                         ("mxtpu_rpa",)) == {"mxtpu_rpa": 1}
 
 
+# the benchmark server's own shapes (benchmarks/configs/nmt_base.json): 256
+# slots, 2049 pages, 8 pages a slot; or heads and pages past what one
+# grid step's fast memory holds
+def pallas_grids(fn, *args):
+    """The grid of every `pallas_call` in fn's jaxpr."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield tuple(eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize("window,kv_dtype,heads,dh,psize,npages,plan", [
+    (None, F32, 8, 64, 16, 8, (8, 8)), (None, I8, 8, 64, 16, 8, (8, 8)),
+    (4, F32, 8, 64, 16, 8, (8, 8)), (4, I8, 8, 64, 16, 8, (8, 8)),
+    (None, F32, 8, 64, 16, 20, (8, 8)), (None, F32, 32, 128, 128, 8, (32, 1)),
+    (None, F32, 64, 128, 256, 4, (16, 1)),
+], ids=["single-f32", "single-int8", "w4-f32", "w4-int8", "20-pages",
+        "32-heads-of-128-tokens", "64-heads-of-256-tokens"])
+def test_ragged_paged_attention_grid_at_the_servers_shapes(
+        chip_compile, window, kv_dtype, heads, dh, psize, npages, plan):
+    """One `mxtpu_rpa` call takes a slot's heads and eight of its pages
+    a grid step (PR 31): 256 steps at the benchmark server's shapes,
+    where one (slot, head, page) a step took 16,384. The steps follow
+    from the shapes through `_rpa_plan`, which `_rpa_pallas` itself
+    uses: a table eight pages do not divide, and blocks past the fast
+    memory's budget, take fewer pages, then fewer heads, a step, and
+    compile."""
+    slots, pool = 256, 2049 if psize == 16 else 65
+    lanes = pk.pool_lanes(dh)
+    assert pk._rpa_plan(heads, npages, psize, lanes,
+                        jnp.dtype(kv_dtype).itemsize) == plan
+    grid = pk._rpa_steps(slots, heads, npages, *plan)
+    steps = grid[0] * grid[1]
+    assert steps == slots * (heads // plan[0]) * -(-npages // plan[1])
+    if (heads, npages) == (8, 8):
+        assert steps == 256
+    q = ((slots, heads, dh) if window is None
+         else (slots, window, heads, dh), F32)
+    pages = ((heads, pool, psize, lanes), kv_dtype)
+    avals = [q, pages, pages, ((slots, npages), I32), ((slots,), I32)]
+    if kv_dtype == I8:
+        avals += [((heads, pool), F32)] * 2
+
+        def attn(q, k, v, pt, ln, ks, vs):
+            return pk.ragged_paged_attention(q, k, v, pt, ln,
+                                             k_scales=ks, v_scales=vs)
+    else:
+        attn = pk.ragged_paged_attention
+    assert kernel_calls(chip_compile(attn, *avals),
+                        ("mxtpu_rpa",)) == {"mxtpu_rpa": 1}
+    assert pallas_grids(attn, *(jax.ShapeDtypeStruct(s, d)
+                                for s, d in avals)) == [grid]
+
+
 # ------------------------------------------- the pools stay where they lie
 def pool_sized_results(text, elems):
     """{opcode: count} over the instructions of one optimized-HLO text
