@@ -43,7 +43,7 @@ from ..tune import overrides as _tune_overrides
 __all__ = ["flash_attention", "flash_block_attention", "fused_layer_norm",
            "attention_reference", "on_tpu",
            "single_query_cached_attention", "ragged_paged_attention",
-           "pool_lanes",
+           "latent_paged_attention", "pool_lanes",
            "kernel_mesh"]
 
 
@@ -1322,6 +1322,185 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lengths,
         plan=_rpa_plan(H, page_tables.shape[1], psize, lanes,
                        k_pages.dtype.itemsize))
     return out if q.ndim == 4 else out[:, 0]
+
+
+# ---------------------------------------------------------------------------
+# latent paged attention (MLA decode, the absorbed form)
+# ---------------------------------------------------------------------------
+def _latent_attention_lax(q, lat_pages, page_tables, lengths, kv_rank):
+    """Pure-lax fallback of `latent_paged_attention`: each slot's pages
+    gathered into a dense (S, L, lanes) context that is keys and, in its
+    first kv_rank values, values."""
+    width = q.shape[-1]
+    ctx = lat_pages[page_tables]            # (S, npages, psize, lanes)
+    ctx = ctx.reshape(ctx.shape[0], -1, ctx.shape[-1])
+    s = jnp.einsum("shw,skw->shk", q, ctx[..., :width],
+                   preferred_element_type=jnp.float32)
+    keep = jnp.arange(ctx.shape[1])[None, :] < lengths[:, None]
+    p = jax.nn.softmax(jnp.where(keep[:, None, :], s, -1e30), -1)
+    return jnp.einsum("shk,skc->shc", p.astype(q.dtype),
+                      ctx[..., :kv_rank],
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def _mla_decode_kernel(pt_ref, len_ref, q_ref, pool_ref, o_ref, kbuf, sem,
+                       par, m_scr, l_scr, acc_scr, *, psize, cpp, kv_rank):
+    """One SLOT a grid step with all its query heads; the slot's LIVE
+    latent pages fetched by the kernel's own DMAs from the pool left in
+    HBM, `cpp` pages (a chunk of cpp * psize rows) at a time into one of
+    two VMEM buffers while the other is computed on. A block spec a page
+    costs the pipeline about 44 ns whether the page is live or not
+    (PERF.md section 6, PR 32: 1.45 of 2.09 ms a call at 32,768 specs for
+    10,400 live pages); here a dead page costs nothing, and the next
+    slot's first chunk is in flight while this slot's last is computed
+    (`par` carries the buffer's parity from step to step, so the grid is
+    sequential). A chunk's rows are one (cpp * psize, lanes) tile that
+    the heads' queries score against whole (the zero lanes past the rows'
+    width add nothing) and whose first `kv_rank` lanes are the values:
+    read once, used twice. Rows past the slot's length are masked; what a
+    buffer holds there is an older chunk's rows or the zeros it started
+    with, never a NaN. Online softmax in float32, running max and sum
+    lane-replicated (rows, 128)."""
+    s_idx, n_slots = pl.program_id(0), pl.num_programs(0)
+    npages = pt_ref.shape[1]
+    chunk = cpp * psize
+    rows = q_ref.shape[1]
+
+    def copies(slot, c, buf, go):
+        """Start (or wait for) the DMAs of chunk `c` of `slot`: a page a
+        descriptor, live pages only; start and wait see the same ones."""
+        length = len_ref[slot]
+        for i in range(cpp):
+            pg = c * cpp + i
+
+            @pl.when(pg * psize < length)
+            def _():
+                dma = pltpu.make_async_copy(
+                    pool_ref.at[pt_ref[slot, jnp.minimum(pg, npages - 1)]],
+                    kbuf.at[buf, pl.ds(i * psize, psize)], sem.at[buf])
+                dma.start() if go else dma.wait()
+
+    @pl.when(s_idx == 0)
+    def _first():
+        kbuf[...] = jnp.zeros_like(kbuf)
+        par[0] = 0
+        copies(0, 0, 0, True)
+
+    m_scr[:] = jnp.full_like(m_scr, -1e30)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    length, first = len_ref[s_idx], par[0]
+    n_chunks = jnp.maximum(1, lax.div(length + (chunk - 1), chunk))
+    nxt = jnp.minimum(s_idx + 1, n_slots - 1)
+
+    def body(c, carry):
+        buf = lax.rem(first + c, 2)
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            copies(s_idx, c + 1, 1 - buf, True)
+
+        @pl.when((c + 1 == n_chunks) & (s_idx + 1 < n_slots))
+        def _():
+            copies(nxt, 0, 1 - buf, True)
+
+        copies(s_idx, c, buf, False)
+        k = kbuf[buf]
+        s = jax.lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        kj = c * chunk + lax.broadcasted_iota(jnp.int32, (rows, chunk), 1)
+        s = jnp.where(kj < length, s, -1e30)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(k.dtype), k[:, :kv_rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
+
+    lax.fori_loop(0, n_chunks, body, 0)
+    par[0] = lax.rem(first + n_chunks, 2)
+    # a slot with length 0 has every row masked: p = 1 over rows that are
+    # finite, an output nobody reads
+    o_ref[0] = (acc_scr[:] /
+                jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("kv_rank", "cpp", "interpret"))
+def _mla_decode_pallas(q, lat_pages, page_tables, lengths, *, kv_rank, cpp,
+                       interpret):
+    """q (S, H, width) pre-scaled; lat_pages (P, psize, lanes >= width);
+    returns (S, H, kv_rank). Jitted, as `_rpa_pallas` is and for its
+    reason: a decoder's layers trace and lower the body once."""
+    S, H, width = q.shape
+    psize, lanes = lat_pages.shape[1:]
+    # whole sublane tiles of query rows: 8 rows of 4 bytes, 16 of 2
+    tile = 8 * max(1, 4 // q.dtype.itemsize)
+    rows = -(-H // tile) * tile
+    if rows != H or lanes != width:
+        q = jnp.pad(q, ((0, 0), (0, rows - H), (0, lanes - width)))
+    out = pl.pallas_call(
+        functools.partial(_mla_decode_kernel, psize=psize, cpp=cpp,
+                          kv_rank=kv_rank),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(S,),
+            in_specs=[pl.BlockSpec((1, rows, lanes),
+                                   lambda s, pt, ln: (s, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, rows, kv_rank),
+                                   lambda s, pt, ln: (s, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, cpp * psize, lanes), lat_pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, kv_rank), jnp.float32)]),
+        out_shape=_sds((S, rows, kv_rank), q.dtype, q, lat_pages),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="mxtpu_mla_decode",
+    )(page_tables.astype(jnp.int32), lengths.astype(jnp.int32), q,
+      lat_pages)
+    return out[:, :H]
+
+
+# rows a chunk of `mxtpu_mla_decode` holds: whole pages up to this many
+# (512 rows of 640 lanes are 0.65 MB a buffer, and two buffers)
+_MLA_STEP_KEYS = 512
+
+
+def latent_paged_attention(q, lat_pages, page_tables, lengths, kv_rank):
+    """Decode attention of multi-head latent attention in its absorbed
+    form, one launch a layer and decode step over a paged LATENT cache.
+
+    q: (S, H, width) one query a slot and head against cached rows,
+    already scaled: `q_lat | q_rope`, width = kv_rank + rope size;
+    lat_pages: (P, psize, lanes >= width) ONE pool a layer, a row a token:
+    `c_kv | k_rope` and zeros up to `lanes` (keep a pool at
+    `pool_lanes(width)`: whole 128-lane tiles on the chip). Every head
+    reads the same rows: they are its keys and, in their first `kv_rank`
+    values, its values. page_tables (S, npages) int32 and lengths (S,)
+    (the current position included) as `ragged_paged_attention` takes
+    them. Returns (S, H, kv_rank): P c_kv, before the value up-projection.
+
+    On the TPU (or MXTPU_PALLAS_INTERPRET=1) the Pallas kernel
+    `mxtpu_mla_decode`: a slot a grid step, its live pages fetched by the
+    kernel's own DMAs `_MLA_STEP_KEYS` rows at a time, the page ids from
+    scalar prefetch. Elsewhere a lax gather with the same numbers."""
+    psize = lat_pages.shape[1]
+    if not _rpa_pallas_ok(psize):
+        return _latent_attention_lax(q, lat_pages, page_tables, lengths,
+                                     kv_rank)
+    cpp = max(1, min(page_tables.shape[1], _MLA_STEP_KEYS // psize))
+    return _mla_decode_pallas(q, lat_pages, page_tables, lengths,
+                              kv_rank=kv_rank, cpp=cpp,
+                              interpret=_interpret())
 
 
 # ---------------------------------------------------------------------------

@@ -3,12 +3,18 @@ ONE cached prefill executable and ONE cached decode executable behind the
 same `Server`, `Scheduler`, `PagePool` and `EngineLoop` that serve
 `TransformerNMT` through `serve.decode.DecodeRuntime`.
 
-Two kinds of device state live side by side, both donated to both
-executables so every write is in place:
+Three kinds of device state live side by side, each only where the
+model's pattern has its layers, all donated to both executables so every
+write is in place:
 
-  * paged KV for the softmax-attention layers ONLY (one layer in four of
-    the published pattern, not every layer): pools of `(P, psize,
-    Hkv * dh)` a layer (the shape the decode kernel reads in place), reached through the scheduler's page tables;
+  * paged KV for the softmax-attention ("gqa") layers: pools of `(P,
+    psize, Hkv * dh)` a layer (the shape the decode kernel reads in
+    place), reached through the scheduler's page tables;
+  * latent pages for the latent-attention ("mla") layers, through the
+    SAME page tables: ONE pool `(P, psize, lanes)` a layer, a row a token
+    holding `c_kv | k_rope` (kv_rank + rope_dim values, zeros up to whole
+    128-lane tiles on the chip), which the decode kernel reads once as
+    keys and values;
   * fixed per-slot arrays that no page table reaches, for the KDA layers:
     the recurrent state `(slots, H, dv, dk)` float32 (value-major, as the
     decode kernel walks it) and the short
@@ -16,10 +22,10 @@ executables so every write is in place:
 
 A request's first argument is its prompt. Prefill runs the whole prompt
 but its last token in one dispatch (padded to the static prompt length):
-it writes the prompt's K/V into the pages the scheduler granted and
-OVERWRITES the slot's recurrent state, so a slot needs no clearing when a
-request leaves it, and a requeued request is simply prefilled again. The
-last prompt token is the first decode turn's input, so every generated
+it writes the prompt's K/V or latent rows into the pages the scheduler
+granted and OVERWRITES the slot's recurrent state, so a slot needs no
+clearing when a request leaves it, and a requeued request is simply
+prefilled again. The last prompt token is the first decode turn's input, so every generated
 token, the first included, comes out of the decode executable.
 
 Recurrent state cannot be shared by page or rewound by dropping pages:
@@ -43,6 +49,7 @@ from .. import profiler
 from ..base import MXNetError
 from ..models import decoder_lm as lm
 from ..ops.nn_ops import rms_norm
+from ..ops.pallas_kernels import pool_lanes
 from ..observability import registry as _obs_registry
 from ..observability import tracer as _tracer
 from ..observability import compilex as _compilex
@@ -78,14 +85,17 @@ class LMRuntime:
         # the static prefill length: whole KDA chunks and whole pages
         step = math.lcm(_CHUNK, self.page_size)
         self._plen = -(-self.max_src_len // step) * step
-        self._n_gqa = sum(k == "gqa" for k in spec.pattern)
-        self._n_kda = len(spec.pattern) - self._n_gqa
+        # layers of each kind: each kind keeps its own device state
+        self._n = {k: spec.pattern.count(k) for k in ("gqa", "kda", "mla")}
+        # which layers have experts (a dense layer counts no dispatch)
+        self._is_moe = np.array([f == "moe" for f in spec.ffn_kinds()],
+                                np.int64)
         self.page_reuse_refusal = (
             "the model has recurrent (KDA) layers: a slot's state after a "
             "prefix is one array that pages neither share nor rewind, so "
             "prefix-cache adoption and rejected speculative drafts would "
             "leave it wrong; both wait for state snapshots"
-            if self._n_kda else
+            if self._n["kda"] else
             "the decoder-only runtime prefills a whole prompt in one "
             "dispatch and decodes one token a turn: it cannot start after "
             "adopted pages and has no widened verify executable yet")
@@ -118,15 +128,19 @@ class LMRuntime:
         failed dispatch consumed the donated buffers)."""
         s, sp = self.slots, self.spec
         pool = (self.num_pages, self.page_size, sp.kv_heads * sp.head_dim)
+        latent = (self.num_pages, self.page_size,
+                  pool_lanes(sp.kv_rank + sp.rope_dim))
         c = 3 * sp.kda_heads * sp.kda_head_dim
         self._state = {
-            "k": [jnp.zeros(pool, self._dtype) for _ in range(self._n_gqa)],
-            "v": [jnp.zeros(pool, self._dtype) for _ in range(self._n_gqa)],
+            "k": [jnp.zeros(pool, self._dtype) for _ in range(self._n["gqa"])],
+            "v": [jnp.zeros(pool, self._dtype) for _ in range(self._n["gqa"])],
+            "lat": [jnp.zeros(latent, self._dtype)
+                    for _ in range(self._n["mla"])],
             "kda": [jnp.zeros((s, sp.kda_heads, sp.kda_head_dim,
                                sp.kda_head_dim), jnp.float32)
-                    for _ in range(self._n_kda)],
+                    for _ in range(self._n["kda"])],
             "conv": [jnp.zeros((s, sp.conv_kernel - 1, c), self._dtype)
-                     for _ in range(self._n_kda)],
+                     for _ in range(self._n["kda"])],
         }
         # always-on counters of the expert layers, by layer, kept on the
         # host so that a reader never touches a donated buffer: a decode
@@ -146,6 +160,8 @@ class LMRuntime:
         self.routing = {"prefill": None, "decode": None}
         _obs_registry().gauge("serve_slot_state_bytes").set(
             self.slot_state_bytes())
+        _obs_registry().gauge("serve_latent_cache_bytes").set(
+            self.latent_cache_bytes())
 
     def slot_state_bytes(self):
         """Device bytes of the per-slot arrays (recurrent state and
@@ -153,9 +169,19 @@ class LMRuntime:
         return sum(a.size * a.dtype.itemsize
                    for a in self._state["kda"] + self._state["conv"])
 
+    def latent_cache_bytes(self):
+        """Device bytes of the latent pools as they are kept (the rows'
+        padding to whole lane tiles included)."""
+        return sum(a.size * a.dtype.itemsize for a in self._state["lat"])
+
     def kv_bytes_per_page(self):
+        """What one page holds over all layers: K and V of the "gqa"
+        layers, kv_rank + rope_dim values a token of the "mla" layers
+        (values, not the tiles they are kept in)."""
         sp = self.spec
-        return (2 * self._n_gqa * self.page_size * sp.kv_heads * sp.head_dim
+        per_token = (2 * self._n["gqa"] * sp.kv_heads * sp.head_dim
+                     + self._n["mla"] * (sp.kv_rank + sp.rope_dim))
+        return (per_token * self.page_size
                 * jnp.dtype(self._dtype).itemsize)
 
     def moe_counters(self):
@@ -173,14 +199,19 @@ class LMRuntime:
     def _count(self, counts, prefill=False):
         self._moe["rows"] += counts
         self._moe["touched"] += (counts > 0).sum(1)
-        self._moe["dispatches"] += 1
+        self._moe["dispatches"] += self._is_moe
         if prefill:
-            self._moe["dispatches"][-1] -= 1
+            self._moe["dispatches"][-1] -= self._is_moe[-1]
 
     @property
     def kda_state(self):
         """The recurrent state arrays, one a KDA layer (read-only use)."""
         return list(self._state["kda"])
+
+    @property
+    def latent_pages(self):
+        """The latent pools, one an "mla" layer (read-only use)."""
+        return list(self._state["lat"])
 
     @property
     def conv_tails(self):
@@ -196,17 +227,34 @@ class LMRuntime:
             perm[new] = old
         profiler.record_dispatch("serve_page_remap")
         st = self._state
-        n = self._n_gqa
-        pools = self._remap_fn(st["k"] + st["v"], jnp.asarray(perm))
-        st["k"], st["v"] = pools[:n], pools[n:]
+        n = self._n["gqa"]
+        pools = self._remap_fn(st["k"] + st["v"] + st["lat"],
+                               jnp.asarray(perm))
+        st["k"], st["v"], st["lat"] = pools[:n], pools[n:2 * n], pools[2 * n:]
 
     # ------------------------------------------------------- programs
     def _layers(self, weights):
         """(kind, layer weights, index among the layers of its kind)."""
-        seen = {"gqa": 0, "kda": 0}
+        seen = dict.fromkeys(self._n, 0)
         for kind, L in zip(self.spec.pattern, weights["layers"]):
             yield kind, L, seen[kind]
             seen[kind] += 1
+
+    def _join(self, x, y, L, which):
+        """The residual after a sub-layer: its output normed first where
+        the block is a sandwich."""
+        if self.spec.sandwich:
+            y = rms_norm(y, L[which + "_post_gamma"], self.spec.eps)
+        return x + y
+
+    def _ffn(self, L, h, valid):
+        """A layer's feed-forward on normed rows h: (y, rows each held
+        expert took, expert ids chosen); a dense layer has neither."""
+        if "moe" in L:
+            return lm.mx_moe(L["moe"], h, valid, spec=self.spec)
+        return (lm.mx_ffn(L["ffn"], h),
+                jnp.zeros((self.spec.held_n,), jnp.int32),
+                jnp.full((h.shape[0], self.spec.top_k), -1, jnp.int32))
 
     def _decode_program(self, state, weights, page_tables, lens, tok,
                         active):
@@ -224,17 +272,20 @@ class LMRuntime:
                 y, state["k"][j], state["v"][j] = lm.mx_gqa(
                     L["mixer"], h, state["k"][j], state["v"][j],
                     page_tables, lens, page, off, spec=spec)
+            elif kind == "mla":
+                y, state["lat"][j] = lm.mx_mla(
+                    L["mixer"], h, state["lat"][j], page_tables, lens,
+                    page, off, spec=spec)
             else:
                 y, state["kda"][j], state["conv"][j] = lm.mx_kda(
                     L["mixer"], h, state["kda"][j], state["conv"][j],
                     spec=spec)
-            x = x + y
-            y, n, idx = lm.mx_moe(L["moe"], rms_norm(x, L["norm2_gamma"],
-                                                     spec.eps), valid,
-                                  spec=spec)
+            x = self._join(x, y, L, "norm1")
+            y, n, idx = self._ffn(
+                L, rms_norm(x, L["norm2_gamma"], spec.eps), valid)
             counts.append(n)
             chose.append(idx)
-            x = x + y
+            x = self._join(x, y, L, "norm2")
         x = rms_norm(x, weights["final_norm_gamma"], spec.eps)
         logits = jax.lax.dot_general(
             x, weights["head"], (((1,), (1,)), ((), ())),
@@ -265,6 +316,13 @@ class LMRuntime:
                 for name, a in (("k", k), ("v", v)):
                     state[name][j] = state[name][j].at[pages].set(
                         a.reshape(n_pg, psize, -1))
+            elif kind == "mla":
+                y, rows = lm.mx_mla_seq(L["mixer"], h, pos, spec=spec)
+                lat = state["lat"][j]
+                rows = jnp.pad(rows, ((0, 0),
+                                      (0, lat.shape[-1] - rows.shape[-1])))
+                state["lat"][j] = lat.at[pages].set(
+                    rows.reshape(n_pg, psize, -1))
             else:
                 y, s_end, pre = lm.mx_kda_seq(L["mixer"], h, valid,
                                               spec=spec)
@@ -280,21 +338,21 @@ class LMRuntime:
                 chose.append(jnp.full((self._plen, spec.top_k), -1,
                                       jnp.int32))
                 break
-            x = x + y
-            y, c, idx = lm.mx_moe(L["moe"], rms_norm(x, L["norm2_gamma"],
-                                                     spec.eps), valid,
-                                  spec=spec)
+            x = self._join(x, y, L, "norm1")
+            y, c, idx = self._ffn(
+                L, rms_norm(x, L["norm2_gamma"], spec.eps), valid)
             counts.append(c)
             chose.append(idx)
-            x = x + y
+            x = self._join(x, y, L, "norm2")
         return state, jnp.stack(counts), jnp.stack(chose)
 
     # ---------------------------------------------------------- calls
     def prefill(self, slot, prompt, pages):
         """Run all but the last token of `prompt` into decode slot `slot`
-        (ONE dispatch): K/V into `pages` (the slot's granted pages, in
-        order), the recurrent state and convolution tails into the slot's
-        rows, whatever a previous request left there overwritten."""
+        (ONE dispatch): K/V or latent rows into `pages` (the slot's
+        granted pages, in order), the recurrent state and convolution
+        tails into the slot's rows, whatever a previous request left
+        there overwritten."""
         toks = np.asarray(prompt, np.int32).reshape(-1)
         if not 1 <= toks.size <= self.max_src_len:
             raise MXNetError(f"prompt of {toks.size} tokens: this server "

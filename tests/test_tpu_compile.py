@@ -322,3 +322,20 @@ def test_kda_step_compiles(chip_compile):
         lambda *a: kda.kda_step_slots(*a)[1], vec, vec, vec, vec,
         ((16, 64), F32), ((16, 64, 128, 128), F32))
     assert kernel_calls(text, ("mxtpu_kda_step",)) == {"mxtpu_kda_step": 1}
+
+
+# the latent-attention server's shapes (benchmarks/configs/
+# pangu_ultra_ep16.json): 128 query heads against ONE latent row a token,
+# 512 + 64 values in 640 lanes, 16-token pages, 128 pages a slot
+def test_latent_paged_attention_compiles(chip_compile):
+    slots, pool = 16, 16 * 128 + 1
+    text = chip_compile(
+        lambda *a: pk.latent_paged_attention(*a, 512),
+        ((slots, 128, 576), BF16), ((pool, 16, 640), BF16),
+        ((slots, 128), I32), ((slots,), I32))
+    assert kernel_calls(text, ("mxtpu_mla_decode",)) \
+        == {"mxtpu_mla_decode": 1}
+    # the pool is read where it lies: nothing of its size is made
+    import re
+    assert not re.search(r"= bf16\[2049,16,\d+[^=]* (copy|transpose|"
+                         r"reshape|fusion)\(", text)
