@@ -53,7 +53,7 @@ from ..ops.pallas_kernels import pool_lanes
 from ..observability import registry as _obs_registry
 from ..observability import tracer as _tracer
 from ..observability import compilex as _compilex
-from .decode import MemoryStateLost
+from .decode import MemoryStateLost, _raised
 from .kv_pages import NULL_PAGE
 
 __all__ = ["LMRuntime"]
@@ -66,7 +66,6 @@ class LMRuntime:
     serving engine. The scheduler hands it host-side int arrays only."""
 
     kv_quant = False
-    prefill_writes_pages = True     # the scheduler hands prefill the pages
 
     def __init__(self, model, slots, num_pages, page_size,
                  max_pages_per_slot, max_prompt_len, width=1):
@@ -101,6 +100,7 @@ class LMRuntime:
             "adopted pages and has no widened verify executable yet")
         self.decode_traces = 0
         self.prefill_traces = 0
+        self._m_rows = _obs_registry().counter("serve_prefill_rows")
         self.reset_pages()
         self._decode_fn = _compilex.instrument(
             jax.jit(self._decode_program, donate_argnums=(0,)),
@@ -367,6 +367,7 @@ class LMRuntime:
         row = np.full((self.max_pages_per_slot,), NULL_PAGE, np.int32)
         row[:len(pages)] = pages
         profiler.record_dispatch("serve_prefill")
+        self._m_rows.inc()
         old = jax.tree_util.tree_leaves(self._state)
 
         def launch():
@@ -392,6 +393,15 @@ class LMRuntime:
                     f"prefill failed after consuming the donated state: "
                     f"{type(e).__name__}: {e}") from e
             raise
+
+    def prefill_many(self, entries):
+        """A turn's admissions, `entries` = [(slot, prompt, pages)], one
+        `prefill` dispatch each in the order given (a prompt reads every
+        weight: the device bounds this prefill, not the dispatches).
+        Yields (1, the exception or None) after each, as
+        `DecodeRuntime.prefill_many` does a dispatch."""
+        for entry in entries:
+            yield 1, _raised(self.prefill, *entry)
 
     def decode(self, page_tables, lens, tok, active):
         """One decode step for every slot (ONE dispatch). Returns

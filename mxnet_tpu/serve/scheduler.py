@@ -3,8 +3,10 @@
 Every `step()` is one turn of the serving crank:
 
   1. ADMIT — pop queued requests into free decode slots while pages are
-     available (all-or-nothing first-page grant), running the cached
-     prefill executable per admission;
+     available (all-or-nothing first-page grant), then hand the runtime
+     the turn's whole list: the translation runtime encodes up to
+     `prefill_rows` sources in ONE dispatch of its cached prefill
+     executable, a decoder-only runtime takes one dispatch a prompt;
   2. DECODE — one shared decode dispatch for ALL active slots (mixed
      lengths share the ragged-paged-attention launch), growing each
      active request by one token and one cache position, allocating a
@@ -642,11 +644,53 @@ class Scheduler:
             self._m_active.set(self.active_count())
 
     def _admit(self, res=None):
-        admitted = 0
-        while True:
-            free = [s for s, r in enumerate(self._slots) if r is None]
-            if not free:
-                break
+        """A turn's admissions: gather what can start, hand the runtime
+        the whole list (`prefill_many`: ONE dispatch for up to
+        `prefill_rows` requests of an encoder's sources; a decoder-only
+        runtime takes one a prompt), then stamp each dispatch's requests
+        into their slots as it returns."""
+        gathered = self._gather_admissions()
+        if not gathered:
+            return 0
+        admitted = done = 0
+        for n, err in self._rt.prefill_many(
+                [(s, req.src, pages) for s, req, pages, *_ in gathered]):
+            group = gathered[done:done + n]
+            done += n
+            if err is None:
+                for entry in group:
+                    self._seat(*entry)
+                admitted += n
+                continue
+            # the dispatch failed: its requests fail, their pages go
+            # back, their slots stay free, and the turn goes on
+            for _s, req, pages, *_ in group:
+                self._pool.free(pages)
+                self._m_failed.inc()
+                req._finish("failed", f"prefill error: {err!r}")
+            if isinstance(err, MemoryStateLost):
+                # the donated memory buffers died: EVERY in-flight slot
+                # lost its encoder state (the runtime already rebuilt
+                # zeroed buffers) — restart those requests from scratch;
+                # re-admission re-prefills each slot
+                self._fail_inflight(
+                    [(s2, r2) for s2, r2 in enumerate(self._slots)
+                     if r2 is not None],
+                    res if res is not None else StepResult(), err,
+                    reset_pages=False)
+        if admitted:
+            self._m_active.set(self.active_count())
+        return admitted
+
+    def _gather_admissions(self):
+        """Pop queued requests while a free slot and their first pages
+        are there: [(slot, request, pages, known, adopted positions,
+        prefilled positions)], nothing dispatched yet. A slot is reserved
+        by its place in the list."""
+        free = [s for s, r in enumerate(self._slots) if r is None]
+        psize = self._pool.page_size
+        gathered = []
+        while len(gathered) < len(free):
             with self._lock:
                 if not self._queue:
                     break
@@ -662,7 +706,6 @@ class Scheduler:
                 except _finj.FaultInjected:
                     self._degrade_quant(req)
                     continue
-            psize = self._pool.page_size
             # what is known before generation, and how much of it the
             # runtime's prefill caches (a decoder-only prompt; nothing
             # of an encoder's source)
@@ -696,7 +739,7 @@ class Scheduler:
                 first = self._alloc_pages(self._pool.pages_for(
                     len(hit) * psize + prefilled + 1) - len(hit))
             except PageAllocError:
-                # no first page -> push back and stop admitting; decode
+                # no first page -> push back and stop gathering; decode
                 # progress on the current actives will free pages
                 if hit:
                     self._pool.free(hit)
@@ -704,55 +747,33 @@ class Scheduler:
                     self._queue.appendleft(req)
                     self._m_queue.set(len(self._queue))
                 break
-            pages = hit + first
-            s = free[0]
-            try:
-                if self._rt.prefill_writes_pages:
-                    self._rt.prefill(s, req.src, pages)
-                else:
-                    self._rt.prefill(s, req.src)
-            except Exception as e:
-                self._pool.free(pages)
-                self._m_failed.inc()
-                req._finish("failed", f"prefill error: {e!r}")
-                if isinstance(e, MemoryStateLost):
-                    # the donated memory buffers died: EVERY in-flight
-                    # slot lost its encoder state (the runtime already
-                    # rebuilt zeroed buffers) — restart those requests
-                    # from scratch; re-admission re-prefills each slot
-                    self._fail_inflight(
-                        [(s2, r2) for s2, r2 in enumerate(self._slots)
-                         if r2 is not None],
-                        res if res is not None else StepResult(), e,
-                        reset_pages=False)
-                    break
-                continue
-            req.state = "running"
-            req._slot = s
-            req._pages = pages
-            req.known = known
-            req.prompt_cached_tokens = len(hit) * psize
-            req._cache_done = False
-            self._slots[s] = req
-            self._page_tables[s, :] = NULL_PAGE
-            for i, p in enumerate(pages):
-                self._page_tables[s, i] = p
-            req._n_table = len(pages)
-            self._lens[s] = len(hit) * psize + prefilled
-            admitted += 1
-            # queue wait, measured where the request leaves the queue:
-            # submit to holding a slot, its own prefill dispatch included
-            req.t_admit = time.perf_counter()
-            wait = req.t_admit - req.t_submit
-            self._m_queue_wait.observe(wait)
-            if _tracer.ACTIVE:
-                _tracer.instant("serve.admitted", cat="serve", args={
-                    "id": req.id, "slot": s,
-                    "queue_wait_ms": wait * 1e3,
-                    "cached_tokens": req.prompt_cached_tokens})
-        if admitted:
-            self._m_active.set(self.active_count())
-        return admitted
+            gathered.append((free[len(gathered)], req, hit + first, known,
+                             len(hit) * psize, prefilled))
+        return gathered
+
+    def _seat(self, s, req, pages, known, adopted, prefilled):
+        """A prefilled request takes its slot."""
+        req.state = "running"
+        req._slot = s
+        req._pages = pages
+        req.known = known
+        req.prompt_cached_tokens = adopted
+        req._cache_done = False
+        self._slots[s] = req
+        self._page_tables[s, :] = NULL_PAGE
+        self._page_tables[s, :len(pages)] = pages
+        req._n_table = len(pages)
+        self._lens[s] = adopted + prefilled
+        # queue wait, measured where the request leaves the queue:
+        # submit to holding a slot, its own prefill dispatch included
+        req.t_admit = time.perf_counter()
+        wait = req.t_admit - req.t_submit
+        self._m_queue_wait.observe(wait)
+        if _tracer.ACTIVE:
+            _tracer.instant("serve.admitted", cat="serve", args={
+                "id": req.id, "slot": s,
+                "queue_wait_ms": wait * 1e3,
+                "cached_tokens": adopted})
 
     # a cold queue head is bypassed by warm-preferred admissions at most
     # this many times before FIFO order reasserts itself — bounds

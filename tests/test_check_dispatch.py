@@ -62,6 +62,11 @@ def test_captured_dispatch_budget_and_parity():
     assert res["serve_decode_retraces"] == 0
     assert res["serve_pages_leaked"] == 0
     assert res["serve_decode_steps_measured"] > 0
+    # PR 33: a turn's admissions (several, or the 1 would be vacuous)
+    # share ONE dispatch of the one prefill executable
+    assert res["serve_most_admitted_in_a_turn"] >= 2
+    assert res["serve_prefill_dispatches_per_admit_turn"] == 1
+    assert res["serve_prefill_traces"] == 1
     # ISSUE 12: the serving fast path — speculative decode holds the
     # same one-dispatch/zero-retrace budget while draft acceptance
     # varies (and genuinely accepts drafts), the prefix cache strictly

@@ -186,6 +186,32 @@ def test_server_generates_the_references_greedy_tokens(model, reference):
     srv.close()
 
 
+def test_a_turns_admissions_are_one_dispatch_a_prompt_in_queue_order(model):
+    """Over `LMRuntime` the scheduler's gathered admissions stay n
+    dispatches for n prompts, in the order they were queued (a prompt
+    reads every weight: the device bounds this prefill)."""
+    from mxnet_tpu.observability import registry
+    srv = _server(model, slots=4, max_new_tokens=2)
+    rt = srv.runtime
+    seen, orig = [], rt.prefill
+    rt.prefill = lambda slot, prompt, pages: (
+        seen.append((slot, len(prompt), len(pages))),
+        orig(slot, prompt, pages))[1]
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, VOCAB, n) for n in (3, 17, 9)]
+    rows = registry().counter("serve_prefill_rows")
+    d0, rows0 = mx.profiler.dispatch_count("serve_prefill"), rows.value
+    hs = [srv.submit(p) for p in prompts]
+    assert srv.scheduler.step().admitted == 3
+    assert mx.profiler.dispatch_count("serve_prefill") - d0 == 3
+    assert rows.value - rows0 == 3 and rt.prefill_traces == 1
+    assert seen == [(0, 3, 1), (1, 17, 3), (2, 9, 2)]
+    assert [h._slot for h in hs] == [0, 1, 2]
+    assert all(len(h.result(timeout=300)) == 2 for h in hs)
+    assert srv.pool.in_use() == 0
+    srv.close()
+
+
 def test_server_runs_on_the_engine_loop(model, reference):
     srv = _server(model, engine_driven=True)
     prompt = np.arange(1, 8)

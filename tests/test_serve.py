@@ -593,16 +593,16 @@ def test_prefill_failure_fails_only_that_request():
     rng = np.random.RandomState(25)
     ok1 = srv.submit(rng.randint(4, 50, (5,)))
     srv.scheduler.step()                     # ok1 admitted + decoding
-    orig = srv.runtime.prefill
+    orig = srv.runtime._prefill_fn
     calls = {"n": 0}
 
-    def flaky(slot, src, src_len=None):
+    def flaky(*args):
         calls["n"] += 1
         if calls["n"] == 1:
             raise RuntimeError("transient prefill failure")
-        return orig(slot, src, src_len)
+        return orig(*args)
 
-    srv.runtime.prefill = flaky
+    srv.runtime._prefill_fn = flaky
     bad = srv.submit(rng.randint(4, 50, (4,)))
     ok2 = srv.submit(rng.randint(4, 50, (6,)))
     srv.scheduler.run_until_idle(max_steps=200)
@@ -616,23 +616,22 @@ def test_prefill_memory_loss_restarts_inflight_requests():
     """A prefill failure that consumed the donated memory buffers
     (`MemoryStateLost`) restarts EVERY in-flight request — re-admission
     re-prefills each slot — with zero leaked pages."""
-    from mxnet_tpu.serve.decode import MemoryStateLost
     srv = _server(max_new_tokens=4, max_retries=2)
     rng = np.random.RandomState(26)
     inflight = srv.submit(rng.randint(4, 50, (5,)))
     srv.scheduler.step()                     # admitted + one token
     assert inflight.state == "running"
-    orig = srv.runtime.prefill
+    orig = srv.runtime._prefill_fn
     calls = {"n": 0}
 
-    def lossy(slot, src, src_len=None):
+    def lossy(mem_k, *args):
         calls["n"] += 1
         if calls["n"] == 1:
-            srv.runtime.reset_mem()          # what the real path does
-            raise MemoryStateLost("prefill consumed donated buffers")
-        return orig(slot, src, src_len)
+            mem_k.delete()                   # what a consumed donation is
+            raise RuntimeError("prefill consumed donated buffers")
+        return orig(mem_k, *args)
 
-    srv.runtime.prefill = lossy
+    srv.runtime._prefill_fn = lossy
     bad = srv.submit(rng.randint(4, 50, (4,)))
     srv.scheduler.run_until_idle(max_steps=200)
     assert bad.state == "failed"
@@ -1532,3 +1531,148 @@ def test_pool_layout_keeps_the_greedy_tokens(monkeypatch, lanes, kv_dtype,
     srv.close()
     assert srv.pool.in_use() == 0
     assert out == _JOURNEY_TOKENS
+
+
+# -------------------------- one prefill dispatch a turn (PR 33)
+def _wide_server(model, **kw):
+    """Room for 2R + 3 admissions in one turn."""
+    kw.setdefault("slots", 68)
+    kw.setdefault("max_new_tokens", 3)
+    kw.setdefault("max_queue", 80)
+    return _server(model, **kw)
+
+
+@pytest.mark.parametrize("n_of", [lambda r: 1, lambda r: 2, lambda r: r,
+                                  lambda r: r + 1, lambda r: 2 * r + 3],
+                         ids=["1", "2", "R", "R+1", "2R+3"])
+def test_a_turns_admissions_share_prefill_dispatches(n_of):
+    """n admissions in ONE turn ride in ceil(n / R) dispatches of the
+    one prefill executable (a device loop over the dispatch's rows);
+    every slot's encoder memory is what n one-row `prefill` calls
+    write, and the tokens are those of a server that admits one request
+    a turn."""
+    model = _tiny_model()
+    srv, ref = _wide_server(model), _wide_server(model)
+    rt = srv.runtime
+    r_n = rt.prefill_rows
+    assert r_n == 32                # min(slots, 32), set in the code
+    n = n_of(r_n)
+    rng = np.random.RandomState(40 + n)
+    srcs = [rng.randint(4, 50, (int(k),)).astype(np.int32)
+            for k in rng.randint(1, 17, (n,))]
+    rows = registry().counter("serve_prefill_rows")
+    d0, rows0 = mx.profiler.dispatch_count("serve_prefill"), rows.value
+    hs = [srv.submit(s) for s in srcs]
+    res = srv.scheduler.step()
+    assert res.admitted == n
+    assert mx.profiler.dispatch_count("serve_prefill") - d0 == -(-n // r_n)
+    assert rows.value - rows0 == n and rt.prefill_traces == 1
+    assert sorted(h._slot for h in hs) == list(range(n))
+    # the same sources, one row a dispatch, into the same slots
+    for h, s in zip(hs, srcs):
+        ref.runtime.prefill(h._slot, s)
+    assert mx.profiler.dispatch_count("serve_prefill") - d0 \
+        == -(-n // r_n) + n
+    for got, want in ((rt.mem_k, ref.runtime.mem_k),
+                      (rt.mem_v, ref.runtime.mem_v)):
+        np.testing.assert_allclose(np.asarray(got)[:, :n],
+                                   np.asarray(want)[:, :n],
+                                   rtol=0, atol=1e-6)
+    assert np.array_equal(np.asarray(rt.mem_vl)[:n],
+                          [s.size for s in srcs])
+    assert np.array_equal(np.asarray(rt.mem_vl), np.asarray(ref.runtime.mem_vl))
+    srv.scheduler.run_until_idle(max_steps=200)
+    one_at_a_time = []
+    for s in srcs:
+        one_at_a_time.append(ref.submit(s).result(timeout=60))
+    assert [h.result(timeout=60) for h in hs] == one_at_a_time
+    assert srv.pool.in_use() == 0 and ref.runtime.prefill_traces == 1
+    srv.close()
+    ref.close()
+
+
+def test_pool_dry_mid_gather_admits_the_gathered_and_requeues_the_rest():
+    """The third request finds no first page: the two gathered before it
+    are prefilled (one dispatch) and seated, it goes back to the head of
+    the queue with the fourth still behind it."""
+    srv = _wide_server(_tiny_model(), num_pages=3, max_new_tokens=4,
+                       prefix_cache=False)
+    rng = np.random.RandomState(41)
+    hs = [srv.submit(rng.randint(4, 50, (5,))) for _ in range(4)]
+    d0 = mx.profiler.dispatch_count("serve_prefill")
+    res = srv.scheduler.step()
+    assert res.admitted == 2
+    assert mx.profiler.dispatch_count("serve_prefill") - d0 == 1
+    assert [h.state for h in hs] == ["running", "running", "queued",
+                                     "queued"]
+    assert list(srv.scheduler._queue) == hs[2:]
+    assert all(len(h.result(timeout=60)) == 4 for h in hs)
+    assert srv.pool.in_use() == 0
+    srv.close()
+
+
+def test_a_failed_dispatch_fails_its_own_requests_only():
+    """R + 2 admissions in one turn, the FIRST dispatch made to raise
+    with the donated buffers alive: its R requests fail (each counted),
+    their pages are freed and their slots stay free; the second
+    dispatch's two requests are seated in the same turn, and the next
+    turn admits into the slots the failed ones never took."""
+    srv = _wide_server(_tiny_model())
+    rt = srv.runtime
+    r_n = rt.prefill_rows
+    rng = np.random.RandomState(42)
+    orig, calls = rt._prefill_fn, {"n": 0}
+
+    def flaky(*args):
+        calls["n"] += 1
+        if calls["n"] == 1:
+            raise RuntimeError("transient prefill failure")
+        return orig(*args)
+
+    rt._prefill_fn = flaky
+    failed = registry().counter("serve_requests", result="failed")
+    f0 = failed.value
+    hs = [srv.submit(rng.randint(4, 50, (5,))) for _ in range(r_n + 2)]
+    res = srv.scheduler.step()
+    assert res.admitted == 2 and calls["n"] == 2
+    assert [h.state for h in hs[:r_n]] == ["failed"] * r_n
+    assert failed.value - f0 == r_n
+    assert "transient prefill failure" in hs[0].error
+    assert srv.scheduler.active_count() == 2
+    assert srv.pool.in_use() == 2            # the survivors' first pages
+    assert sorted(h._slot for h in hs[r_n:]) == [r_n, r_n + 1]
+    late = srv.submit(rng.randint(4, 50, (6,)))
+    assert srv.scheduler.step().admitted == 1 and late._slot == 0
+    srv.scheduler.run_until_idle(max_steps=200)
+    assert all(len(h.result(timeout=60)) >= 1 for h in hs[r_n:] + [late])
+    assert srv.pool.in_use() == 0
+    srv.close()
+
+
+def test_memory_loss_in_a_batch_restarts_every_inflight_request():
+    """`MemoryStateLost` from a batched dispatch: the dispatch's own
+    requests fail, every request already in a slot restarts from
+    scratch, nothing leaks."""
+    srv = _wide_server(_tiny_model(), max_new_tokens=4, max_retries=2)
+    rt = srv.runtime
+    rng = np.random.RandomState(43)
+    inflight = [srv.submit(rng.randint(4, 50, (5,))) for _ in range(3)]
+    srv.scheduler.step()
+    assert [h.state for h in inflight] == ["running"] * 3
+    orig, calls = rt._prefill_fn, {"n": 0}
+
+    def lossy(mem_k, *args):
+        calls["n"] += 1
+        if calls["n"] == 1:
+            mem_k.delete()
+            raise RuntimeError("prefill consumed donated buffers")
+        return orig(mem_k, *args)
+
+    rt._prefill_fn = lossy
+    bad = [srv.submit(rng.randint(4, 50, (4,))) for _ in range(2)]
+    srv.scheduler.run_until_idle(max_steps=200)
+    assert [h.state for h in bad] == ["failed"] * 2
+    assert "MemoryStateLost" in bad[0].error
+    assert all(h.retries >= 1 and len(h.result()) == 4 for h in inflight)
+    assert srv.pool.in_use() == 0
+    srv.close()

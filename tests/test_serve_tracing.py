@@ -18,8 +18,9 @@ def _server(**kw):
                        max_length=32, dropout=0.0)
     m.initialize()
     kw.setdefault("engine_driven", False)
-    return mx.serve.Server(m, slots=2, page_size=4, max_src_len=16,
-                           max_new_tokens=6, **kw)
+    kw.setdefault("slots", 2)
+    kw.setdefault("max_new_tokens", 6)
+    return mx.serve.Server(m, page_size=4, max_src_len=16, **kw)
 
 
 def _sources(n=5):
@@ -102,6 +103,37 @@ def test_a_request_is_one_chain_of_instants_and_queue_wait_is_its_own():
     # two slots, five requests: the later ones waited for a slot
     waits = [h.t_admit - h.t_submit for h in hs]
     assert max(waits[2:]) > max(waits[:2])
+    srv.close()
+
+
+def test_a_batch_is_one_prefill_span_with_its_rows_inside_admit():
+    """Five admissions in one turn: ONE `serve.prefill` span, a child of
+    `serve.admit` inside `serve.turn`, carrying the batch's `rows` and
+    its longest source; every request of the batch is stamped after the
+    dispatch and its `queue_wait_ms` is its own `t_admit - t_submit`."""
+    srv = _server(slots=8, max_new_tokens=3)
+    srcs = _sources()
+    tracer.start()
+    hs = [srv.submit(s) for s in srcs]
+    srv.scheduler.step()
+    tracer.stop()
+    events = _events()
+    spans = _spans(events)
+    (turn,) = [s for s in spans if s[0] == "serve.turn"]
+    (admit,) = [s for s in spans if s[0] == "serve.admit"]
+    (fill,) = [s for s in spans if s[0] == "serve.prefill"]
+    assert turn[1] <= admit[1] <= fill[1] <= fill[2] <= admit[2] <= turn[2]
+    assert fill[4] == {"rows": len(srcs),
+                       "src_len": max(s.size for s in srcs)}
+    admitted = {e["args"]["id"]: e for e in events
+                if e["name"] == "serve.admitted"}
+    assert sorted(admitted) == [h.id for h in hs]
+    for h in hs:
+        e = admitted[h.id]
+        assert e["args"]["queue_wait_ms"] == (h.t_admit - h.t_submit) * 1e3
+        # stamped after the dispatch that holds it, still inside admit
+        assert fill[2] <= e["ts"] <= admit[2]
+    srv.scheduler.run_until_idle()
     srv.close()
 
 

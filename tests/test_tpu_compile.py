@@ -275,6 +275,66 @@ def test_serve_programs_make_nothing_of_a_pools_size(one_chip, chip_compile,
     assert not rewrites, made
 
 
+def test_prefill_program_writes_its_memory_buffers_in_place(one_chip,
+                                                           chip_compile):
+    """`DecodeRuntime`'s prefill program at cell 2's shapes (256 slots,
+    sources of 128, 6 layers of 8 heads of 64, float32): the three
+    memory buffers (402.7 MB each for K and V) are donated into the
+    results and nothing else in the program has their size: a trip of
+    the device loop writes its slot by `dynamic-update-slice` into the
+    carried buffer. A form XLA serves by copying (PR 29 met a scatter
+    whose window was not minor-most) shows here as a `copy` or another
+    fusion of that size (1.6 GB of traffic a dispatch). The runtime is built without its
+    device state: the program is lowered from shapes."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models.transformer import (TransformerNMT,
+                                              decoder_weights,
+                                              encoder_weights)
+    from mxnet_tpu.observability import compilex
+    from mxnet_tpu.serve.decode import DecodeRuntime
+
+    class Shapes(DecodeRuntime):
+        def reset_pages(self):
+            self.k_pages = self.v_pages = []
+            self.k_scales = self.v_scales = None
+
+        def reset_mem(self):
+            pass
+
+    layers, slots, src = 6, 256, 128
+    mx.random.seed(0)
+    model = TransformerNMT(96, units=H * DH, hidden=2048, num_layers=layers,
+                           num_heads=H, max_length=src, dropout=0.0)
+    model.initialize()
+    rt = Shapes(decoder_weights(model), encoder_weights(model), slots=slots,
+                num_pages=POOL, page_size=PSIZE, max_pages_per_slot=8,
+                max_src_len=src)
+    rows = rt.prefill_rows
+    assert rows == 32
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    mem = aval((layers, slots, H, src, DH), F32)
+    compiled = rt._prefill_fn._jfn.lower(
+        mem, mem, aval((slots,), I32), aval((rows, src + 2), I32)).compile()
+    text = compiled.as_text()
+    assert rt.prefill_traces == 1
+    assert kernel_calls(text, ("mxtpu_flash_fwd",)) \
+        == {"mxtpu_flash_fwd": layers}
+    assert compilex.inspect_hlo_text(text)["aliased_inputs"] == 3
+    made = pool_sized_results(text, layers * slots * H * src * DH)
+    moved = {op: n for op, n in made.items()
+             if op not in ("parameter", "get-tuple-element", "bitcast",
+                           "dynamic-update-slice", "scatter",
+                           "fusion:dynamic-update-slice",
+                           "fusion:scatter")}
+    assert not moved and made, made
+    # beside the 805 MB of aliased buffers the program keeps a row's
+    # activations and little else (7.8 MB when this was written)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+
+
 # the decoder-only server's shapes (benchmarks/configs/solar_open2_ep8.json):
 # 64 query heads over 8 KV heads of 128, 16-token pages, 64 pages a slot,
 # 40 experts of width 1280 under hidden 4096, KDA state 64 x 128 x 128

@@ -60,7 +60,9 @@ dispatch (the shared ragged-paged-attention decode executable), the
 decode executable must never RETRACE while slot occupancy and page
 tables vary mid-flight (mixed-length admissions/evictions between
 steps), and the KV page pool must return to zero pages in use once
-every request completes.
+every request completes. PR 33: an admission turn of n <= R requests
+(`DecodeRuntime.prefill_rows`) pays ONE prefill dispatch beside it, from
+ONE prefill executable.
 
 ISSUE 12 extension — the serving FAST PATH: speculative decode holds
 the same <=1 dispatch per warm turn with ZERO retraces of the widened
@@ -703,6 +705,8 @@ def _run_serve_phase(errors):
         srv.submit(rng.randint(4, 32, (n,)), max_new_tokens=mt)
     worst = 0
     decode_steps = 0
+    worst_admit = most_admitted = 0
+    rows = srv.runtime.prefill_rows
     for _ in range(100):
         if not sched.pending_work():
             break
@@ -710,14 +714,20 @@ def _run_serve_phase(errors):
         r = sched.step()
         if r.decoded and not r.admitted:
             # a pure decode turn: the only allowed launch is the decode
-            # executable itself (admission turns additionally pay the
-            # prefill executable per admitted request)
+            # executable itself
             worst = max(worst, profiler.dispatch_count())
             decode_steps += 1
+        elif 0 < r.admitted <= rows:
+            # an admission turn of n <= R requests additionally pays ONE
+            # prefill dispatch, however many it admits
+            worst_admit = max(worst_admit,
+                              profiler.dispatch_count("serve_prefill"))
+            most_admitted = max(most_admitted, r.admitted)
     # capture BEFORE close(): Scheduler.shutdown clears queue/slots and
     # frees pages, which would mask a wedged scheduler or a leak
     undrained = sched.pending_work()
     retraces = srv.runtime.decode_traces - warm_traces
+    prefill_traces = srv.runtime.prefill_traces
     leaked = srv.pool.in_use()
     srv.close()
     if undrained:
@@ -727,6 +737,15 @@ def _run_serve_phase(errors):
     if worst > 1:
         errors.append(f"serve decode budget exceeded: {worst} "
                       f"dispatches/turn (budget 1)")
+    if most_admitted < 2:
+        errors.append("serve phase measured no turn of several admissions")
+    if worst_admit > 1:
+        errors.append(f"serve prefill budget exceeded: {worst_admit} "
+                      f"dispatches in an admission turn of <= {rows} "
+                      f"requests (budget 1)")
+    if prefill_traces != 1:
+        errors.append(f"serve prefill executable traced {prefill_traces}x "
+                      f"(budget 1)")
     if retraces:
         errors.append(f"serve decode executable retraced {retraces}x "
                       "across occupancy changes (budget 0)")
@@ -737,6 +756,9 @@ def _run_serve_phase(errors):
         "serve_decode_budget": 1,
         "serve_decode_steps_measured": decode_steps,
         "serve_decode_retraces": retraces,
+        "serve_prefill_dispatches_per_admit_turn": worst_admit,
+        "serve_most_admitted_in_a_turn": most_admitted,
+        "serve_prefill_traces": prefill_traces,
         "serve_pages_leaked": leaked,
     }
 

@@ -86,9 +86,15 @@ BUDGETS = {
         "collective_total": 0,
         "aliased_inputs": 2,         # donated K/V page pools
     },
+    # PR 33: ONE dispatch encodes a turn's admissions in a device loop
+    # over its rows. Measured 22 fusions / 5 copies whatever R (the loop
+    # body is the parent's one-row program: 20 / 3); a form that unrolls
+    # the rows grows with R (65 / 37 at 16) and one that copies the
+    # donated memory buffers shows in the copy band first.
     "serve_prefill": {
         "fusions": (8, 36),
         "collective_total": 0,
+        "copies": (0, 10),
         "aliased_inputs": 3,         # donated mem_k / mem_v / mem_vl
     },
     # ISSUE 12: the WIDENED speculative-verify decode executable

@@ -50,7 +50,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 # ---------------------------------------------------------------------
 # Copy allowances per executable (graphlint MXTPU-G02). Measured 2026-08
 # on the pinned toolchain (jax 0.4.37 CPU): captured 5, sharded 17,
-# decode 10, prefill 3, verify 10, backward 2, fused buckets 0 — the
+# decode 10, prefill 3 (5 since PR 33's device loop over a dispatch's
+# rows, whatever R), verify 10, backward 2, fused buckets 0 — the
 # allowance leaves ~2x headroom for benign drift while still tripping a
 # donation/layout regression that starts materialising copies in bulk.
 BUDGETS = {
