@@ -1,13 +1,18 @@
-"""A decoder-only language model assembled from a layer pattern: gated
-softmax attention with grouped KV heads and no positional term, Kimi
-Delta Attention (KDA) linear-attention layers, multi-head latent
-attention (MLA) with a rotary term, and in each layer either a mixture
-of experts that is told which experts it holds or a dense SwiGLU.
+"""A decoder-only language model assembled from a layer pattern. Four
+mixers: softmax attention with grouped KV heads and no positional term
+(`"gqa"`, its output gated or not), Kimi Delta Attention linear-attention
+layers (`"kda"`), multi-head latent attention with a rotary term
+(`"mla"`) and Mamba-2 state-space layers (`"mamba"`). Two feed-forwards:
+a mixture of experts that is told which experts it holds (`"moe"`:
+SwiGLU experts, or ungated relu^2 experts with a shared expert of its own
+width) and a dense SwiGLU (`"dense"`).
 
-Every block is a pre-norm residual pair (mixer, feed-forward) with
-RMSNorm; with `LMSpec.sandwich` each sub-layer's output is normed again
-before it joins the residual. The mathematics lives in pure functions
-over plain dicts of arrays (`gqa_*`, `kda_*`, `mla_*`, `moe_forward`),
+A layer is a pre-norm residual PAIR (mixer, then feed-forward) with
+RMSNorm, or with `LMSpec.paired` false ONE sub-layer, `x + f(N(x))`,
+where `pattern` names mixers and feed-forwards alike; with
+`LMSpec.sandwich` each sub-layer's output is normed again before it
+joins the residual. The mathematics lives in pure functions over plain
+dicts of arrays (`gqa_*`, `kda_*`, `mla_*`, `mamba_*`, `moe_forward`),
 in two forms where a layer keeps state: a whole sequence (training, the
 Gluon forward, the server's prefill) and one position for a batch of
 slots (the server's decode turn). The Gluon blocks hold the parameters
@@ -35,14 +40,16 @@ from ..gluon.block import HybridBlock
 from ..ndarray.ndarray import _apply
 from ..ops import grouped_matmul as gmm
 from ..ops import kda as kda_ops
+from ..ops import ssd as ssd_ops
 from ..ops.nn_ops import dense_nt as _mm, rms_norm, swiglu
 from ..ops.pallas_kernels import flash_attention
 
 __all__ = ["LMSpec", "DecoderLM", "GatedAttention", "KDALayer",
-           "LatentAttention", "DenseFFN", "MoELayer", "lm_weights",
-           "moe_forward", "moe_route", "rope", "mla_sequence", "mx_gqa",
-           "mx_kda", "mx_mla", "mx_moe", "mx_ffn", "mx_gqa_seq",
-           "mx_kda_seq", "mx_mla_seq"]
+           "LatentAttention", "Mamba2Layer", "DenseFFN", "MoELayer",
+           "lm_weights", "moe_forward", "moe_route", "rope", "mla_sequence",
+           "mamba_sequence", "mx_gqa", "mx_kda", "mx_mla", "mx_mamba",
+           "mx_moe", "mx_ffn", "mx_gqa_seq", "mx_kda_seq", "mx_mla_seq",
+           "mx_mamba_seq"]
 
 _F32 = jnp.float32
 GATE_RANK = 128          # the low-rank gates of a KDA layer pass through this
@@ -65,7 +72,8 @@ class LMSpec(NamedTuple):
     held_n: int
     scaling: float
     eps: float
-    pattern: tuple            # "gqa" | "kda" | "mla" for each layer
+    pattern: tuple            # a layer's mixer: "gqa" | "kda" | "mla" |
+    #                           "mamba"; not `paired`, also "moe" | "dense"
     # what only an "mla" layer, a dense layer or a sandwich block reads
     q_rank: int = 0           # the query's latent
     kv_rank: int = 0          # the cached latent c_kv: key AND value
@@ -77,12 +85,43 @@ class LMSpec(NamedTuple):
     dense_width: int = 0
     sandwich: bool = False    # a norm after each sub-layer too
     router_bias: bool = True  # a selection-only bias on the router
+    paired: bool = True       # False: a layer is ONE sub-layer of `pattern`
+    attn_gate: bool = True    # a "gqa" layer's sigmoid gate on its output
+    expert_act: str = "swiglu"    # or "relu2": W_down relu(W_up u)^2
+    shared_width: int = 0     # the shared expert's; 0 = expert_width
+    # what only a "mamba" layer reads (with conv_kernel)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0        # B and C's size, the state's second axis
+    ssm_groups: int = 0       # B and C are shared by heads / groups heads
+    ssm_chunk: int = 128      # positions a step of the chunked scan
 
     def ffn_kinds(self):
         return self.ffn or ("moe",) * len(self.pattern)
 
+    def sublayers(self):
+        """(mixer or None, feed-forward or None) for each layer."""
+        if self.paired:
+            return tuple(zip(self.pattern, self.ffn_kinds()))
+        return tuple((None, k) if k in FFNS else (k, None)
+                     for k in self.pattern)
+
+    def ssm_dims(self):
+        """(d_inner = heads x head_dim, the convolution's channels
+        x | B | C)."""
+        inner = self.ssm_heads * self.ssm_head_dim
+        return inner, inner + 2 * self.ssm_groups * self.ssm_state
+
+
+FFNS = ("moe", "dense")
+
 
 # --------------------------------------------------------- expert layer
+def relu2(x):
+    """relu(x)^2, in x's type."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def moe_route(w, spec, x):
     """(expert ids (T, k) int32, weights (T, k) float32): sigmoid scores
     over ALL experts, the k largest of score + selection bias (where the
@@ -116,36 +155,53 @@ def moe_forward(w, spec, x, valid=None, tile=32):
     cap = gmm.rows_capacity(t * spec.top_k, spec.held_n, tile)
     token = jnp.arange(t * spec.top_k, dtype=jnp.int32) // spec.top_k
     rows = jnp.zeros((cap, d), x.dtype).at[dest].set(x[token], mode="drop")
-    gu = gmm.grouped_matmul(rows, w["experts_gate_up"], tile_group, used,
-                            tile)
-    g, u = jnp.split(gu, 2, -1)
-    out = gmm.grouped_matmul(jax.nn.silu(g) * u, w["experts_down"],
-                             tile_group, used, tile)
+    if spec.expert_act == "relu2":
+        # each expert's W_up kept (width, d), as a dense layer's: see
+        # `grouped_matmul`'s `nt`
+        act = relu2(gmm.grouped_matmul(rows, w["experts_up"], tile_group,
+                                       used, tile, nt=True))
+    else:
+        gu = gmm.grouped_matmul(rows, w["experts_gate_up"], tile_group,
+                                used, tile)
+        g, u = jnp.split(gu, 2, -1)
+        act = jax.nn.silu(g) * u
+    out = gmm.grouped_matmul(act, w["experts_down"], tile_group, used, tile)
     # rows of skipped tiles hold anything: select, do not multiply by 0
     picked = jnp.where(mine.reshape(-1, 1),
                        out[jnp.minimum(dest, cap - 1)].astype(_F32), 0.0)
     routed = jnp.sum(picked.reshape(t, spec.top_k, d) * wts[..., None], 1)
-    shared = swiglu(x, w["shared_gate_up"], w["shared_down"])
+    if spec.expert_act == "relu2":
+        shared = _mm(relu2(_mm(x, w["shared_up"])), w["shared_down"])
+    else:
+        shared = swiglu(x, w["shared_gate_up"], w["shared_down"])
     return (routed + shared.astype(_F32)).astype(x.dtype), counts, idx
 
 
 # ---------------------------------------------- gated grouped attention
 def gqa_project(w, spec, x):
-    """x (T, d) -> q (T, H, dh), output gate (T, H * dh), k, v
-    (T, Hkv, dh). No positional term."""
+    """x (T, d) -> q (T, H, dh), output gate (T, H * dh) or None where
+    the spec has none, k, v (T, Hkv, dh). No positional term."""
     h, hk, dh = spec.heads, spec.kv_heads, spec.head_dim
-    p = _mm(x, w["qgkv_weight"])
-    q, gate, k, v = jnp.split(p, [h * dh, 2 * h * dh, (2 * h + hk) * dh], -1)
+    if spec.attn_gate:
+        q, gate, k, v = jnp.split(
+            _mm(x, w["qgkv_weight"]),
+            [h * dh, 2 * h * dh, (2 * h + hk) * dh], -1)
+    else:
+        gate = None
+        q, k, v = jnp.split(_mm(x, w["qkv_weight"]),
+                            [h * dh, (h + hk) * dh], -1)
     t = x.shape[0]
     return (q.reshape(t, h, dh), gate, k.reshape(t, hk, dh),
             v.reshape(t, hk, dh))
 
 
 def gqa_output(w, attn, gate):
-    """W_o (attn * sigmoid(gate)); attn: (T, H, dh)."""
+    """W_o (attn * sigmoid(gate)), or W_o attn without a gate; attn:
+    (T, H, dh)."""
     a = attn.reshape(attn.shape[0], -1)
-    return _mm(a * jax.nn.sigmoid(gate.astype(_F32)).astype(a.dtype),
-               w["o_weight"])
+    if gate is not None:
+        a = a * jax.nn.sigmoid(gate.astype(_F32)).astype(a.dtype)
+    return _mm(a, w["o_weight"])
 
 
 def gqa_sequence(w, spec, x):
@@ -211,6 +267,58 @@ def kda_sequence(w, spec, x, valid=None, chunk=32):
         jnp.zeros((h, dk, dk), _F32), chunk=chunk, length=length)
     return (kda_output(w, spec, o.transpose(1, 0, 2), ogate, x.dtype),
             state, pre)
+
+
+# -------------------------------------------- Mamba-2 state-space layer
+def mamba_inputs(w, spec, x):
+    """x (T, d) -> the gate z (T, d_inner), the convolution's input
+    (T, d_inner + 2 G N) (x | B | C before it), the step sizes delta
+    (T, H) float32 after their bias and softplus."""
+    inner, conv = spec.ssm_dims()
+    z, pre, dt = jnp.split(_mm(x, w["in_weight"]), [inner, inner + conv], -1)
+    return z, pre, jax.nn.softplus(dt.astype(_F32)
+                                   + w["dt_bias"].astype(_F32))
+
+
+def mamba_xbc(spec, conv_out):
+    """SiLU, split: x (T, H, P), B and C (T, G, N), float32."""
+    inner, _ = spec.ssm_dims()
+    g, n = spec.ssm_groups, spec.ssm_state
+    a = jax.nn.silu(conv_out.astype(_F32))
+    x, b, c = jnp.split(a, [inner, inner + g * n], -1)
+    return (x.reshape(-1, spec.ssm_heads, spec.ssm_head_dim),
+            b.reshape(-1, g, n), c.reshape(-1, g, n))
+
+
+def mamba_output(w, spec, y, x, z, dtype):
+    """W_out (RMSNorm_group((y + D x) * SiLU(z)) * gamma): the norm over
+    each of the G groups of d_inner / G channels, after the gate. y, x:
+    (T, H, P) float32."""
+    t = y.shape[0]
+    y = (y + w["d_skip"].astype(_F32)[:, None] * x).reshape(t, -1)
+    y = (y * jax.nn.silu(z.astype(_F32))).reshape(t, spec.ssm_groups, -1)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + spec.eps)
+    return _mm((y.reshape(t, -1)
+                * w["norm_gamma"].astype(_F32)).astype(dtype), w["o_weight"])
+
+
+def mamba_sequence(w, spec, x, valid=None):
+    """One sequence x (T, d), T whole chunks, from a zero state. `valid`
+    (T,) bool marks the real positions, a prefix; the rest leave the
+    state alone and the chunks that hold none are not run. Returns (y,
+    final state (H, P, N) float32, the convolution's input (T, C))."""
+    z, pre, delta = mamba_inputs(w, spec, x)
+    length = None
+    if valid is not None:
+        delta = jnp.where(valid[:, None], delta, 0.0)
+        length = jnp.sum(valid.astype(jnp.int32))
+    xs, b, c = mamba_xbc(spec, kda_ops.causal_conv(
+        pre, w["conv_weight"], w["conv_bias"]))
+    y, state = ssd_ops.ssd_chunked(
+        xs, delta, -jnp.exp(w["a_log"].astype(_F32)), b, c,
+        jnp.zeros((spec.ssm_heads, spec.ssm_head_dim, spec.ssm_state), _F32),
+        chunk=spec.ssm_chunk, length=length)
+    return mamba_output(w, spec, y, xs, z, x.dtype), state, pre
 
 
 # ----------------------------------------------- latent attention (MLA)
@@ -337,6 +445,19 @@ def mx_mla(w, x, lat_pages, page_tables, lens, page, off, spec):
 
 
 @partial(jax.jit, static_argnames=("spec",))
+def mx_mamba(w, x, state, tail, spec):
+    """One decode position a slot. state: (S, H, P, N) float32; tail:
+    (S, K - 1, C), the convolution's last inputs."""
+    z, pre, delta = mamba_inputs(w, spec, x)
+    conv, tail = kda_ops.causal_conv_step(tail, pre, w["conv_weight"],
+                                          w["conv_bias"])
+    xs, b, c = mamba_xbc(spec, conv)
+    y, state = ssd_ops.ssd_step_slots(
+        xs, delta, -jnp.exp(w["a_log"].astype(_F32)), b, c, state)
+    return mamba_output(w, spec, y, xs, z, x.dtype), state, tail
+
+
+@partial(jax.jit, static_argnames=("spec",))
 def mx_moe(w, x, valid, spec):
     return moe_forward(w, spec, x, valid)
 
@@ -362,6 +483,11 @@ def mx_mla_seq(w, x, pos, spec):
     return mla_sequence(w, spec, x, pos)
 
 
+@partial(jax.jit, static_argnames=("spec",))
+def mx_mamba_seq(w, x, valid, spec):
+    return mamba_sequence(w, spec, x, valid)
+
+
 # ---------------------------------------------------------- Gluon blocks
 class _PureBlock(HybridBlock):
     """A block whose parameters are all its own and whose forward is a
@@ -382,9 +508,25 @@ class _PureBlock(HybridBlock):
         return _apply(fn, [x] + [params[n] for n in names])
 
 
+def _scan_batch(sequence, w, spec, x, chunk):
+    """A recurrent layer's sequence form over a batch x (B, T, d): each
+    sequence padded to whole chunks of its scan, the padding marked not
+    valid, the output cut back to T."""
+    t = x.shape[1]
+    pad = -t % chunk
+    valid = jnp.arange(t + pad) < t
+
+    def one(s):
+        return sequence(w, spec, jnp.pad(s, ((0, pad), (0, 0))),
+                        valid)[0][:t]
+    return jax.vmap(one)(x)
+
+
 class GatedAttention(_PureBlock):
     """Causal softmax attention, `kv_heads` KV heads under `heads` query
-    heads, no positional term, output gated by sigmoid(W_gate x)."""
+    heads, no positional term, output gated by sigmoid(W_gate x) unless
+    the spec says `attn_gate` false (`qkv_weight` then, without the
+    gate's rows)."""
 
     def __init__(self, spec, **kwargs):
         super().__init__(**kwargs)
@@ -393,8 +535,12 @@ class GatedAttention(_PureBlock):
         if h % hk:
             raise MXNetError(f"{h} query heads over {hk} KV heads")
         with self.name_scope():
-            self.qgkv_weight = self.params.get(
-                "qgkv_weight", shape=(2 * (h + hk) * dh, d))
+            if spec.attn_gate:
+                self.qgkv_weight = self.params.get(
+                    "qgkv_weight", shape=(2 * (h + hk) * dh, d))
+            else:
+                self.qkv_weight = self.params.get(
+                    "qkv_weight", shape=((h + 2 * hk) * dh, d))
             self.o_weight = self.params.get("o_weight", shape=(d, h * dh))
 
     def _pure(self, w, x):
@@ -421,14 +567,37 @@ class KDALayer(_PureBlock):
                 setattr(self, name, self.params.get(name, shape=shape))
 
     def _pure(self, w, x):
-        t = x.shape[1]
-        pad = -t % 32
-        valid = jnp.arange(t + pad) < t
+        return _scan_batch(kda_sequence, w, self._spec, x, 32)
 
-        def one(s):
-            return kda_sequence(w, self._spec,
-                                jnp.pad(s, ((0, pad), (0, 0))), valid)[0][:t]
-        return jax.vmap(one)(x)
+
+class Mamba2Layer(_PureBlock):
+    """A Mamba-2 state-space layer (SSD): one input projection to gate,
+    convolution input and step sizes, a short depthwise convolution with
+    bias over x | B | C, a scalar decay a head over a (head_dim,
+    state) state, the skip D, a gated RMSNorm over groups of channels."""
+
+    def __init__(self, spec, **kwargs):
+        super().__init__(**kwargs)
+        self._spec = spec
+        h, d = spec.ssm_heads, spec.hidden
+        if min(h, spec.ssm_head_dim, spec.ssm_state, spec.ssm_groups,
+               spec.conv_kernel) < 1 or h % spec.ssm_groups:
+            raise MXNetError("a 'mamba' layer needs ssm_heads (a multiple "
+                             "of ssm_groups), ssm_head_dim, ssm_state and "
+                             "conv_kernel")
+        inner, conv = spec.ssm_dims()
+        with self.name_scope():
+            for name, shape in (
+                    ("in_weight", (inner + conv + h, d)),
+                    ("conv_weight", (spec.conv_kernel, conv)),
+                    ("conv_bias", (conv,)), ("dt_bias", (h,)),
+                    ("a_log", (h,)), ("d_skip", (h,)),
+                    ("norm_gamma", (inner,)), ("o_weight", (d, inner))):
+                setattr(self, name, self.params.get(name, shape=shape))
+
+    def _pure(self, w, x):
+        return _scan_batch(mamba_sequence, w, self._spec, x,
+                           self._spec.ssm_chunk)
 
 
 class LatentAttention(_PureBlock):
@@ -471,9 +640,10 @@ class DenseFFN(nn.SwiGLU):
 
 
 class MoELayer(_PureBlock):
-    """Sigmoid-scored top-k routing over `num_experts` SwiGLU experts of
+    """Sigmoid-scored top-k routing over `num_experts` experts (SwiGLU,
+    or with `expert_act` "relu2" ungated, W_down relu(W_up u)^2) of
     which this block HOLDS experts held_lo .. held_lo + held_n - 1, plus
-    one shared expert. Dropless. With all experts held it is the whole
+    one shared expert of `shared_width`. Dropless. With all experts held it is the whole
     layer; with a share it is one chip's part of an expert-parallel
     layer, without the exchange."""
 
@@ -481,17 +651,24 @@ class MoELayer(_PureBlock):
         super().__init__(**kwargs)
         self._spec = spec
         d, wd, n = spec.hidden, spec.expert_width, spec.held_n
+        ws = spec.shared_width or wd
         if not 0 <= spec.held_lo <= spec.held_lo + n <= spec.num_experts:
             raise MXNetError("held experts outside the router's range")
+        if spec.expert_act not in ("swiglu", "relu2"):
+            raise MXNetError(f"expert_act {spec.expert_act!r}: 'swiglu' "
+                             f"or 'relu2'")
+        up, shared_up = (
+            (("experts_up", (n, wd, d)), ("shared_up", (ws, d)))
+            if spec.expert_act == "relu2" else
+            (("experts_gate_up", (n, d, 2 * wd)),
+             ("shared_gate_up", (2 * ws, d))))
         with self.name_scope():
             for name, shape in (
                     ("router_weight", (spec.num_experts, d)),
                     *([("router_bias", (spec.num_experts,))]
                       if spec.router_bias else []),
-                    ("experts_gate_up", (n, d, 2 * wd)),
-                    ("experts_down", (n, wd, d)),
-                    ("shared_gate_up", (2 * wd, d)),
-                    ("shared_down", (d, wd))):
+                    up, ("experts_down", (n, wd, d)),
+                    shared_up, ("shared_down", (d, ws))):
                 setattr(self, name, self.params.get(name, shape=shape))
 
     def _pure(self, w, x):
@@ -500,37 +677,49 @@ class MoELayer(_PureBlock):
             .reshape(b, t, d)
 
 
-_MIXERS = {"gqa": GatedAttention, "kda": KDALayer, "mla": LatentAttention}
+_MIXERS = {"gqa": GatedAttention, "kda": KDALayer, "mla": LatentAttention,
+           "mamba": Mamba2Layer}
 
 
 class DecoderBlock(HybridBlock):
     """x + [N](mixer(N(x))), then x + [N](ffn(N(x))): the norms after
-    the sub-layers only where the spec says sandwich."""
+    the sub-layers only where the spec says sandwich. `kind` or `ffn`
+    may be None: a layer of the one sub-layer that is left, with the
+    norm (and the names) that sub-layer has in a pair."""
 
     def __init__(self, spec, kind, ffn="moe", **kwargs):
         super().__init__(**kwargs)
         # the feed-forward child's name: "moe", or "ffn" for a dense one
-        self._ffn = "moe" if ffn == "moe" else "ffn"
+        self._ffn = {"moe": "moe", "dense": "ffn", None: None}[ffn]
+        self._mixer = kind is not None
         self._sandwich = bool(spec.sandwich)
+
+        def norm(name):
+            return nn.RMSNorm(spec.hidden, spec.eps, prefix=name + "_")
+
         with self.name_scope():
-            self.norm1 = nn.RMSNorm(spec.hidden, spec.eps, prefix="norm1_")
-            self.mixer = _MIXERS[kind](spec, prefix="mixer_")
-            self.norm2 = nn.RMSNorm(spec.hidden, spec.eps, prefix="norm2_")
+            if self._mixer:
+                self.norm1 = norm("norm1")
+                self.mixer = _MIXERS[kind](spec, prefix="mixer_")
+            if self._ffn:
+                self.norm2 = norm("norm2")
             if self._ffn == "moe":
                 self.moe = MoELayer(spec, prefix="moe_")
-            else:
+            elif self._ffn:
                 self.ffn = DenseFFN(spec, prefix="ffn_")
-            if self._sandwich:
-                self.norm1_post = nn.RMSNorm(spec.hidden, spec.eps,
-                                             prefix="norm1_post_")
-                self.norm2_post = nn.RMSNorm(spec.hidden, spec.eps,
-                                             prefix="norm2_post_")
+            if self._sandwich and self._mixer:
+                self.norm1_post = norm("norm1_post")
+            if self._sandwich and self._ffn:
+                self.norm2_post = norm("norm2_post")
 
     def hybrid_forward(self, F, x):
-        y = self.mixer(self.norm1(x))
-        x = x + (self.norm1_post(y) if self._sandwich else y)
-        y = getattr(self, self._ffn)(self.norm2(x))
-        return x + (self.norm2_post(y) if self._sandwich else y)
+        if self._mixer:
+            y = self.mixer(self.norm1(x))
+            x = x + (self.norm1_post(y) if self._sandwich else y)
+        if self._ffn:
+            y = getattr(self, self._ffn)(self.norm2(x))
+            x = x + (self.norm2_post(y) if self._sandwich else y)
+        return x
 
 
 class DecoderLM(HybridBlock):
@@ -540,14 +729,21 @@ class DecoderLM(HybridBlock):
 
     def __init__(self, vocab_size, spec, **kwargs):
         super().__init__(**kwargs)
-        bad = set(spec.pattern) - set(_MIXERS)
-        if bad or not spec.pattern:
-            raise MXNetError(f"layer pattern {spec.pattern!r}: each layer "
-                             f"is 'gqa', 'kda' or 'mla'")
-        ffn = spec.ffn_kinds()
-        if len(ffn) != len(spec.pattern) or set(ffn) - {"moe", "dense"}:
+        kinds = set(_MIXERS) | (set() if spec.paired else set(FFNS))
+        if set(spec.pattern) - kinds or not spec.pattern:
+            raise MXNetError(
+                f"layer pattern {spec.pattern!r}: each layer is one of "
+                f"{', '.join(repr(k) for k in sorted(kinds))}"
+                + (" (a pair's mixer; its feed-forward is `ffn`'s)"
+                   if spec.paired else " (one sub-layer a layer)"))
+        ffn = spec.ffn_kinds() if spec.paired else ()
+        if spec.paired and (len(ffn) != len(spec.pattern)
+                            or set(ffn) - set(FFNS)):
             raise MXNetError(f"ffn {spec.ffn!r}: 'moe' or 'dense' for each "
                              f"of the {len(spec.pattern)} layers, or ()")
+        if not spec.paired and spec.ffn:
+            raise MXNetError("ffn is a pair's feed-forward: a pattern of "
+                             "single sub-layers names them itself")
         self.spec = spec
         self.vocab_size = int(vocab_size)
         with self.name_scope():
@@ -555,7 +751,7 @@ class DecoderLM(HybridBlock):
                                       prefix="embed_")
             self.layers = nn.HybridSequential(prefix="layers_")
             with self.layers.name_scope():
-                for kind, f in zip(spec.pattern, ffn):
+                for kind, f in spec.sublayers():
                     self.layers.add(DecoderBlock(spec, kind, f))
             self.final_norm = nn.RMSNorm(spec.hidden, spec.eps,
                                          prefix="final_norm_")
@@ -571,13 +767,15 @@ def lm_weights(model):
     the serving executables (which take it as an ARGUMENT: at these sizes
     weights cannot be constants of a program)."""
     def layer(b):
-        out = {"norm1_gamma": b.norm1.gamma.data()._data,
-               "norm2_gamma": b.norm2.gamma.data()._data,
-               "mixer": b.mixer.weights(),
-               b._ffn: getattr(b, b._ffn).weights()}
-        if b._sandwich:
-            out["norm1_post_gamma"] = b.norm1_post.gamma.data()._data
-            out["norm2_post_gamma"] = b.norm2_post.gamma.data()._data
+        out = {}
+        for n, child in (("norm1", "mixer" if b._mixer else None),
+                         ("norm2", b._ffn)):
+            if child:
+                out[n + "_gamma"] = getattr(b, n).gamma.data()._data
+                out[child] = getattr(b, child).weights()
+                if b._sandwich:
+                    out[n + "_post_gamma"] = getattr(
+                        b, n + "_post").gamma.data()._data
         return out
 
     return {

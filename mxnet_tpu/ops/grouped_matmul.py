@@ -16,6 +16,8 @@ so nothing is ever dropped; tiles past the last used one are skipped.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -58,17 +60,20 @@ def layout(group, n_groups, tile):
     return dest, tile_group, (ends[-1] // tile).astype(jnp.int32), counts
 
 
-def _gmm_kernel(tg_ref, used_ref, x_ref, w_ref, o_ref):
+def _gmm_kernel(tg_ref, used_ref, x_ref, w_ref, o_ref, *, nt):
     @pl.when(pl.program_id(1) < used_ref[0])
     def _():
-        o_ref[...] = jnp.dot(x_ref[...], w_ref[0],
-                             preferred_element_type=jnp.float32
-                             ).astype(o_ref.dtype)
+        o_ref[...] = jax.lax.dot_general(
+            x_ref[...], w_ref[0], (((1,), (1 if nt else 0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def _block_n(k, n, itemsize):
     """Widest column block (a multiple of 128 that divides n) whose
-    double-buffered weight block stays near 8 MB of fast memory."""
+    double-buffered weight block stays near 8 MB of fast memory; an n
+    that is not whole 128-lane tiles admits only itself as a block."""
+    if n % 128:
+        return n
     best = 128
     for bn in range(128, n + 1, 128):
         if n % bn == 0 and 2 * k * bn * itemsize <= 8 * 2 ** 20:
@@ -76,9 +81,9 @@ def _block_n(k, n, itemsize):
     return best
 
 
-def _gmm_pallas(x, w, tile_group, tiles_used, tile):
+def _gmm_pallas(x, w, tile_group, tiles_used, tile, nt):
     m, k = x.shape
-    n = w.shape[2]
+    n = w.shape[1 if nt else 2]
     bn = _block_n(k, n, w.dtype.itemsize)
     tiles = m // tile
 
@@ -90,34 +95,48 @@ def _gmm_pallas(x, w, tile_group, tiles_used, tile):
         grid=(n // bn, tiles),
         in_specs=[
             pl.BlockSpec((tile, k), lambda j, i, tg, used: (at(i, used), 0)),
-            pl.BlockSpec((1, k, bn),
-                         lambda j, i, tg, used: (tg[at(i, used)], 0, j)),
+            pl.BlockSpec((1, bn, k) if nt else (1, k, bn),
+                         lambda j, i, tg, used: (
+                             (tg[at(i, used)], j, 0) if nt
+                             else (tg[at(i, used)], 0, j))),
         ],
         out_specs=pl.BlockSpec((tile, bn),
                                lambda j, i, tg, used: (at(i, used), j)),
     )
     return pl.pallas_call(
-        _gmm_kernel, grid_spec=grid_spec,
+        functools.partial(_gmm_kernel, nt=nt), grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=32 * 2 ** 20),
+            # a whole-width weight block, double-buffered, needs its room
+            vmem_limit_bytes=max(32 * 2 ** 20,
+                                 3 * k * bn * w.dtype.itemsize)),
         interpret=_pk._interpret(),
         name="mxtpu_gmm",
     )(tile_group, tiles_used.reshape(1), x, w)
 
 
-def grouped_matmul(x, w, tile_group, tiles_used, tile):
-    """x: (M, K) rows in `layout`'s order, M whole tiles; w: (G, K, N);
-    tile_group: (M // tile,) the group of each tile; tiles_used: () int32.
-    Returns (M, N) in x's dtype: rows of used tiles hold x @ w[group],
-    the others anything."""
+def _whole(v):
+    """A width the kernel takes: whole 128-lane tiles, or more than one of
+    them in whole 16-row sublane tiles, taken as ONE block (1856 = 14.5
+    x 128)."""
+    return v % 128 == 0 or (v > 128 and v % 16 == 0)
+
+
+def grouped_matmul(x, w, tile_group, tiles_used, tile, nt=False):
+    """x: (M, K) rows in `layout`'s order, M whole tiles; w: (G, K, N),
+    or with `nt` (G, N, K), each group's matrix (out, in) as a dense
+    layer keeps it (the shape to KEEP a bank in whose N is not whole
+    128-lane tiles: the device lays an array out row-major only where its
+    minor dimension is); tile_group: (M // tile,) the group of each tile;
+    tiles_used: () int32. Returns (M, N) in x's dtype: rows of used tiles
+    hold x @ w[group] (x @ w[group].T with `nt`), the others anything."""
     m, k = x.shape
-    n = w.shape[2]
-    if (_pk.on_tpu() or _pk._interpret()) and n % 128 == 0 and k % 128 == 0 \
-            and tile % 8 == 0:
-        return _gmm_pallas(x, w, tile_group, tiles_used, tile)
+    n = w.shape[1 if nt else 2]
+    if (_pk.on_tpu() or _pk._interpret()) and tile % 8 == 0 and _whole(k) \
+            and (n % 128 == 0 or nt and _whole(n)):
+        return _gmm_pallas(x, w, tile_group, tiles_used, tile, nt)
     xt = x.reshape(m // tile, tile, k)
-    out = jnp.einsum("tmk,tkn->tmn", xt, w[tile_group],
-                     preferred_element_type=jnp.float32)
+    out = jnp.einsum("tmk,tnk->tmn" if nt else "tmk,tkn->tmn", xt,
+                     w[tile_group], preferred_element_type=jnp.float32)
     return out.astype(x.dtype).reshape(m, n)

@@ -163,18 +163,20 @@ def kda_chunked(q, k, v, g, beta, state, chunk=32, length=None):
     return o, state
 
 
-def causal_conv(x, weight):
+def causal_conv(x, weight, bias=None):
     """Causal depthwise convolution over the last K positions. x: (T, C);
-    weight: (K, C), weight[K-1] multiplies the current position. Positions
-    before the sequence count as zero."""
+    weight: (K, C), weight[K-1] multiplies the current position; bias:
+    (C,) or None. Positions before the sequence count as zero."""
     kw = weight.shape[0]
     pad = jnp.concatenate([jnp.zeros((kw - 1, x.shape[1]), x.dtype), x])
-    return sum(pad[j:j + x.shape[0]] * weight[j] for j in range(kw))
+    out = sum(pad[j:j + x.shape[0]] * weight[j] for j in range(kw))
+    return out if bias is None else out + bias
 
 
-def causal_conv_step(tail, x, weight):
+def causal_conv_step(tail, x, weight, bias=None):
     """One position of `causal_conv` for a batch. tail: (S, K-1, C), the
     K-1 inputs before this one; x: (S, C). Returns (out (S, C), the next
     tail)."""
     window = jnp.concatenate([tail, x[:, None]], 1)      # (S, K, C)
-    return jnp.sum(window * weight, 1), window[:, 1:]
+    out = jnp.sum(window * weight, 1)
+    return (out if bias is None else out + bias), window[:, 1:]

@@ -3,7 +3,7 @@ ONE cached prefill executable and ONE cached decode executable behind the
 same `Server`, `Scheduler`, `PagePool` and `EngineLoop` that serve
 `TransformerNMT` through `serve.decode.DecodeRuntime`.
 
-Three kinds of device state live side by side, each only where the
+Four kinds of device state live side by side, each only where the
 model's pattern has its layers, all donated to both executables so every
 write is in place:
 
@@ -18,7 +18,13 @@ write is in place:
   * fixed per-slot arrays that no page table reaches, for the KDA layers:
     the recurrent state `(slots, H, dv, dk)` float32 (value-major, as the
     decode kernel walks it) and the short
-    convolution's last `K - 1` inputs `(slots, K - 1, 3 H dk)`.
+    convolution's last `K - 1` inputs `(slots, K - 1, 3 H dk)`;
+  * the same two for the Mamba-2 state-space ("mamba") layers, a second
+    recurrent form beside KDA's: the state `(slots, H, P, N)` float32 and
+    the convolution's last inputs `(slots, K - 1, H P + 2 G N)`.
+
+A layer is a (mixer, feed-forward) pair or, where the spec is not
+`paired`, one of the two: both programs walk `LMSpec.sublayers()`.
 
 A request's first argument is its prompt. Prefill runs the whole prompt
 but its last token in one dispatch (padded to the static prompt length):
@@ -59,6 +65,11 @@ from .kv_pages import NULL_PAGE
 __all__ = ["LMRuntime"]
 
 _CHUNK = 32        # positions a step of the chunked KDA scan
+_MIXERS = ("gqa", "kda", "mla", "mamba")
+# a recurrent mixer's per-slot state and convolution tails in `_state`,
+# and its sequence form
+_RECURRENT = {"kda": ("kda", "conv", lm.mx_kda_seq),
+              "mamba": ("ssm", "ssm_conv", lm.mx_mamba_seq)}
 
 
 class LMRuntime:
@@ -81,20 +92,29 @@ class LMRuntime:
         if self.max_src_len < 1:
             raise MXNetError("a decoder-only server needs max_prompt_len "
                              ">= 1: the prompt is the request")
-        # the static prefill length: whole KDA chunks and whole pages
-        step = math.lcm(_CHUNK, self.page_size)
-        self._plen = -(-self.max_src_len // step) * step
         # layers of each kind: each kind keeps its own device state
-        self._n = {k: spec.pattern.count(k) for k in ("gqa", "kda", "mla")}
-        # which layers have experts (a dense layer counts no dispatch)
-        self._is_moe = np.array([f == "moe" for f in spec.ffn_kinds()],
-                                np.int64)
+        self._n = {k: spec.pattern.count(k) for k in _MIXERS}
+        # the static prefill length: whole chunks of the recurrent layers'
+        # scans and whole pages
+        step = math.lcm(_CHUNK, self.page_size,
+                        *([spec.ssm_chunk] if self._n["mamba"] else []))
+        self._plen = -(-self.max_src_len // step) * step
+        subs = spec.sublayers()
+        # which layers have experts (another layer counts no dispatch)
+        self._is_moe = np.array([f == "moe" for _, f in subs], np.int64)
+        # prefill gives no logits: it stops after the last mixer, and
+        # whatever follows (the last pair's feed-forward, trailing layers
+        # of one feed-forward each) feeds nothing
+        self._last_mixer = max(i for i, (m, _) in enumerate(subs) if m)
+        self._in_prefill = self._is_moe * (np.arange(len(subs))
+                                           < self._last_mixer)
         self.page_reuse_refusal = (
-            "the model has recurrent (KDA) layers: a slot's state after a "
-            "prefix is one array that pages neither share nor rewind, so "
-            "prefix-cache adoption and rejected speculative drafts would "
-            "leave it wrong; both wait for state snapshots"
-            if self._n["kda"] else
+            "the model has recurrent (KDA or state-space) layers: a "
+            "slot's state after a prefix is one array a layer that pages "
+            "neither share nor rewind, so prefix-cache adoption and "
+            "rejected speculative drafts would leave it wrong; both wait "
+            "for state snapshots"
+            if self._n["kda"] or self._n["mamba"] else
             "the decoder-only runtime prefills a whole prompt in one "
             "dispatch and decodes one token a turn: it cannot start after "
             "adopted pages and has no widened verify executable yet")
@@ -124,9 +144,10 @@ class LMRuntime:
 
     # ---------------------------------------------------------- state
     def reset_pages(self):
-        """Zeroed device state of BOTH kinds (construction, and after a
+        """Zeroed device state of every kind (construction, and after a
         failed dispatch consumed the donated buffers)."""
         s, sp = self.slots, self.spec
+        ssm_conv = sp.ssm_dims()[1]
         pool = (self.num_pages, self.page_size, sp.kv_heads * sp.head_dim)
         latent = (self.num_pages, self.page_size,
                   pool_lanes(sp.kv_rank + sp.rope_dim))
@@ -141,6 +162,12 @@ class LMRuntime:
                     for _ in range(self._n["kda"])],
             "conv": [jnp.zeros((s, sp.conv_kernel - 1, c), self._dtype)
                      for _ in range(self._n["kda"])],
+            "ssm": [jnp.zeros((s, sp.ssm_heads, sp.ssm_head_dim,
+                               sp.ssm_state), jnp.float32)
+                    for _ in range(self._n["mamba"])],
+            "ssm_conv": [jnp.zeros((s, sp.conv_kernel - 1, ssm_conv),
+                                   self._dtype)
+                         for _ in range(self._n["mamba"])],
         }
         # always-on counters of the expert layers, by layer, kept on the
         # host so that a reader never touches a donated buffer: a decode
@@ -155,8 +182,8 @@ class LMRuntime:
         # prompt rows, k) and the last decode turn (layers, slots, k):
         # device arrays that no turn fetches, for whoever audits the
         # routing against a reference (rows past a prompt's end and empty
-        # slots hold ids that nothing used; -1 in prefill's last layer,
-        # whose experts it does not run)
+        # slots hold ids that nothing used; -1 where a layer has no
+        # experts or prefill does not run them)
         self.routing = {"prefill": None, "decode": None}
         _obs_registry().gauge("serve_slot_state_bytes").set(
             self.slot_state_bytes())
@@ -164,10 +191,11 @@ class LMRuntime:
             self.latent_cache_bytes())
 
     def slot_state_bytes(self):
-        """Device bytes of the per-slot arrays (recurrent state and
-        convolution tails) that the page pool does not count."""
+        """Device bytes of the per-slot arrays (both recurrent forms'
+        state and convolution tails) that the page pool does not count."""
         return sum(a.size * a.dtype.itemsize
-                   for a in self._state["kda"] + self._state["conv"])
+                   for k in ("kda", "conv", "ssm", "ssm_conv")
+                   for a in self._state[k])
 
     def latent_cache_bytes(self):
         """Device bytes of the latent pools as they are kept (the rows'
@@ -191,22 +219,28 @@ class LMRuntime:
         (layers,): how many (dispatch, held expert) pairs had a row at
         all, so how many experts' weights the dispatches had to read;
         `dispatches` (layers,): the decode turns and prefills that ran
-        the layer's experts. A prefill runs every layer's but the last's,
-        whose output would feed nothing (prefill gives no logits), and is
-        counted at the decode turn that follows it."""
+        the layer's experts. A prefill runs the experts of the layers
+        before the last mixer only: what follows it would feed nothing
+        (prefill gives no logits); it is counted at the decode turn that
+        follows it."""
         return {k: v.copy() for k, v in self._moe.items()}
 
     def _count(self, counts, prefill=False):
         self._moe["rows"] += counts
         self._moe["touched"] += (counts > 0).sum(1)
-        self._moe["dispatches"] += self._is_moe
-        if prefill:
-            self._moe["dispatches"][-1] -= self._is_moe[-1]
+        self._moe["dispatches"] += self._in_prefill if prefill \
+            else self._is_moe
 
     @property
     def kda_state(self):
         """The recurrent state arrays, one a KDA layer (read-only use)."""
         return list(self._state["kda"])
+
+    @property
+    def ssm_state(self):
+        """The state-space layers' state arrays, one a "mamba" layer
+        (read-only use)."""
+        return list(self._state["ssm"])
 
     @property
     def latent_pages(self):
@@ -216,8 +250,9 @@ class LMRuntime:
     @property
     def conv_tails(self):
         """The convolution's last K - 1 inputs of every slot, one array a
-        KDA layer (read-only use)."""
-        return list(self._state["conv"])
+        recurrent layer: the KDA layers', then the state-space layers'
+        (read-only use)."""
+        return self._state["conv"] + self._state["ssm_conv"]
 
     def remap_pages(self, mapping):
         if not mapping:
@@ -234,11 +269,13 @@ class LMRuntime:
 
     # ------------------------------------------------------- programs
     def _layers(self, weights):
-        """(kind, layer weights, index among the layers of its kind)."""
+        """(mixer's kind or None, layer weights, index among the layers
+        of that kind)."""
         seen = dict.fromkeys(self._n, 0)
-        for kind, L in zip(self.spec.pattern, weights["layers"]):
-            yield kind, L, seen[kind]
-            seen[kind] += 1
+        for (kind, _), L in zip(self.spec.sublayers(), weights["layers"]):
+            yield kind, L, seen.get(kind)
+            if kind:
+                seen[kind] += 1
 
     def _join(self, x, y, L, which):
         """The residual after a sub-layer: its output normed first where
@@ -247,14 +284,24 @@ class LMRuntime:
             y = rms_norm(y, L[which + "_post_gamma"], self.spec.eps)
         return x + y
 
-    def _ffn(self, L, h, valid):
-        """A layer's feed-forward on normed rows h: (y, rows each held
-        expert took, expert ids chosen); a dense layer has neither."""
+    def _no_experts(self, rows):
+        """What a layer without experts counts: no rows, ids of -1."""
+        return (jnp.zeros((self.spec.held_n,), jnp.int32),
+                jnp.full((rows, self.spec.top_k), -1, jnp.int32))
+
+    def _ffn(self, x, L, valid):
+        """The residual after a layer's feed-forward, where it has one:
+        (x, rows each held expert took, expert ids chosen); only an
+        expert layer has the last two."""
+        if "moe" not in L and "ffn" not in L:
+            return (x, *self._no_experts(x.shape[0]))
+        h = rms_norm(x, L["norm2_gamma"], self.spec.eps)
         if "moe" in L:
-            return lm.mx_moe(L["moe"], h, valid, spec=self.spec)
-        return (lm.mx_ffn(L["ffn"], h),
-                jnp.zeros((self.spec.held_n,), jnp.int32),
-                jnp.full((h.shape[0], self.spec.top_k), -1, jnp.int32))
+            y, n, idx = lm.mx_moe(L["moe"], h, valid, spec=self.spec)
+        else:
+            y = lm.mx_ffn(L["ffn"], h)
+            n, idx = self._no_experts(x.shape[0])
+        return self._join(x, y, L, "norm2"), n, idx
 
     def _decode_program(self, state, weights, page_tables, lens, tok,
                         active):
@@ -267,7 +314,8 @@ class LMRuntime:
         off = lens % psize
         counts, chose = [], []
         for kind, L, j in self._layers(weights):
-            h = rms_norm(x, L["norm1_gamma"], spec.eps)
+            if kind:
+                h = rms_norm(x, L["norm1_gamma"], spec.eps)
             if kind == "gqa":
                 y, state["k"][j], state["v"][j] = lm.mx_gqa(
                     L["mixer"], h, state["k"][j], state["v"][j],
@@ -276,16 +324,19 @@ class LMRuntime:
                 y, state["lat"][j] = lm.mx_mla(
                     L["mixer"], h, state["lat"][j], page_tables, lens,
                     page, off, spec=spec)
-            else:
+            elif kind == "kda":
                 y, state["kda"][j], state["conv"][j] = lm.mx_kda(
                     L["mixer"], h, state["kda"][j], state["conv"][j],
                     spec=spec)
-            x = self._join(x, y, L, "norm1")
-            y, n, idx = self._ffn(
-                L, rms_norm(x, L["norm2_gamma"], spec.eps), valid)
+            elif kind == "mamba":
+                y, state["ssm"][j], state["ssm_conv"][j] = lm.mx_mamba(
+                    L["mixer"], h, state["ssm"][j], state["ssm_conv"][j],
+                    spec=spec)
+            if kind:
+                x = self._join(x, y, L, "norm1")
+            x, n, idx = self._ffn(x, L, valid)
             counts.append(n)
             chose.append(idx)
-            x = self._join(x, y, L, "norm2")
         x = rms_norm(x, weights["final_norm_gamma"], spec.eps)
         logits = jax.lax.dot_general(
             x, weights["head"], (((1,), (1,)), ((), ())),
@@ -309,8 +360,9 @@ class LMRuntime:
         tail_at = n - (spec.conv_kernel - 1) + jnp.arange(
             spec.conv_kernel - 1)
         counts, chose = [], []
-        for kind, L, j in self._layers(weights):
-            h = rms_norm(x, L["norm1_gamma"], spec.eps)
+        for i, (kind, L, j) in enumerate(self._layers(weights)):
+            if kind:
+                h = rms_norm(x, L["norm1_gamma"], spec.eps)
             if kind == "gqa":
                 y, k, v = lm.mx_gqa_seq(L["mixer"], h, spec=spec)
                 for name, a in (("k", k), ("v", v)):
@@ -323,27 +375,28 @@ class LMRuntime:
                                       (0, lat.shape[-1] - rows.shape[-1])))
                 state["lat"][j] = lat.at[pages].set(
                     rows.reshape(n_pg, psize, -1))
-            else:
-                y, s_end, pre = lm.mx_kda_seq(L["mixer"], h, valid,
-                                              spec=spec)
-                state["kda"][j] = state["kda"][j].at[slot].set(
-                    s_end.swapaxes(-1, -2))
+            elif kind in _RECURRENT:
+                which, conv, sequence = _RECURRENT[kind]
+                y, s_end, pre = sequence(L["mixer"], h, valid, spec=spec)
+                if kind == "kda":       # a slot's state is value-major
+                    s_end = s_end.swapaxes(-1, -2)
+                state[which][j] = state[which][j].at[slot].set(s_end)
                 tail = jnp.where((tail_at >= 0)[:, None],
                                  pre[jnp.maximum(tail_at, 0)], 0)
-                state["conv"][j] = state["conv"][j].at[slot].set(tail)
-            if len(counts) == len(spec.pattern) - 1:
-                # the last layer's experts would feed nothing: prefill
+                state[conv][j] = state[conv][j].at[slot].set(tail)
+            if i == self._last_mixer:
+                # what follows the last mixer would feed nothing: prefill
                 # gives no logits, the next position needs only the state
-                counts.append(jnp.zeros((spec.held_n,), jnp.int32))
-                chose.append(jnp.full((self._plen, spec.top_k), -1,
-                                      jnp.int32))
+                for _ in range(i, len(spec.pattern)):
+                    n, idx = self._no_experts(self._plen)
+                    counts.append(n)
+                    chose.append(idx)
                 break
-            x = self._join(x, y, L, "norm1")
-            y, c, idx = self._ffn(
-                L, rms_norm(x, L["norm2_gamma"], spec.eps), valid)
+            if kind:
+                x = self._join(x, y, L, "norm1")
+            x, c, idx = self._ffn(x, L, valid)
             counts.append(c)
             chose.append(idx)
-            x = self._join(x, y, L, "norm2")
         return state, jnp.stack(counts), jnp.stack(chose)
 
     # ---------------------------------------------------------- calls

@@ -399,3 +399,37 @@ def test_latent_paged_attention_compiles(chip_compile):
     import re
     assert not re.search(r"= bf16\[2049,16,\d+[^=]* (copy|transpose|"
                          r"reshape|fusion)\(", text)
+
+
+# the state-space server's shapes (benchmarks/configs/
+# nemotron3_nano_ep2.json): a Mamba-2 state of 64 heads x 64 x 128 float32
+# a slot, 8 groups of B and C; 64 ungated experts of 1856 (14.5 lane
+# tiles) under hidden 2688, the first matrix kept (experts, width, d)
+def test_ssd_step_compiles(chip_compile):
+    from mxnet_tpu.ops import ssd
+    text = chip_compile(
+        lambda *a: ssd.ssd_step_slots(*a)[1], ((16, 64, 64), F32),
+        ((16, 64), F32), ((64,), F32), ((16, 8, 128), F32),
+        ((16, 8, 128), F32), ((16, 64, 64, 128), F32))
+    assert kernel_calls(text, ("mxtpu_ssd_step",)) == {"mxtpu_ssd_step": 1}
+
+
+def test_grouped_matmul_compiles_at_a_width_of_half_lane_tiles(chip_compile):
+    """Up (`nt`: the bank (experts, 1856, 2688), its minor dimension whole
+    lane tiles, so the device keeps it row-major and the kernel reads it
+    where it lies) then relu^2 then down: two kernels, no copy of a bank."""
+    import re
+    from mxnet_tpu.models.decoder_lm import relu2
+    from mxnet_tpu.ops import grouped_matmul as gmm
+    tile, rows = 32, gmm.rows_capacity(256 * 6, 64, 32)
+
+    def fn(x, up, down, tile_group, used):
+        u = gmm.grouped_matmul(x, up, tile_group, used, tile, nt=True)
+        return gmm.grouped_matmul(relu2(u), down, tile_group, used, tile)
+
+    text = chip_compile(fn, ((rows, 2688), BF16), ((64, 1856, 2688), BF16),
+                        ((64, 1856, 2688), BF16), ((rows // tile,), I32),
+                        ((), I32))
+    assert kernel_calls(text, ("mxtpu_gmm",)) == {"mxtpu_gmm": 2}
+    assert not re.search(r"= bf16\[64,\d+,\d+[^=]* (copy|transpose|fusion)\(",
+                         text)
