@@ -311,9 +311,15 @@ class DecodeRuntime:
 
     def _decode_program(self, pools, inputs):
         """One token a slot. inputs: (page_tables, lens, tok, active,
-        mem_k, mem_v, mem_vl); outputs: (next_tok, logits)."""
+        prev_tok, mem_k, mem_v, mem_vl); outputs: (next_tok, logits). A
+        slot's input token is the host's `tok` where `active` is 1 and
+        the previous step's `next_tok` where it is 2: `prev_tok` is that
+        output, still on the device and NOT donated (its own step's
+        reader fetches it after this dispatch)."""
         self.decode_traces += 1
-        page_tables, lens, tok, active, mem_k, mem_v, mem_vl = inputs
+        (page_tables, lens, tok, active, prev_tok, mem_k, mem_v,
+         mem_vl) = inputs
+        tok = jnp.where(active == 2, prev_tok, tok)
         w, h, psize = self._w, self._h, self.page_size
         pools = [None if a is None else list(a) for a in pools]
         s_n = tok.shape[0]
@@ -483,22 +489,33 @@ class DecodeRuntime:
                     f"buffers: {type(e).__name__}: {e}") from e
             raise
 
-    def decode(self, page_tables, lens, tok, active):
-        """One decode step for every slot (ONE dispatch): writes each
-        active slot's K/V into its current page in place, runs the shared
-        ragged-paged-attention launch, returns (next_tok (S,) host int32,
-        logits (S, V) device array)."""
+    def decode_launch(self, page_tables, lens, tok, active):
+        """Dispatch one decode step for every slot (ONE dispatch) and
+        return its `read`: the call that waits for the step and gives
+        what `decode` returns. Each active slot's K/V goes into its
+        current page in place, then the shared ragged-paged-attention
+        launch runs. `active[s]` is 0 (empty), 1 (input token `tok[s]`)
+        or 2 (the token the PREVIOUS launch chose for the slot, which
+        never left the device): a scheduler that launches a step before
+        it has read the one before feeds the tokens back that way."""
         profiler.record_dispatch("serve_decode")
         inputs = (jnp.asarray(page_tables, jnp.int32),
                   jnp.asarray(lens, jnp.int32), jnp.asarray(tok, jnp.int32),
-                  jnp.asarray(active, jnp.int32),
+                  jnp.asarray(active, jnp.int32), self._last_tok,
                   self.mem_k, self.mem_v, self.mem_vl)
-        return self._turn(self._decode_fn, inputs)
+        next_tok, logits = self._run(self._decode_fn, inputs)
+        self._last_tok = next_tok
+        return lambda: (np.asarray(next_tok), logits)
 
-    def _turn(self, fn, inputs):
+    def decode(self, page_tables, lens, tok, active):
+        """One decode step for every slot, launched and read: returns
+        (next_tok (S,) host int32, logits (S, V) device array)."""
+        return self.decode_launch(page_tables, lens, tok, active)()
+
+    def _run(self, fn, inputs):
         (self.k_pages, self.v_pages, self.k_scales,
-         self.v_scales), (next_tok, logits) = fn(self._pools(), inputs)
-        return np.asarray(next_tok), logits
+         self.v_scales), outputs = fn(self._pools(), inputs)
+        return outputs
 
     def decode_multi(self, page_tables, lens, toks, qlens, active):
         """One WIDENED decode turn for every slot (still ONE dispatch):
@@ -518,7 +535,8 @@ class DecodeRuntime:
                   jnp.asarray(qlens, jnp.int32),
                   jnp.asarray(active, jnp.int32),
                   self.mem_k, self.mem_v, self.mem_vl)
-        return self._turn(self._verify_fn, inputs)
+        next_tok, logits = self._run(self._verify_fn, inputs)
+        return np.asarray(next_tok), logits
 
     def remap_pages(self, mapping):
         """Apply a `PagePool.defrag()` renumbering to the device pools
@@ -547,6 +565,8 @@ class DecodeRuntime:
         self.k_pages, self.v_pages = (per_layer(pool, dtype)
                                       for _ in range(2))
         self.k_scales = self.v_scales = None
+        # the last decode step's tokens, the next one's `prev_tok`
+        self._last_tok = jnp.zeros((self.slots,), jnp.int32)
         if self.kv_quant:
             self.k_scales, self.v_scales = (
                 per_layer(pool[:2], jnp.float32) for _ in range(2))

@@ -178,6 +178,8 @@ class LMRuntime:
                      "touched": np.zeros((layers,), np.int64),
                      "dispatches": np.zeros((layers,), np.int64)}
         self._pending = []
+        # the last decode step's tokens, the next one's `prev_tok`
+        self._last_tok = jnp.zeros((s,), jnp.int32)
         # the expert ids every row chose in the last prefill (layers,
         # prompt rows, k) and the last decode turn (layers, slots, k):
         # device arrays that no turn fetches, for whoever audits the
@@ -304,10 +306,14 @@ class LMRuntime:
         return self._join(x, y, L, "norm2"), n, idx
 
     def _decode_program(self, state, weights, page_tables, lens, tok,
-                        active):
+                        active, prev_tok):
+        """`active`, `prev_tok`: as `DecodeRuntime._decode_program`'s (a
+        slot's input token is `tok` at 1, the previous step's choice,
+        which stayed on the device and is not donated, at 2)."""
         self.decode_traces += 1
         spec, psize = self.spec, self.page_size
         valid = active > 0
+        tok = jnp.where(active == 2, prev_tok, tok)
         x = weights["embed"][tok]                            # (S, d)
         page = page_tables[jnp.arange(tok.shape[0]), lens // psize]
         page = jnp.where(valid, page, NULL_PAGE)
@@ -456,19 +462,32 @@ class LMRuntime:
         for entry in entries:
             yield 1, _raised(self.prefill, *entry)
 
-    def decode(self, page_tables, lens, tok, active):
-        """One decode step for every slot (ONE dispatch). Returns
-        (next_tok (S,) host int32, logits (S, V) float32 device array)."""
+    def decode_launch(self, page_tables, lens, tok, active):
+        """Dispatch one decode step for every slot (ONE dispatch) and
+        return its `read`, as `DecodeRuntime.decode_launch` does
+        (`active` 0 / 1 / 2 the same). The read also brings the step's
+        expert counts, and those of the prefills dispatched before it,
+        into `moe_counters()`: read every launch, once, in order."""
         profiler.record_dispatch("serve_decode")
         (self._state, next_tok, logits, counts,
          self.routing["decode"]) = self._decode_fn(
             self._state, self._w, jnp.asarray(page_tables, jnp.int32),
             jnp.asarray(lens, jnp.int32), jnp.asarray(tok, jnp.int32),
-            jnp.asarray(active, jnp.int32))
-        next_tok, counts, pending = jax.device_get(
-            (next_tok, counts, self._pending))
-        self._pending = []
-        for c in pending:
-            self._count(c, prefill=True)
-        self._count(counts)
-        return next_tok, logits
+            jnp.asarray(active, jnp.int32), self._last_tok)
+        self._last_tok = next_tok
+        pending, self._pending = self._pending, []
+
+        def read():
+            host_tok, step, prefills = jax.device_get(
+                (next_tok, counts, pending))
+            for c in prefills:
+                self._count(c, prefill=True)
+            self._count(step)
+            return host_tok, logits
+
+        return read
+
+    def decode(self, page_tables, lens, tok, active):
+        """One decode step for every slot, launched and read. Returns
+        (next_tok (S,) host int32, logits (S, V) float32 device array)."""
+        return self.decode_launch(page_tables, lens, tok, active)()

@@ -16,6 +16,28 @@ Every `step()` is one turn of the serving crank:
      NEXT step can admit into the freed capacity. No drain barriers:
      short requests never wait for long ones.
 
+ONE TURN IN FLIGHT (ISSUE 35). While requests still wait for a SLOT after
+a turn's admission (and the turn is one token wide), the crank looks ahead
+by one turn: it plans and dispatches turn n+1 from what it knows without
+turn n's tokens — each slot of turn n stands one position further, a slot
+that reaches `max_new_tokens` in turn n is left out, and a slot at the
+generation frontier takes its input token from turn n's output where it
+lies on the device — and only THEN reads turn n and commits it. Commit,
+eviction, the next call's deadline sweep, admission (its prefill
+dispatches queue behind n+1) and planning all run while the device works.
+With the queue empty an arrival would join turn n+2 where it joins n+1
+today, so the turn in flight is read first and the serial order above
+returns (`serve_lookahead_drains{why=queue_empty}`); a widened turn
+drafts from committed tokens and never leaves one in flight. Everything
+rare reads and commits the turn in flight before it acts (a dry pool's
+preemption, `defrag`, `shutdown`, a fault). A request that ended on
+`eos_id` in turn n has run one wasted position in n+1: commit drops a row
+whose slot no longer holds its request. Page safety: a turn dispatched
+ahead writes only rows of pages its slots own at dispatch, and the device
+runs dispatches in order, so a page freed at commit n and granted again is
+written by its new owner AFTER the stale row (a slot's recurrent state
+likewise: the new owner's prefill overwrites it, behind the stale step).
+
 Backpressure: the admission queue is bounded (`max_queue`); a submit into
 a full queue raises `ServeOverloaded` (counted) instead of buffering
 unboundedly. A request that cannot get its next page mid-decode is
@@ -48,7 +70,9 @@ retried from scratch (bounded by `max_retries`) or failed cleanly;
 either way `kv_pages_in_use` returns to baseline (the chaos test
 asserts this). An error raised by the decode executable itself
 additionally resets the page pools AND clears the prefix cache (their
-contents are no longer trustworthy after a partial in-place step). A
+contents are no longer trustworthy after a partial in-place step); with a
+turn in flight it surfaces at that turn's read and takes the turn
+dispatched after it along. A
 `serve.prefix` or `serve.speculate` fault merely DEGRADES — cache
 lookup/insert skipped, turn runs unspeculated — with bitwise-identical
 request output.
@@ -247,6 +271,20 @@ class StepResult:
         return bool(self.admitted or self.decoded)
 
 
+class _Turn:
+    """A dispatched decode turn: `rows` {slot: request} as it ran them,
+    their `plans` (`Scheduler._plan_turn`), `read` (the call that waits
+    for the turn and gives its (slots, width) host tokens), and when it
+    began."""
+    __slots__ = ("rows", "plans", "read", "t0")
+
+    def __init__(self, rows, plans, read, t0):
+        self.rows = rows
+        self.plans = plans
+        self.read = read
+        self.t0 = t0
+
+
 class Scheduler:
     def __init__(self, runtime, pool, bos_id=2, eos_id=3, max_queue=64,
                  max_retries=1, max_preemptions=8, prefix_cache=True,
@@ -329,8 +367,19 @@ class Scheduler:
         self._m_prefix_degraded = reg.counter("serve_prefix_degraded")
         self._m_quant_degraded = reg.counter("serve_quant_degraded")
         self._m_warm_pref = reg.counter("serve_prefix_admit_preferred")
+        # the lookahead (ISSUE 35): turns dispatched before the previous
+        # turn's read, and why a turn in flight was read before the next
+        # dispatch (queue_empty, pool_dry, defrag, shutdown, error)
+        self._m_ahead = reg.counter("serve_lookahead_turns")
+        self._m_drains = {
+            why: reg.counter("serve_lookahead_drains", why=why)
+            for why in ("queue_empty", "pool_dry", "defrag", "shutdown",
+                        "error")}
+        # the turn dispatched and not yet read, if any (a `_Turn`)
+        self._inflight = None
         # per-instance tallies (registry counters are process-global)
-        self.decode_turns = 0
+        self.decode_turns = 0       # committed turns
+        self.lookahead_turns = 0
         self.spec_drafted = 0
         self.spec_accepted = 0
 
@@ -421,8 +470,8 @@ class Scheduler:
 
     def pending_work(self):
         with self._lock:
-            return bool(self._queue) or any(
-                r is not None for r in self._slots)
+            return bool(self._queue) or self._inflight is not None \
+                or any(r is not None for r in self._slots)
 
     def active_count(self):
         return sum(1 for r in self._slots if r is not None)
@@ -452,46 +501,93 @@ class Scheduler:
         self._expire_deadlines()
         with _tracer.span("serve.admit", cat="serve"):
             res.admitted = self._admit(res)
+        # look ahead only while requests still wait for a SLOT: a new
+        # arrival then loses nothing by a turn in flight. With the queue
+        # empty it would join turn n+2 where it joins n+1, and a widened
+        # turn drafts from committed tokens: both keep the serial order
+        ahead = self.width == 1 and bool(self._queue)
+        if self._inflight is not None and not ahead:
+            self._drain("queue_empty", res)
+            return res
         active = [(s, r) for s, r in enumerate(self._slots)
                   if r is not None]
-        if not active:
+        if not active and self._inflight is None:
             self._m_active.set(0)
             return res
         t0 = time.perf_counter()
+        turn = None
         try:
             if _finj.ENABLED:
                 _finj.check("serve.decode",
                             context=f"{len(active)} active")
             with _tracer.span("serve.plan", cat="serve"):
                 plans = self._plan_turn(active, res)
-            active = [(s, r) for s, r in enumerate(self._slots)
-                      if r is not None]
-            if not active:
-                return res
-            next_tok = self._decode(active, plans)
+            if plans is not None:
+                rows = {s: r for s, r in active if s in plans}
+                if not rows and self._inflight is None:
+                    return res
+                turn, next_tok = self._decode(rows, plans, t0, ahead)
+                res.decoded = len(rows)
         except _finj.FaultInjected as e:
-            self._fail_inflight(active, res, e, reset_pages=False)
+            self._fail_inflight(res, e, reset_pages=False)
             return res
         except Exception as e:  # executable error: pages untrustworthy
-            self._fail_inflight(active, res, e, reset_pages=True)
+            self._fail_inflight(res, e, reset_pages=True)
             return res
-        self._m_step.observe(time.perf_counter() - t0)
-        res.decoded = len(active)
-        self.decode_turns += 1
-        with _tracer.span("serve.commit", cat="serve"):
-            self._commit(active, plans, next_tok, res)
+        if plans is None:
+            # the pool is dry under a turn in flight: commit it (what it
+            # completes frees pages) and let the next call preempt from
+            # committed state if it still must
+            self._drain("pool_dry", res)
+        elif turn is not None:
+            self._finish_turn(turn, next_tok, res)
         return res
 
-    def _commit(self, active, plans, next_tok, res):
+    def _finish_turn(self, turn, next_tok, res):
+        """A turn's tokens are on the host: count it and commit it."""
+        self._m_step.observe(time.perf_counter() - turn.t0)
+        res.decoded = res.decoded or len(turn.rows)
+        self.decode_turns += 1
+        with _tracer.span("serve.commit", cat="serve"):
+            self._commit(turn.rows, turn.plans, next_tok, res)
+
+    def _drain(self, why, res):
+        """Read and commit the turn in flight, if there is one: what
+        everything that needs the committed state does first (the serial
+        order's return, a dry pool, `defrag`, `shutdown`, a fault). A
+        read that fails takes the in-flight requests with it, as a decode
+        error does."""
+        turn, self._inflight = self._inflight, None
+        if turn is None:
+            return
+        self._m_drains[why].inc()
+        try:
+            # the read alone: a decode_step without a dispatch, which
+            # carries no `cached_tokens` for a reader to count
+            with _tracer.span("serve.decode_step", cat="serve",
+                              args={"active": 0}):
+                next_tok = turn.read()
+        except Exception as e:
+            self._fail_inflight(res, e, reset_pages=True)
+            return
+        self._finish_turn(turn, next_tok, res)
+
+    def _commit(self, rows, plans, next_tok, res):
         """The turn's host tail: commit each slot's accepted tokens, emit
         them, offer finished prompt pages to the prefix cache, evict the
         requests that are done."""
         now = time.perf_counter()
-        for s, r in active:
-            window, f = plans[s]
+        for s, r in rows.items():
+            if self._slots[s] is not r:
+                # the request left its slot while the turn was in flight
+                # (it ended on `eos_id` a turn earlier, or its deadline
+                # passed): the row is nobody's. A request that is
+                # REQUEUED never has a turn in flight (`_drain` first),
+                # so the same request here is the same attempt
+                continue
+            L, window, f, _ = plans[s]
             q = len(window)
             g = next_tok[s]                    # (width,) host int32
-            L = int(self._lens[s])
             commits = []
             accepted = 0
             if L + f == len(r.known):
@@ -550,6 +646,7 @@ class Scheduler:
             return self._defrag_locked()
 
     def _defrag_locked(self):
+        self._drain("defrag", StepResult())
         mapping = self._pool.defrag()
         if not mapping:
             return 0
@@ -574,6 +671,7 @@ class Scheduler:
             self._shutdown_locked(reason)
 
     def _shutdown_locked(self, reason):
+        self._drain("shutdown", StepResult())
         with self._lock:
             queued = list(self._queue)
             self._queue.clear()
@@ -674,8 +772,6 @@ class Scheduler:
                 # zeroed buffers) — restart those requests from scratch;
                 # re-admission re-prefills each slot
                 self._fail_inflight(
-                    [(s2, r2) for s2, r2 in enumerate(self._slots)
-                     if r2 is not None],
                     res if res is not None else StepResult(), err,
                     reset_pages=False)
         if admitted:
@@ -841,11 +937,21 @@ class Scheduler:
         FORCED tokens first (known-but-uncached prompt / committed
         tokens), then up to `spec_k` n-gram drafts once the window
         reaches the generation frontier — and allocate the pages those
-        positions need. A slot whose current page is full when the pool
+        positions need: {slot: (position, window, forced tokens in it,
+        source)}. A slot whose current page is full when the pool
         is dry is preempted (pages freed, requeued) exactly like the
         1-wide path; a slot that can only fit part of its window just
         runs a shorter window (ragged qlens are free — same executable,
-        same dispatch)."""
+        same dispatch).
+
+        Under a turn in flight (n; this plans n+1) a slot of turn n
+        stands one position further than the host has committed; one that
+        reaches `max_new_tokens` in turn n is left out; one at the
+        generation frontier has nothing known to feed, and takes turn n's
+        choice where it lies on the device (source 2, the runtime's
+        `active` code; 1 is the host's token). If the pool runs dry the
+        plan is dropped (None): preemption is for committed state."""
+        prev = self._inflight
         psize = self._rt.page_size
         budget = self._rt.max_pages_per_slot * psize
         width = self.width
@@ -861,9 +967,16 @@ class Scheduler:
         plans = {}
         for s, r in active:
             L = int(self._lens[s])
+            if prev is not None and prev.rows.get(s) is r:
+                if L + 1 == len(r.known) \
+                        and len(r.tokens) + 1 >= r.max_new_tokens:
+                    continue            # turn n is its last, by length
+                L += 1
             window = list(r.known[L:L + width])
-            f = len(window)
-            if draft_ok and f < width:
+            f, source = len(window), 1
+            if not window:
+                window, f, source = [0], 1, 2
+            elif draft_ok and f < width:
                 window.extend(propose_ngram(r.known, width - f,
                                             self.spec_ngram))
             del window[budget - L:]     # never write past the page budget
@@ -872,6 +985,8 @@ class Scheduler:
                 try:
                     page = self._alloc_pages(1)[0]
                 except PageAllocError:
+                    if prev is not None:
+                        return None
                     del window[r._n_table * psize - L:]
                     break
                 r._pages.append(page)
@@ -883,34 +998,60 @@ class Scheduler:
                               preempted=True)
                 res.preempted += 1
                 continue
-            plans[s] = (window, min(f, len(window)))
+            plans[s] = (L, window, min(f, len(window)), source)
         return plans
 
-    def _decode(self, active, plans):
+    def _decode(self, rows, plans, t0, ahead):
+        """Dispatch the planned turn and read the turn that is due: the
+        one in flight if there is one (this turn then takes its place),
+        else this one, unless it is to stay in flight (`ahead`). Returns
+        (the turn read or None, its tokens)."""
         np = self._np
         width = self.width
         mask = np.zeros((self._rt.slots,), np.int32)
         toks = np.zeros((self._rt.slots, width), np.int32)
         qlens = np.ones((self._rt.slots,), np.int32)
-        for s, r in active:
-            window, _f = plans[s]
-            mask[s] = 1
+        lens = np.zeros((self._rt.slots,), np.int32)
+        for s, (L, window, _f, source) in plans.items():
+            mask[s] = source
             toks[s, :len(window)] = window
             qlens[s] = len(window)
+            lens[s] = L
+        # the scheduler's own table changes under a turn in flight (a
+        # commit, an admission), and a host array may go to the device
+        # after the dispatch returns, or stay the device's own on a CPU
+        tables = self._page_tables.copy()
 
         def launch():
-            if width == 1:
-                out, _ = self._rt.decode(self._page_tables, self._lens,
-                                         toks[:, 0], mask)
-                return out.reshape(-1, 1)
-            out, _ = self._rt.decode_multi(self._page_tables, self._lens,
-                                           toks, qlens, mask)
-            return out
+            prev, cur = self._inflight, None
+            if rows:
+                if width == 1:
+                    read = self._rt.decode_launch(tables, lens, toks[:, 0],
+                                                  mask)
+                    cur = _Turn(rows, plans,
+                                lambda: read()[0].reshape(-1, 1), t0)
+                else:
+                    out, _ = self._rt.decode_multi(tables, lens, toks,
+                                                   qlens, mask)
+                    cur = _Turn(rows, plans, lambda: out, t0)
+                if prev is not None:
+                    self._m_ahead.inc()
+                    self.lookahead_turns += 1
+            due = prev if prev is not None else (None if ahead else cur)
+            # a read that raises leaves NO turn in flight: the error
+            # takes the turn dispatched after it along
+            self._inflight = None
+            next_tok = None if due is None else due.read()
+            self._inflight = None if cur is due else cur
+            return due, next_tok
 
         if _tracer.ACTIVE:
-            with _tracer.span("serve.decode_step", cat="serve",
-                              args={"active": len(active),
-                                    "cached_tokens": int(self._lens.sum())}):
+            # `cached_tokens` marks a span that dispatched: its readers
+            # reckon a decode step's bytes from it
+            args = {"active": len(rows)}
+            if rows:
+                args["cached_tokens"] = int(lens.sum())
+            with _tracer.span("serve.decode_step", cat="serve", args=args):
                 return launch()
         return launch()
 
@@ -1042,13 +1183,17 @@ class Scheduler:
             self._m_queue.set(len(self._queue))
         return True
 
-    def _fail_inflight(self, active, res, exc, reset_pages):
+    def _fail_inflight(self, res, exc, reset_pages):
         """A decode-time fault killed the whole in-flight batch: every
         active request retries from scratch or fails cleanly; page
-        accounting returns to baseline either way."""
+        accounting returns to baseline either way. A turn in flight that
+        can still be read is committed first: what it completed stays
+        completed."""
+        self._drain("error", res)
         self._m_retries.inc()
-        for s, r in active:
-            if self._requeue(s, r, f"decode fault: {exc!r}"):
+        for s, r in enumerate(self._slots):
+            if r is not None and self._requeue(s, r,
+                                               f"decode fault: {exc!r}"):
                 res.retried += 1
         if reset_pages:
             self._rt.reset_pages()

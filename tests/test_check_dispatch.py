@@ -62,6 +62,11 @@ def test_captured_dispatch_budget_and_parity():
     assert res["serve_decode_retraces"] == 0
     assert res["serve_pages_leaked"] == 0
     assert res["serve_decode_steps_measured"] > 0
+    # ISSUE 35: the phase's backlog (5 requests, 3 slots) looks ahead, and
+    # a turn dispatched before the previous read is still ONE dispatch of
+    # the one executable
+    assert res["serve_lookahead_turns"] > 0
+    assert res["serve_lookahead_dispatches_per_turn"] == 1
     # PR 33: a turn's admissions (several, or the 1 would be vacuous)
     # share ONE dispatch of the one prefill executable
     assert res["serve_most_admitted_in_a_turn"] >= 2

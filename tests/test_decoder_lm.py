@@ -186,6 +186,93 @@ def test_server_generates_the_references_greedy_tokens(model, reference):
     srv.close()
 
 
+@pytest.mark.parametrize("ends", ["by_length", "on_eos"])
+def test_a_backlog_looks_ahead_and_keeps_every_token(model, reference, ends):
+    """ISSUE 35 over `LMRuntime`: 3 x slots requests of mixed lengths
+    queued at once run with a turn in flight (n+1 dispatched before n is
+    read, the token fed back on the device, the slot's recurrent state
+    written in dispatch order); the same requests a wave at a time never
+    look ahead. Both give the reference's greedy tokens through ONE
+    decode executable, a direct `decode` call included, and every launch
+    was read: the last layer's experts ran once a committed turn."""
+    from mxnet_tpu.observability import registry
+    rng = np.random.default_rng(35)
+    prompts = [rng.integers(0, VOCAB, n) for n in (3, 24, 1, 9, 17, 6)]
+    budgets = [9, 4, 12, 7, 3, 10]
+    want = [_greedy(reference, p, n) for p, n in zip(prompts, budgets)]
+    eos_id = -1
+    if ends == "on_eos":
+        eos_id = want[0][3]
+        want = [t[:t.index(eos_id) + 1] if eos_id in t else t for t in want]
+        assert any(len(t) < n for t, n in zip(want, budgets))
+    srv = _server(model, eos_id=eos_id)
+    sched, rt = srv.scheduler, srv.runtime
+    hs = [srv.submit(p, max_new_tokens=n) for p, n in zip(prompts, budgets)]
+    sched.run_until_idle()
+    assert [h.result() for h in hs] == want
+    ahead = sched.lookahead_turns
+    assert 0 < ahead < sched.decode_turns
+    assert rt.moe_counters()["dispatches"][-1] == sched.decode_turns
+    assert srv.pool.in_use() == 0
+    waves = []
+    for i in range(0, 6, 2):
+        hs = [srv.submit(p, max_new_tokens=n)
+              for p, n in zip(prompts[i:i + 2], budgets[i:i + 2])]
+        sched.run_until_idle()
+        waves += [h.result() for h in hs]
+    assert waves == want and sched.lookahead_turns == ahead
+    compiles = registry().counter("compiles", executable="serve_lm_decode")
+    before = compiles.value
+    s_n = rt.slots
+    rt.decode(np.zeros((s_n, rt.max_pages_per_slot), np.int32),
+              np.zeros((s_n,), np.int32), np.zeros((s_n,), np.int32),
+              np.zeros((s_n,), np.int32))
+    assert rt.decode_traces == 1 and rt.prefill_traces == 1
+    assert compiles.value == before
+    assert rt.moe_counters()["dispatches"][-1] == sched.decode_turns + 1
+    srv.close()
+
+
+def test_the_previous_steps_tokens_feed_the_next_on_the_device(model):
+    """`LMRuntime.decode_launch`'s `active` 2 / 1 / 0, as
+    `DecodeRuntime`'s: the logits and the slots' recurrent state are
+    those of feeding the chosen tokens from the host."""
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, VOCAB, n) for n in (5, 9)]
+
+    def run(on_device):
+        srv = _server(model)
+        rt, pool = srv.runtime, srv.pool
+        tables = np.zeros((2, rt.max_pages_per_slot), np.int32)
+        lens = np.zeros((2,), np.int32)
+        cur = np.zeros((2,), np.int32)
+        for s, p in enumerate(prompts):
+            pages = pool.alloc(pool.pages_for(len(p) + 3))
+            tables[s, :len(pages)] = pages
+            rt.prefill(s, p, pages)
+            lens[s], cur[s] = len(p) - 1, p[-1]
+        ones = np.ones((2,), np.int32)
+        out = []
+        if on_device:
+            reads = [rt.decode_launch(tables, lens, cur, ones)]
+            # the host's tokens are wrong on purpose: they must not count
+            reads += [rt.decode_launch(tables, lens + t, cur + 1, 2 * ones)
+                      for t in (1, 2)]
+            out = [np.asarray(r()[1]) for r in reads]
+        else:
+            for t in range(3):
+                cur, lg = rt.decode(tables, lens + t, cur, ones)
+                out.append(np.asarray(lg))
+        state = [np.asarray(a) for a in rt.kda_state]
+        assert rt.decode_traces == 1
+        srv.close()
+        return out, state
+
+    (fed, fed_state), (host, host_state) = run(True), run(False)
+    for a, b in zip(fed + fed_state, host + host_state):
+        assert np.array_equal(a, b)
+
+
 def test_a_turns_admissions_are_one_dispatch_a_prompt_in_queue_order(model):
     """Over `LMRuntime` the scheduler's gathered admissions stay n
     dispatches for n prompts, in the order they were queued (a prompt

@@ -286,7 +286,7 @@ def test_paged_decode_bitwise_parity():
         (kp, vp, _, _), (_, logits_e) = rt._decode_program(
             (kp, vp, None, None),
             (pt_dev, jnp.asarray(lens), jnp.asarray(tok), active,
-             rt.mem_k, rt.mem_v, rt.mem_vl))
+             jnp.zeros((2,), jnp.int32), rt.mem_k, rt.mem_v, rt.mem_vl))
         assert np.array_equal(np.asarray(logits_e)[0],
                               np.asarray(logits_d)[0]), f"step {t}"
         # the jitted production path: same token choice, logits ~1 ULP
@@ -1676,3 +1676,367 @@ def test_memory_loss_in_a_batch_restarts_every_inflight_request():
     assert all(h.retries >= 1 and len(h.result()) == 4 for h in inflight)
     assert srv.pool.in_use() == 0
     srv.close()
+
+
+# ------------------------------------- one turn in flight (ISSUE 35)
+def _mixed_requests(n, seed=35, lo=2, hi=12):
+    rng = np.random.RandomState(seed)
+    return [(rng.randint(4, 50, (int(k),)).astype(np.int32), int(m))
+            for k, m in zip(rng.randint(3, 12, n), rng.randint(lo, hi, n))]
+
+
+def _beam1(model, src, n, eos_id):
+    """The plain greedy reference: `beam_search_cached` at one beam, cut
+    where the server would stop."""
+    from mxnet_tpu.models.transformer import beam_search_cached
+    tokens, _ = beam_search_cached(model, mx.nd.array(src.reshape(1, -1)),
+                                   beam_size=1, max_length=n + 1)
+    want = tokens.asnumpy()[0, 0].tolist()[1:n + 1]
+    return want[:want.index(eos_id) + 1] if eos_id in want else want
+
+
+def _until_in_flight(sched, max_steps=50):
+    for _ in range(max_steps):
+        sched.step()
+        if sched._inflight is not None:
+            return
+    raise AssertionError("no turn was ever left in flight")
+
+
+def _drains(why):
+    return registry().counter("serve_lookahead_drains", why=why).value
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_a_backlog_looks_ahead_and_keeps_every_token(kv_dtype):
+    """3 x slots requests of mixed lengths, queued at once: turns are
+    dispatched before the previous turn's read, and every request's
+    tokens are the plain greedy reference's and those of the same
+    requests sent a wave at a time (no backlog, so no lookahead), through
+    ONE decode executable, a direct `decode` call included."""
+    model = _tiny_model(seed=19)
+    reqs = _mixed_requests(9)
+    srv = _server(model, slots=3, eos_id=-1, kv_dtype=kv_dtype)
+    sched, rt = srv.scheduler, srv.runtime
+    ahead0 = registry().counter("serve_lookahead_turns").value
+    hs = [srv.submit(s, max_new_tokens=m) for s, m in reqs]
+    sched.run_until_idle()
+    backlog = [h.result() for h in hs]
+    ahead = sched.lookahead_turns
+    assert 0 < ahead < sched.decode_turns
+    assert registry().counter("serve_lookahead_turns").value \
+        == ahead0 + ahead
+    assert srv.pool.in_use() == srv.prefix_cache.pages_held()
+    waves = []
+    for i in range(0, 9, 3):
+        hs = [srv.submit(s, max_new_tokens=m) for s, m in reqs[i:i + 3]]
+        sched.run_until_idle()
+        waves += [h.result() for h in hs]
+    assert sched.lookahead_turns == ahead       # a wave leaves no queue
+    assert waves == backlog
+    if kv_dtype is None:
+        assert backlog == [_beam1(model, s, m, -1) for s, m in reqs]
+    assert [len(t) for t in backlog] == [m for _, m in reqs]
+    compiles = registry().counter(
+        "compiles", executable="serve_decode" + ("_int8" if kv_dtype
+                                                 else "")).value
+    s_n = rt.slots
+    rt.decode(np.zeros((s_n, rt.max_pages_per_slot), np.int32),
+              np.zeros((s_n,), np.int32), np.full((s_n,), 2, np.int32),
+              np.zeros((s_n,), np.int32))
+    assert rt.decode_traces == 1
+    assert registry().counter(
+        "compiles", executable="serve_decode" + ("_int8" if kv_dtype
+                                                 else "")).value == compiles
+    srv.close()
+    assert srv.pool.in_use() == 0
+
+
+def test_speculation_never_leaves_a_turn_in_flight():
+    model = _tiny_model(max_length=48)
+    reqs = _mixed_requests(6)
+    srv = _server(model, slots=2, eos_id=-1, speculative_k=2,
+                  max_prompt_len=8)
+    hs = [srv.submit(s, max_new_tokens=m) for s, m in reqs]
+    sched = srv.scheduler
+    while sched.pending_work():
+        sched.step()
+        assert sched._inflight is None
+    assert sched.lookahead_turns == 0
+    assert [h.result() for h in hs] \
+        == [_beam1(model, s, m, -1) for s, m in reqs]
+    srv.close()
+
+
+def test_prompt_tokens_are_forced_from_the_host_under_a_turn_in_flight():
+    """A slot that still has known tokens to feed takes them from the
+    host, turn in flight or not; only the generation frontier reads the
+    previous turn's choice on the device."""
+    model = _tiny_model(max_length=48)
+    rng = np.random.RandomState(3)
+    reqs = [(s, m, rng.randint(4, 50, (int(k),)))
+            for (s, m), k in zip(_mixed_requests(6, seed=4), (5, 0, 9, 3, 7,
+                                                               1))]
+    def run(backlog):
+        srv = _server(model, slots=2, eos_id=-1, max_prompt_len=12,
+                      prefix_cache=False)
+        out = []
+        for i in range(0, 6, 6 if backlog else 2):
+            hs = [srv.submit(s, max_new_tokens=m, prompt_tokens=p)
+                  for s, m, p in reqs[i:i + (6 if backlog else 2)]]
+            srv.scheduler.run_until_idle()
+            out += [h.result() for h in hs]
+        assert (srv.scheduler.lookahead_turns > 0) == backlog
+        assert srv.pool.in_use() == 0
+        srv.close()
+        return out
+
+    assert run(True) == run(False)
+
+
+@pytest.mark.parametrize("event", ["eos", "deadline", "pool_dry", "defrag",
+                                   "shutdown"])
+def test_what_is_rare_meets_a_turn_in_flight(event):
+    """An `eos_id` hit, a deadline's expiry, a dry pool's preemption,
+    `defrag()` and `shutdown()`, each with a turn in flight: the pool
+    ends empty and every handle has the right tokens or the right
+    error."""
+    import time
+    from mxnet_tpu.serve import ServeDeadlineExceeded
+    model = _tiny_model(seed=19)
+    reqs = _mixed_requests(8, seed=8, lo=6)
+    plain = [_beam1(model, s, m, -1) for s, m in reqs]
+    eos_id = -1
+    if event == "eos":
+        # a token the first request chooses mid-way ends it (and cuts
+        # whoever else chooses it) when the next turn is already out
+        eos_id = next(t for t in plain[0] if t != plain[0][0])
+    want = [t[:t.index(eos_id) + 1] if eos_id in t else t for t in plain]
+    kw = dict(slots=2, eos_id=eos_id, prefix_cache=False)
+    if event == "pool_dry":
+        kw.update(page_size=2, num_pages=10, max_retries=0)  # 9 usable
+    srv = _server(model, **kw)
+    sched = srv.scheduler
+    why = {"eos": "queue_empty", "deadline": "queue_empty"}.get(event,
+                                                               event)
+    drains0 = _drains(why)
+    hs = [srv.submit(s, max_new_tokens=m,
+                     deadline_ms=6e4 if event == "deadline" and i == 1
+                     else None)
+          for i, (s, m) in enumerate(reqs)]
+    _until_in_flight(sched)
+    sched.step()
+    assert sched._inflight is not None and sched.lookahead_turns == 1
+    if event == "deadline":
+        assert hs[1].state == "running"
+        hs[1].deadline = time.monotonic()       # it passes now
+    elif event == "defrag":
+        sched.defrag()
+        assert sched._inflight is None
+    elif event == "shutdown":
+        done = [h for h in hs if h.done()]
+        srv.close()
+        assert sched._inflight is None
+        for h, t in zip(hs, want):
+            if h in done or h.state == "done":
+                assert h.result() == t
+            else:
+                with pytest.raises(ServeError):
+                    h.result(timeout=1)
+        assert any(h.state == "failed" for h in hs)
+    sched.run_until_idle(max_steps=2000)
+    assert _drains(why) > drains0
+    assert sched.lookahead_turns > 0
+    if event == "deadline":
+        with pytest.raises(ServeDeadlineExceeded):
+            hs[1].result()
+    if event == "pool_dry":
+        assert sum(h.preemptions for h in hs) > 0
+        assert all(h.retries == 0 for h in hs)
+    if event == "eos":
+        assert 1 < len(want[0]) < reqs[0][1]
+    if event != "shutdown":
+        for i, (h, t) in enumerate(zip(hs, want)):
+            if not (event == "deadline" and i == 1):
+                assert h.result() == t, i
+    assert srv.pool.in_use() == 0
+    assert srv.runtime.decode_traces == 1
+    srv.close()
+
+
+def test_a_decode_fault_under_a_turn_in_flight_fails_the_running_only():
+    """An injected `serve.decode` fault with a turn in flight: the
+    requests in the slots (the turn in flight's and the next one's) fail,
+    the turn in flight is committed first, and the queued ones run to
+    the right tokens."""
+    model = _tiny_model(seed=19)
+    reqs = _mixed_requests(6, seed=8, lo=8)
+    srv = _server(model, slots=2, eos_id=-1, max_retries=0,
+                  prefix_cache=False)
+    sched = srv.scheduler
+    hs = [srv.submit(s, max_new_tokens=m) for s, m in reqs]
+    _until_in_flight(sched)
+    sched.step()
+    running = [r for r in sched._slots if r is not None]
+    had = [len(r.tokens) for r in running]
+    drains0 = _drains("error")
+    finj.inject("serve.decode", times=1)
+    sched.step()
+    assert finj.fires("serve.decode") == 1
+    assert sched._inflight is None and _drains("error") == drains0 + 1
+    assert [r.state for r in running] == ["failed", "failed"]
+    assert all(h.state == "queued" for h in hs if h not in running)
+    sched.run_until_idle()
+    for h, (s, m) in zip(hs, reqs):
+        if h in running:
+            with pytest.raises(ServeError):
+                h.result(timeout=1)
+        else:
+            assert h.result() == _beam1(model, s, m, -1)
+    assert srv.pool.in_use() == 0
+    srv.close()
+
+
+def test_a_failed_read_takes_the_turn_dispatched_after_it_along():
+    """A decode error surfaces at the READ of the turn in flight: its
+    slots and those of the turn dispatched after it are retried, the
+    pools are reset, and everything still ends with the right tokens."""
+    model = _tiny_model(seed=19)
+    reqs = _mixed_requests(6, seed=8, lo=8)
+    srv = _server(model, slots=2, eos_id=-1, max_retries=1,
+                  prefix_cache=False)
+    sched, rt = srv.scheduler, srv.runtime
+    hs = [srv.submit(s, max_new_tokens=m) for s, m in reqs]
+    _until_in_flight(sched)
+    sched.step()
+    running = [r for r in sched._slots if r is not None]
+
+    def boom():
+        raise RuntimeError("device lost the step")
+
+    sched._inflight.read = boom
+    res = sched.step()
+    assert sched._inflight is None and res.retried == 2
+    assert all(r.state == "queued" and r.retries == 1 for r in running)
+    sched.run_until_idle()
+    assert [h.result() for h in hs] \
+        == [_beam1(model, s, m, -1) for s, m in reqs]
+    assert srv.pool.in_use() == 0 and rt.decode_traces == 1
+    srv.close()
+
+
+def test_the_previous_steps_tokens_feed_the_next_on_the_device():
+    """`decode_launch` with `active` 2 takes a slot's input token from the
+    previous launch's choice where it lies on the device, 1 takes the
+    host's, 0 leaves the slot out: the logits are those of feeding the
+    same tokens from the host, the launch returns before anything is
+    read, and it is still the ONE executable."""
+    srv = _server(_tiny_model(seed=19), slots=3, eos_id=-1)
+    rt, pool = srv.runtime, srv.pool
+    rng = np.random.RandomState(2)
+    tables = np.zeros((3, rt.max_pages_per_slot), np.int32)
+    for s in range(3):
+        pages = pool.alloc(2)
+        tables[s, :2] = pages
+        rt.prefill(s, rng.randint(4, 50, (6,)))
+    lens = np.zeros((3,), np.int32)
+    first = rt.decode_launch(tables, lens, np.array([2, 7, 9], np.int32),
+                             np.array([1, 1, 1], np.int32))
+    # slot 0 from the device, slot 1 from the host, slot 2 sits out: the
+    # host's entries for slots 0 and 2 must not matter
+    second = rt.decode_launch(tables, lens + 1,
+                              np.array([44, 11, 45], np.int32),
+                              np.array([2, 1, 0], np.int32))
+    chose, _ = first()
+    _, fed = second()
+    rt.reset_pages()
+    rt.decode(tables, lens, np.array([2, 7, 9], np.int32),
+              np.array([1, 1, 1], np.int32))
+    _, host = rt.decode(tables, lens + 1,
+                        np.array([chose[0], 11, 0], np.int32),
+                        np.array([1, 1, 0], np.int32))
+    assert np.array_equal(np.asarray(fed)[:2], np.asarray(host)[:2])
+    _, other = rt.decode(tables, lens + 1,
+                         np.array([chose[0] + 1, 11, 0], np.int32),
+                         np.array([1, 1, 0], np.int32))
+    assert not np.array_equal(np.asarray(other)[0], np.asarray(host)[0])
+    assert rt.decode_traces == 1
+    srv.close()
+
+
+def test_a_turn_dispatched_ahead_writes_only_pages_its_slots_own():
+    """Page safety of the lookahead: at every dispatch, turn in flight or
+    not, each running slot's row goes to a page that slot's request holds
+    then, and to no other slot's. A request that ends on `eos_id` while
+    the next turn is out frees its pages at commit; their next owner is
+    dispatched after that stale row, and reads the right tokens."""
+    model = _tiny_model(seed=19)
+    reqs = _mixed_requests(8, seed=8, lo=6)
+    plain = [_beam1(model, s, m, -1) for s, m in reqs]
+    eos_id = next(t for t in plain[0] if t != plain[0][0])
+    srv = _server(model, slots=2, eos_id=eos_id, prefix_cache=False)
+    sched, rt = srv.scheduler, srv.runtime
+    launch, stale = rt.decode_launch, []
+
+    def checked(tables, lens, tok, active):
+        writes = {}
+        for s, r in enumerate(sched._slots):
+            if active[s]:
+                page = int(tables[s, lens[s] // rt.page_size])
+                assert page != NULL_PAGE and page in r._pages
+                writes[s] = page
+        assert len(set(writes.values())) == len(writes)
+        if sched._inflight is not None:
+            stale.append(sum(1 for s, r in sched._inflight.rows.items()
+                             if active[s] == 2))
+        return launch(tables, lens, tok, active)
+
+    rt.decode_launch = checked
+    hs = [srv.submit(s, max_new_tokens=m) for s, m in reqs]
+    sched.run_until_idle()
+    assert sum(stale) > 0                   # rows were fed on the device
+    assert [h.result() for h in hs] == [
+        t[:t.index(eos_id) + 1] if eos_id in t else t for t in plain]
+    assert any(len(h.tokens) < m for h, (_, m) in zip(hs, reqs))
+    assert srv.pool.in_use() == 0
+    srv.close()
+
+
+def test_an_engine_driven_backlog_looks_ahead_under_concurrent_submits():
+    """The engine's loop cranks turns with one in flight while four
+    threads submit (more requests than slots, and a queue that flickers
+    empty as they race the loop): every request gets the plain greedy
+    tokens, the pool ends empty, nothing is left in flight."""
+    import sys
+    import threading
+    from mxnet_tpu import engine
+    model = _tiny_model(seed=19)
+    reqs = _mixed_requests(24, seed=5)
+    want = [_beam1(model, s, m, -1) for s, m in reqs]
+    srv = _server(model, slots=3, eos_id=-1, engine_driven=True,
+                  prefix_cache=False)
+    handles = [None] * len(reqs)
+
+    def feed(k):
+        for i in range(k, len(reqs), 4):
+            handles[i] = srv.submit(*reqs[i][:1], max_new_tokens=reqs[i][1])
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=feed, args=(k,)) for k in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+        got = [h.result(timeout=120) for h in handles]
+    finally:
+        sys.setswitchinterval(old)
+    assert got == want
+    assert srv.wait(timeout=60)
+    sched = srv.scheduler
+    assert sched.lookahead_turns > 0 and sched._inflight is None
+    assert srv.pool.in_use() == 0 and srv.runtime.decode_traces == 1
+    srv.close()
+    assert not any("serve" in f["site"] for f in engine.failures())

@@ -57,31 +57,84 @@ def _spans(events):
     return sorted(out, key=lambda s: s[1])
 
 
+def _turn_trees(spans):
+    """[(the turn's span, its phases by start, everything else inside)]."""
+    out = []
+    for turn in (s for s in spans if s[0] == "serve.turn"):
+        inside = [s for s in spans if s[0] != "serve.turn"
+                  and turn[1] <= s[1] and s[2] <= turn[2] and s[3] == turn[3]]
+        out.append((turn, [s for s in inside if s[0] in PHASES], inside))
+    return out
+
+
+def _check_phases(phases, inside):
+    # nested in time: each phase ends before the next begins
+    assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
+    for s in inside:
+        if s[0] == "serve.prefill":
+            assert phases[0][1] <= s[1] and s[2] <= phases[0][2]
+
+
 def test_every_turn_holds_its_phases_in_order_on_one_thread():
+    """The serial order (no request waits for a slot after a turn's
+    admission): every `serve.turn` is one committed turn and holds admit,
+    plan, decode_step, commit."""
     srv = _server()
     tracer.start()
-    hs = [srv.submit(s) for s in _sources()]
-    srv.scheduler.run_until_idle()
+    for wave in (_sources()[:2], _sources()[2:4]):
+        hs = [srv.submit(s) for s in wave]
+        srv.scheduler.run_until_idle()
     tracer.stop()
+    assert srv.scheduler.lookahead_turns == 0
     spans = _spans(_events())
-    turns = [s for s in spans if s[0] == "serve.turn"]
-    assert len(turns) == srv.scheduler.decode_turns > 0
-    assert [t[4]["turn"] for t in turns] == list(range(len(turns)))
-    assert turns[0][4]["queued"] == len(hs)
-    for _, t0, t1, tid, _ in turns:
-        inside = [s for s in spans if s[0] != "serve.turn"
-                  and t0 <= s[1] and s[2] <= t1 and s[3] == tid]
-        phases = [s for s in inside if s[0] in PHASES]
+    trees = _turn_trees(spans)
+    assert len(trees) == srv.scheduler.decode_turns > 0
+    assert [t[4]["turn"] for t, _, _ in trees] == list(range(len(trees)))
+    assert trees[0][0][4]["queued"] == len(hs)
+    for _, phases, inside in trees:
         assert [s[0] for s in phases] == PHASES
-        # nested in time: each phase ends before the next begins
-        assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
-        admit = phases[0]
-        for s in inside:
-            if s[0] == "serve.prefill":
-                assert admit[1] <= s[1] and s[2] <= admit[2]
-    assert len({t[3] for t in turns}) == 1
+        _check_phases(phases, inside)
+    assert len({t[3] for t, _, _ in trees}) == 1
     assert {s[0] for s in spans} <= set(PHASES) | {"serve.turn",
                                                    "serve.prefill"}
+    srv.close()
+
+
+def test_a_backlog_turn_keeps_the_four_phases_around_the_turn_in_flight():
+    """ISSUE 35: with requests waiting for a slot, a turn dispatches the
+    NEXT decode step and reads the one in flight inside the one
+    `serve.decode_step` (which keeps `active` and `cached_tokens`), then
+    commits: still admit, plan, decode_step, commit under `serve.turn`.
+    Only the turn that starts a run of lookahead has nothing to commit
+    yet, and the one that ends it (the queue went empty) reads without
+    planning or dispatching; commits and decode turns stay one to one."""
+    srv = _server()
+    sched = srv.scheduler
+    tracer.start()
+    hs = [srv.submit(s) for s in _sources()]
+    sched.run_until_idle()
+    tracer.stop()
+    assert sched.lookahead_turns > 0
+    trees = _turn_trees(_spans(_events()))
+    shapes = [tuple(s[0][6:] for s in phases) for _, phases, _ in trees]
+    whole = ("admit", "plan", "decode_step", "commit")
+    assert set(shapes) <= {whole, whole[:3], whole[:1] + whole[2:]}
+    assert shapes.count(whole) >= sched.lookahead_turns
+    # a run also ends where every slot of the turn in flight ends with it
+    # (nothing to dispatch: a whole turn whose decode_step only reads)
+    assert 1 <= shapes.count(whole[:3]) >= shapes.count(whole[:1] + whole[2:])
+    assert sum("commit" in s for s in shapes) == sched.decode_turns
+    turn_no = [t[4]["turn"] for t, _, _ in trees]
+    assert turn_no == sorted(turn_no) and turn_no[-1] == sched.decode_turns - 1
+    for (_, phases, inside), shape in zip(trees, shapes):
+        _check_phases(phases, inside)
+        step = phases[shape.index("decode_step")]
+        if step[4]["active"]:
+            assert step[4]["cached_tokens"] >= 0
+        else:
+            assert step[4] == {"active": 0}      # a read, no dispatch
+            assert "commit" in shape
+    assert all(len(h.result()) == 6 for h in hs)
     srv.close()
 
 

@@ -256,7 +256,8 @@ def test_serve_programs_make_nothing_of_a_pools_size(one_chip, chip_compile,
 
     ints = [((slots, 8), I32), ((slots,), I32),
             ((slots,) if width == 1 else (slots, width), I32)]
-    ints += [((slots,), I32)] * (1 if width == 1 else 2)
+    # active, then the previous step's tokens (decode) or qlens (verify)
+    ints += [((slots,), I32)] * 2
     inputs = tuple(jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                    for s, d in ints)
     inputs += jax.tree_util.tree_map(aval, (rt.mem_k, rt.mem_v, rt.mem_vl))

@@ -706,12 +706,19 @@ def _run_serve_phase(errors):
     worst = 0
     decode_steps = 0
     worst_admit = most_admitted = 0
+    worst_ahead = 0
     rows = srv.runtime.prefill_rows
     for _ in range(100):
         if not sched.pending_work():
             break
         profiler.reset_dispatches()
+        ahead = sched.lookahead_turns
         r = sched.step()
+        if sched.lookahead_turns > ahead:
+            # a backlog turn (ISSUE 35: dispatched before the turn in
+            # flight was read) still pays ONE decode dispatch
+            worst_ahead = max(worst_ahead,
+                              profiler.dispatch_count("serve_decode"))
         if r.decoded and not r.admitted:
             # a pure decode turn: the only allowed launch is the decode
             # executable itself
@@ -737,6 +744,10 @@ def _run_serve_phase(errors):
     if worst > 1:
         errors.append(f"serve decode budget exceeded: {worst} "
                       f"dispatches/turn (budget 1)")
+    if worst_ahead != 1:
+        errors.append(f"serve backlog turns (one in flight) paid "
+                      f"{worst_ahead} decode dispatches (budget 1; 0 means "
+                      f"the phase never looked ahead)")
     if most_admitted < 2:
         errors.append("serve phase measured no turn of several admissions")
     if worst_admit > 1:
@@ -756,6 +767,8 @@ def _run_serve_phase(errors):
         "serve_decode_budget": 1,
         "serve_decode_steps_measured": decode_steps,
         "serve_decode_retraces": retraces,
+        "serve_lookahead_dispatches_per_turn": worst_ahead,
+        "serve_lookahead_turns": sched.lookahead_turns,
         "serve_prefill_dispatches_per_admit_turn": worst_admit,
         "serve_most_admitted_in_a_turn": most_admitted,
         "serve_prefill_traces": prefill_traces,
