@@ -55,6 +55,7 @@ import os
 import re
 import threading
 import weakref
+import zlib
 from time import perf_counter_ns
 
 import jax
@@ -66,7 +67,7 @@ from .metrics_registry import registry as _registry
 __all__ = ["instrument", "InstrumentedJit", "inspect_hlo_text",
            "analyze_jit", "analyze_compiled", "set_compilation_cache",
            "entry_compilation_cache", "compilation_cache_dir", "compile_cache_stats", "executables",
-           "instrumented", "last_inspections", "op_scopes",
+           "instrumented", "last_inspections", "op_scopes", "op_names",
            "COLLECTIVE_OPS", "set_dispatch_hook", "dispatch_hook"]
 
 # HLO collective opcodes tallied into hlo_collectives{op=}; async
@@ -159,6 +160,29 @@ _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 _CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
 
 
+_MODULE_RE = re.compile(r"HloModule\s+([\w.\-]+)")
+# what an instruction of a computation a trace can show runs of another
+# one: a `while`'s body and condition, a `conditional`'s branches, a
+# `call`'s callee (a reducer's `to_apply` is no op of a trace)
+_CONTROL_RE = re.compile(
+    r"(?:body|condition|true_computation|false_computation)=%?([\w.\-]+)"
+    r"|branch_computations=\{([^}]*)\}|\scall\(.*to_apply=%?([\w.\-]+)")
+OP_NAME_CHARS = 120
+
+
+def _instruction_lines(text):
+    """(computation, whether it is the entry, line) for every instruction
+    line of one module text."""
+    comp = entry = None
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            if line.endswith("{"):          # `[ENTRY ]%name (params) -> .. {`
+                comp = line.split(" (", 1)[0].split()[-1].lstrip("%")
+                entry = line.startswith("ENTRY")
+            continue
+        yield comp, entry, line
+
+
 def op_scopes(text):
     """{instruction name: sorted tuple of the `mx_*` scopes it holds} over
     every instruction of one optimized-HLO module text (names are unique
@@ -166,14 +190,11 @@ def op_scopes(text):
     metadata `op_name` and, for a fusion, in the instructions of the
     computation it calls. A device trace names its events by instruction
     (`fusion.1178`) and keeps no metadata; this map is the join. A fusion
-    that mixes two scopes is listed under both."""
+    that mixes two scopes is listed under both. A scope inside another
+    (`mx_moe_dispatch` in `mx_moe`) shows as both: an `op_name` is the
+    whole path of functions."""
     own, calls, in_comp = {}, {}, {}
-    comp = None
-    for line in text.splitlines():
-        if not line.startswith(" "):
-            if line.endswith("{"):          # `[ENTRY ]%name (params) -> .. {`
-                comp = line.split(" (", 1)[0].split()[-1].lstrip("%")
-            continue
+    for comp, _, line in _instruction_lines(text):
         scoped, caller = "mx_" in line, "calls=" in line
         if not (scoped or caller):
             continue
@@ -196,12 +217,57 @@ def op_scopes(text):
     return out
 
 
-def inspect_hlo_text(text):
+def op_names(text):
+    """{instruction name: its metadata `op_name`, cut to OP_NAME_CHARS}
+    for the instructions a device trace can show: those of the entry
+    computation and of the bodies of `while` / `conditional` / `call`,
+    not the insides of fusions (a fusion that carries no metadata of its
+    own takes its root's). What says where an instruction WITHOUT a scope
+    came from (`jit(program)/jit(main)/transpose`)."""
+    names, runs, roots, bare, entry = {}, {}, {}, [], None
+    for comp, is_entry, line in _instruction_lines(text):
+        m = _INSTR_RE.match(line)
+        if m is None:
+            continue
+        if is_entry:
+            entry = comp
+        meta = _OP_NAME_RE.search(line)
+        if meta:
+            cut = meta.group(1)[:OP_NAME_CHARS]
+            names.setdefault(comp, {})[m.group(1)] = cut
+            if line.lstrip().startswith("ROOT"):
+                roots[comp] = cut
+        elif "calls=" in line:
+            bare.append((comp, m.group(1), _CALLS_RE.search(line).group(1)))
+        if "=%" in line or "={" in line:
+            for one, many, callee in _CONTROL_RE.findall(line):
+                runs.setdefault(comp, set()).update(
+                    c.strip().lstrip("%")
+                    for c in (one, callee, *many.split(",")) if c.strip())
+    for comp, name, callee in bare:     # a fusion the compiler gave no
+        if callee in roots:             # metadata takes its root's
+            names.setdefault(comp, {})[name] = roots[callee]
+    out, seen, todo = {}, set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp is None or comp in seen:
+            continue
+        seen.add(comp)
+        out.update(names.get(comp, {}))
+        todo.extend(runs.get(comp, ()))
+    return out
+
+
+def inspect_hlo_text(text, names=True):
     """Count the structure of one optimized-HLO module text: fusions,
     collectives (per op + total), copies, donated-input aliases, module
-    byte size, the full opcode histogram, and `op_scopes` (which
-    instructions hold which `mx_*` named scope). Pure function — the gate
-    and tests call it on any `compiled.as_text()`."""
+    byte size, the full opcode histogram, `module` (the module's name on
+    the text's first line: what a profiler prints on `XLA Modules`, so
+    what says WHICH program an event of a trace ran), `op_scopes` (which
+    instructions hold which `mx_*` named scope) and `op_names` (where
+    each instruction a trace can show came from; left out with `names`
+    false). Pure function — the gate and tests call it on any
+    `compiled.as_text()`."""
     ops = {}
     for m in _OP_RE.finditer(text):
         op = m.group(1)
@@ -211,7 +277,7 @@ def inspect_hlo_text(text):
         n = ops.get(op, 0) + ops.get(op + "-start", 0)
         if n:
             colls[op] = n
-    return {
+    info = {
         "fusions": ops.get("fusion", 0),
         "collectives": colls,
         "collective_total": sum(colls.values()),
@@ -219,14 +285,23 @@ def inspect_hlo_text(text):
         "aliased_inputs": text.count("may-alias") + text.count("must-alias"),
         "module_bytes": len(text),
         "ops": ops,
+        "module": m.group(1) if (m := _MODULE_RE.match(text)) else None,
         "op_scopes": op_scopes(text),
     }
+    if names:
+        info["op_names"] = op_names(text)
+    return info
 
 
-def analyze_compiled(compiled):
+def analyze_compiled(compiled, defer_names=False):
     """`inspect_hlo_text` of a jax.stages.Compiled plus its
-    cost_analysis flops / bytes-accessed where the backend reports them."""
-    info = inspect_hlo_text(compiled.as_text())
+    cost_analysis flops / bytes-accessed where the backend reports them.
+    With `defer_names` the module text is kept compressed (`hlo_z`) in
+    place of `op_names`, which `_with_names` parses when first asked for."""
+    text = compiled.as_text()
+    info = inspect_hlo_text(text, names=not defer_names)
+    if defer_names:
+        info["hlo_z"] = zlib.compress(text.encode(), 1)
     try:
         ca = compiled.cost_analysis()
         d = ca[0] if isinstance(ca, (list, tuple)) else ca
@@ -255,14 +330,32 @@ def analyze_jit(jfn, *args, **kwargs):
     """AOT-compile `jfn` for the avals/shardings of `args`/`kwargs` and
     return its optimized-HLO counts (no dispatch, no registry writes).
     Accepts an InstrumentedJit or a bare jitted callable."""
+    return _analyze(jfn, args, kwargs)
+
+
+def _analyze(jfn, args, kwargs, defer_names=False):
     jfn = getattr(jfn, "_jfn", jfn)
     aargs, akwargs = jax.tree_util.tree_map(_abstract, (args, kwargs))
     prev = getattr(_tl, "inspecting", False)
     _tl.inspecting = True
     try:
-        return analyze_compiled(jfn.lower(*aargs, **akwargs).compile())
+        return analyze_compiled(jfn.lower(*aargs, **akwargs).compile(),
+                                defer_names)
     finally:
         _tl.inspecting = prev
+
+
+def _with_names(info):
+    """A published inspection with its `op_names`, parsed the first time
+    it is asked for and not when the program compiled: a training step's
+    is tens of thousands of strings, and what a compile leaves on the
+    host's heap moves the collector's pauses into the steps that follow
+    (read on the chip, PR 36: 0.6% of a BERT step's rate and 147 MB of its
+    peak memory, gone with the parse put off)."""
+    if info and "hlo_z" in info:
+        info["op_names"] = op_names(
+            zlib.decompress(info.pop("hlo_z")).decode())
+    return info
 
 
 # --------------------------------------------------------- dispatch hook
@@ -339,7 +432,7 @@ class InstrumentedJit:
     def last_hlo(self):
         """The last inspection of this executable's optimized HLO
         (`inspect_hlo_text`'s dict), None before one."""
-        return self._last_hlo
+        return _with_names(self._last_hlo)
 
     @last_hlo.setter
     def last_hlo(self, info):
@@ -404,7 +497,7 @@ class InstrumentedJit:
                          executable=self.executable).inc()
             return
         try:
-            info = analyze_jit(self._jfn, *args, **kwargs)
+            info = _analyze(self._jfn, args, kwargs, defer_names=True)
         except Exception as e:
             _reg.counter("hlo_inspect_errors",
                          executable=self.executable).inc()
@@ -463,8 +556,9 @@ def instrumented():
 def last_inspections():
     """{executable name: the last `inspect_hlo_text` dict published under
     it} — outlives the wrappers, so a device trace taken from a step
-    that is gone by now can still be joined with its `op_scopes`."""
-    return dict(_inspections)
+    that is gone by now can still be joined with its `op_scopes` (and its
+    `op_names`, parsed here the first time: `_with_names`)."""
+    return {exe: _with_names(info) for exe, info in _inspections.items()}
 
 
 # -------------------------------------------- persistent compile cache

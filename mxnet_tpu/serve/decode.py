@@ -144,6 +144,81 @@ def _quant_page_write(pages, scales, page, off, vals):
     return pages, new_sc
 
 
+def _cached_attention(pools, page, off, qh, kh, vh, page_tables, lens):
+    """One layer's share of a turn: the tokens' K/V rows (..., H, dh)
+    into its pools (k_pages, v_pages, k_scales, v_scales; the scales None
+    without int8 KV) at (page, off), then the shared attention launch
+    over those pools where they lie. Returns (attention, the pools)."""
+    k_pages, v_pages, k_scales, v_scales = pools
+    pad = [(0, 0)] * kh.ndim
+    pad[-1] = (0, k_pages.shape[-1] - kh.shape[-1])
+    written = []
+    for pages, scales, vals in ((k_pages, k_scales, kh),
+                                (v_pages, v_scales, vh)):
+        vals = jnp.pad(jnp.moveaxis(vals, -2, 0), pad)      # (H, ..., lanes)
+        if scales is None:
+            pages = pages.at[_rows(pages, page, off)].set(vals)
+        else:
+            pages, scales = _quant_page_write(pages, scales, page, off, vals)
+        written.append((pages, scales))
+    (k_pages, k_scales), (v_pages, v_scales) = written
+    a = ragged_paged_attention(qh, k_pages, v_pages, page_tables, lens + 1,
+                               k_scales=k_scales, v_scales=v_scales)
+    return a, (k_pages, v_pages, k_scales, v_scales)
+
+
+# ------------------------------------------ the named device-time scopes
+# The parts of a decode turn (`mx_embed`, `mx_self_attn`, `mx_cross_attn`,
+# `mx_ffn`, `mx_head`; the verify program shares them where it shares the
+# code) and of a trip of the prefill program's loop (`mx_encoder`,
+# `mx_memory_kv`). In a program each runs as `_scope(name, fn,
+# weights...)(arrays...)`: a jitted function that XLA inlines and whose
+# name its ops carry (`models.decoder_lm` says why not `jax.named_scope`,
+# and how a scope is named). The weights are BOUND, not passed: these
+# programs close over them, so inside a function they are what they are
+# in the program, concrete arrays whose own arithmetic (`w.T`, a cast)
+# runs once while the function is traced and leaves a constant; as
+# operands they would be traced, and the compiler would lay a weight out
+# otherwise (read in the described-chip compile: the QKV weights
+# transposed, the 36,548-wide projection read in float32). A layer's
+# pools ride through `mx_self_attn`, the memory buffers through
+# `mx_memory_kv`, and the writes stay in place in the donated buffers.
+def _scope(name, fn, *bound):
+    def scoped(*arrays):
+        return fn(*bound, *arrays)
+    scoped.__name__ = scoped.__qualname__ = name
+    return jax.jit(scoped)
+
+
+def _self_attn(L, h, x, pools, page, off, page_tables, lens):
+    """QKV, the page write, the paged attention, the output projection
+    with its residual and norm. x: (S, U) or a window's (S, W, U)."""
+    q, k, v = (a.reshape(x.shape[:-1] + (h, -1))
+               for a in decoder_layer_qkv(L, x))
+    a, pools = _cached_attention(pools, page, off, q, k, v, page_tables,
+                                 lens)
+    return decoder_layer_self_post(L, x, a.reshape(x.shape)), pools
+
+
+def _head(w, x):
+    logits = decode_project(w, x)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
+
+
+def _memory_kv(w, memory, vl, mem, slot):
+    """A source's cross-attention K/V of every layer and their writes,
+    with the source's length, into the slot's rows of the memory
+    buffers `mem`."""
+    mem_k, mem_v, mem_vl = mem
+    kv = precompute_memory_kv(w, memory)
+    mk = jnp.stack([k for k, _ in kv])      # (n_layers, 1, H, Ssrc, dh)
+    mv = jnp.stack([v for _, v in kv])
+    at = (0, slot, 0, 0, 0)
+    return (lax.dynamic_update_slice(mem_k, mk, at),
+            lax.dynamic_update_slice(mem_v, mv, at),
+            lax.dynamic_update_slice(mem_vl, vl, (slot,)))
+
+
 def _raised(call, *args):
     """The exception `call(*args)` raised, or None: what a runtime's
     `prefill_many` yields of each dispatch."""
@@ -286,28 +361,17 @@ class DecodeRuntime:
     def _pools(self):
         return self.k_pages, self.v_pages, self.k_scales, self.v_scales
 
-    def _cached_attention(self, pools, li, page, off, qh, kh, vh,
-                          page_tables, lens):
-        """Layer `li`'s share of a turn: the tokens' K/V rows (..., H, dh)
-        into its pools at (page, off), in place in the lists of `pools`,
-        then the shared attention launch over those pools where they
-        lie."""
-        k_pages, v_pages, k_scales, v_scales = pools
-        pad = [(0, 0)] * kh.ndim
-        pad[-1] = (0, k_pages[li].shape[-1] - self._dh)
-        for pages, scales, vals in ((k_pages, k_scales, kh),
-                                    (v_pages, v_scales, vh)):
-            vals = jnp.pad(jnp.moveaxis(vals, -2, 0), pad)  # (H, ..., lanes)
-            if scales is None:
-                pages[li] = pages[li].at[_rows(pages[li], page, off)].set(
-                    vals)
-            else:
-                pages[li], scales[li] = _quant_page_write(
-                    pages[li], scales[li], page, off, vals)
-        return ragged_paged_attention(
-            qh, k_pages[li], v_pages[li], page_tables, lens + 1,
-            k_scales=None if k_scales is None else k_scales[li],
-            v_scales=None if v_scales is None else v_scales[li])
+    def _layer_self_attn(self, pools, li, L, x, page, off, page_tables,
+                         lens):
+        """The `mx_self_attn` scope over layer `li`'s pools, which it
+        leaves in their places in the lists of `pools`."""
+        x, mine = _scope("mx_self_attn", _self_attn, L, self._h)(
+            x, tuple(None if a is None else a[li] for a in pools), page,
+            off, page_tables, lens)
+        for a, new in zip(pools, mine):
+            if a is not None:
+                a[li] = new
+        return x
 
     def _decode_program(self, pools, inputs):
         """One token a slot. inputs: (page_tables, lens, tok, active,
@@ -322,25 +386,18 @@ class DecodeRuntime:
         tok = jnp.where(active == 2, prev_tok, tok)
         w, h, psize = self._w, self._h, self.page_size
         pools = [None if a is None else list(a) for a in pools]
-        s_n = tok.shape[0]
-        x = decode_embed(w, tok, lens)                       # (S, U)
-        rows = jnp.arange(s_n)
+        x = _scope("mx_embed", decode_embed, w)(tok, lens)   # (S, U)
+        rows = jnp.arange(tok.shape[0])
         page = page_tables[rows, lens // psize]
         page = jnp.where(active > 0, page, NULL_PAGE)
         off = lens % psize
         for li, L in enumerate(w["layers"]):
-            q, k, v = decoder_layer_qkv(L, x)
-            qh = q.reshape(s_n, h, self._dh)
-            kh = k.reshape(s_n, h, self._dh)
-            vh = v.reshape(s_n, h, self._dh)
-            a = self._cached_attention(pools, li, page, off, qh, kh, vh,
-                                       page_tables, lens)
-            x = decoder_layer_self_post(L, x, a.reshape(s_n, h * self._dh))
-            x = decoder_layer_cross(L, h, x, mem_k[li], mem_v[li], mem_vl)
-            x = decoder_layer_ffn(L, x)
-        logits = decode_project(w, x)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return tuple(pools), (next_tok, logits)
+            x = self._layer_self_attn(pools, li, L, x, page, off,
+                                      page_tables, lens)
+            x = _scope("mx_cross_attn", decoder_layer_cross, L, h)(
+                x, mem_k[li], mem_v[li], mem_vl)
+            x = _scope("mx_ffn", decoder_layer_ffn, L)(x)
+        return tuple(pools), _scope("mx_head", _head, w)(x)
 
     def _verify_program(self, pools, inputs):
         """The widened decode step. inputs: (page_tables, lens, toks,
@@ -361,7 +418,7 @@ class DecodeRuntime:
         npages = page_tables.shape[1]
         rows = jnp.arange(s_n)
         pos = lens[:, None] + jnp.arange(width, dtype=lens.dtype)[None, :]
-        x = decode_embed(w, toks, pos)                   # (S, W, U)
+        x = _scope("mx_embed", decode_embed, w)(toks, pos)   # (S, W, U)
         slot_page = jnp.minimum(pos // psize, npages - 1)
         page = page_tables[rows[:, None], slot_page]     # (S, W)
         valid = (jnp.arange(width)[None, :] < qlens[:, None]) \
@@ -369,22 +426,14 @@ class DecodeRuntime:
         page = jnp.where(valid, page, NULL_PAGE)
         off = pos % psize
         for li, L in enumerate(w["layers"]):
-            q, k, v = decoder_layer_qkv(L, x)
-            qh = q.reshape(s_n, width, h, self._dh)
-            kh = k.reshape(s_n, width, h, self._dh)
-            vh = v.reshape(s_n, width, h, self._dh)
             # query i sees positions 0..lens+i (its own included): the
             # ragged-query-length form of the shared paged attention
-            a = self._cached_attention(pools, li, page, off, qh, kh, vh,
-                                       page_tables, lens)
-            x = decoder_layer_self_post(
-                L, x, a.reshape(s_n, width, h * self._dh))
+            x = self._layer_self_attn(pools, li, L, x, page, off,
+                                      page_tables, lens)
             x = decoder_layer_cross_multi(L, h, x, mem_k[li], mem_v[li],
                                           mem_vl)
-            x = decoder_layer_ffn(L, x)
-        logits = decode_project(w, x)                    # (S, W, V)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return tuple(pools), (next_tok, logits)
+            x = _scope("mx_ffn", decoder_layer_ffn, L)(x)
+        return tuple(pools), _scope("mx_head", _head, w)(x)  # (S, W[, V])
 
     def _prefill_program(self, mem_k, mem_v, mem_vl, rows):
         """rows (R, max_src_len + 2) int32, a request a row: its source,
@@ -403,17 +452,12 @@ class DecodeRuntime:
         src, src_len, slots = rows[:, :s_n], rows[:, s_n], rows[:, s_n + 1]
 
         def encode_row(i, mem):
-            mem_k, mem_v, mem_vl = mem
             row = lax.dynamic_slice_in_dim(src, i, 1)        # (1, Ssrc)
             vl = lax.dynamic_slice_in_dim(src_len, i, 1).astype(jnp.int32)
-            memory = encode_memory(self._ew, row, vl)        # (1, Ssrc, U)
-            kv = precompute_memory_kv(self._w, memory)
-            mk = jnp.stack([k for k, _ in kv])  # (n_layers, 1, H, Ssrc, dh)
-            mv = jnp.stack([v for _, v in kv])
-            at = (0, slots[i], 0, 0, 0)
-            return (lax.dynamic_update_slice(mem_k, mk, at),
-                    lax.dynamic_update_slice(mem_v, mv, at),
-                    lax.dynamic_update_slice(mem_vl, vl, (slots[i],)))
+            memory = _scope("mx_encoder", encode_memory, self._ew)(
+                row, vl)                                     # (1, Ssrc, U)
+            return _scope("mx_memory_kv", _memory_kv, self._w)(
+                memory, vl, mem, slots[i])
 
         return lax.fori_loop(0, jnp.sum(src_len > 0), encode_row,
                              (mem_k, mem_v, mem_vl))

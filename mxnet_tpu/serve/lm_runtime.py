@@ -46,6 +46,7 @@ several GB they cannot be baked into a program.
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import numpy as np
 import jax
@@ -70,6 +71,58 @@ _MIXERS = ("gqa", "kda", "mla", "mamba")
 # and its sequence form
 _RECURRENT = {"kda": ("kda", "conv", lm.mx_kda_seq),
               "mamba": ("ssm", "ssm_conv", lm.mx_mamba_seq)}
+
+
+# ---------------------------------- the programs' own device-time scopes
+# Named jitted functions that XLA inlines, as `models.decoder_lm`'s
+# (which says why, and how a scope is named): what both programs do
+# around the mixers and the expert layers. A pool or a slot array rides
+# through `mx_cache_write` as `k_pages` rides through `lm.mx_gqa`: the
+# write stays in place in the donated buffer.
+@jax.jit
+def mx_embed(table, tok):
+    """The embedding gather."""
+    return table[tok]
+
+
+@partial(jax.jit, static_argnames=("eps",))
+def mx_norm(x, gamma, eps):
+    """A sub-layer's RMSNorm on the way in."""
+    return rms_norm(x, gamma, eps)
+
+
+@partial(jax.jit, static_argnames=("eps",))
+def mx_join(x, y, gamma, eps):
+    """The residual after a sub-layer: its output normed first where the
+    block is a sandwich (`gamma` not None)."""
+    if gamma is not None:
+        y = rms_norm(y, gamma, eps)
+    return x + y
+
+
+@partial(jax.jit, static_argnames=("eps",))
+def mx_head(x, gamma, head, eps):
+    """Final norm, the vocabulary projection, the argmax: (the token
+    chosen (S,) int32, logits (S, V) float32)."""
+    logits = jax.lax.dot_general(
+        rms_norm(x, gamma, eps), head, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return jnp.argmax(logits, -1).astype(jnp.int32), logits
+
+
+@partial(jax.jit, static_argnames=("psize",))
+def mx_cache_write(pool, at, values, psize=None):
+    """A prefill's write into a donated array. With `psize`: `values`
+    (T, ...) are the rows of T / psize whole pages, padded with zeros to
+    the pool's lanes, `at` their page ids. Without: `values` is one
+    slot's row, `at` the slot."""
+    if psize is not None:
+        values = values.reshape(values.shape[0], -1)
+        lanes = pool.shape[-1] - values.shape[-1]
+        if lanes:
+            values = jnp.pad(values, ((0, 0), (0, lanes)))
+        values = values.reshape(-1, psize, pool.shape[-1])
+    return pool.at[at].set(values)
 
 
 class LMRuntime:
@@ -282,9 +335,8 @@ class LMRuntime:
     def _join(self, x, y, L, which):
         """The residual after a sub-layer: its output normed first where
         the block is a sandwich."""
-        if self.spec.sandwich:
-            y = rms_norm(y, L[which + "_post_gamma"], self.spec.eps)
-        return x + y
+        return mx_join(x, y, L.get(which + "_post_gamma"),
+                       eps=self.spec.eps)
 
     def _no_experts(self, rows):
         """What a layer without experts counts: no rows, ids of -1."""
@@ -297,7 +349,7 @@ class LMRuntime:
         expert layer has the last two."""
         if "moe" not in L and "ffn" not in L:
             return (x, *self._no_experts(x.shape[0]))
-        h = rms_norm(x, L["norm2_gamma"], self.spec.eps)
+        h = mx_norm(x, L["norm2_gamma"], eps=self.spec.eps)
         if "moe" in L:
             y, n, idx = lm.mx_moe(L["moe"], h, valid, spec=self.spec)
         else:
@@ -314,14 +366,14 @@ class LMRuntime:
         spec, psize = self.spec, self.page_size
         valid = active > 0
         tok = jnp.where(active == 2, prev_tok, tok)
-        x = weights["embed"][tok]                            # (S, d)
+        x = mx_embed(weights["embed"], tok)                  # (S, d)
         page = page_tables[jnp.arange(tok.shape[0]), lens // psize]
         page = jnp.where(valid, page, NULL_PAGE)
         off = lens % psize
         counts, chose = [], []
         for kind, L, j in self._layers(weights):
             if kind:
-                h = rms_norm(x, L["norm1_gamma"], spec.eps)
+                h = mx_norm(x, L["norm1_gamma"], eps=spec.eps)
             if kind == "gqa":
                 y, state["k"][j], state["v"][j] = lm.mx_gqa(
                     L["mixer"], h, state["k"][j], state["v"][j],
@@ -343,12 +395,10 @@ class LMRuntime:
             x, n, idx = self._ffn(x, L, valid)
             counts.append(n)
             chose.append(idx)
-        x = rms_norm(x, weights["final_norm_gamma"], spec.eps)
-        logits = jax.lax.dot_general(
-            x, weights["head"], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (S, V)
-        return (state, jnp.argmax(logits, -1).astype(jnp.int32), logits,
-                jnp.stack(counts), jnp.stack(chose))
+        next_tok, logits = mx_head(x, weights["final_norm_gamma"],
+                                   weights["head"], eps=spec.eps)
+        return (state, next_tok, logits, jnp.stack(counts),
+                jnp.stack(chose))
 
     def _prefill_program(self, state, weights, tokens, n, slot, page_row):
         """tokens: (plen,) the prompt without its last token, padded; n:
@@ -357,7 +407,7 @@ class LMRuntime:
         spec, psize = self.spec, self.page_size
         pos = jnp.arange(self._plen)
         valid = pos < n
-        x = weights["embed"][tokens]
+        x = mx_embed(weights["embed"], tokens)
         n_pg = self._plen // psize
         row = jnp.pad(page_row, (0, max(0, n_pg - page_row.shape[0])),
                       constant_values=NULL_PAGE)[:n_pg]
@@ -368,28 +418,26 @@ class LMRuntime:
         counts, chose = [], []
         for i, (kind, L, j) in enumerate(self._layers(weights)):
             if kind:
-                h = rms_norm(x, L["norm1_gamma"], spec.eps)
+                h = mx_norm(x, L["norm1_gamma"], eps=spec.eps)
             if kind == "gqa":
                 y, k, v = lm.mx_gqa_seq(L["mixer"], h, spec=spec)
                 for name, a in (("k", k), ("v", v)):
-                    state[name][j] = state[name][j].at[pages].set(
-                        a.reshape(n_pg, psize, -1))
+                    state[name][j] = mx_cache_write(state[name][j], pages,
+                                                    a, psize=psize)
             elif kind == "mla":
                 y, rows = lm.mx_mla_seq(L["mixer"], h, pos, spec=spec)
-                lat = state["lat"][j]
-                rows = jnp.pad(rows, ((0, 0),
-                                      (0, lat.shape[-1] - rows.shape[-1])))
-                state["lat"][j] = lat.at[pages].set(
-                    rows.reshape(n_pg, psize, -1))
+                state["lat"][j] = mx_cache_write(state["lat"][j], pages,
+                                                 rows, psize=psize)
             elif kind in _RECURRENT:
                 which, conv, sequence = _RECURRENT[kind]
                 y, s_end, pre = sequence(L["mixer"], h, valid, spec=spec)
                 if kind == "kda":       # a slot's state is value-major
                     s_end = s_end.swapaxes(-1, -2)
-                state[which][j] = state[which][j].at[slot].set(s_end)
+                state[which][j] = mx_cache_write(state[which][j], slot,
+                                                 s_end)
                 tail = jnp.where((tail_at >= 0)[:, None],
                                  pre[jnp.maximum(tail_at, 0)], 0)
-                state[conv][j] = state[conv][j].at[slot].set(tail)
+                state[conv][j] = mx_cache_write(state[conv][j], slot, tail)
             if i == self._last_mixer:
                 # what follows the last mixer would feed nothing: prefill
                 # gives no logits, the next position needs only the state

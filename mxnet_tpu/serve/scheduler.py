@@ -566,11 +566,23 @@ class Scheduler:
             # carries no `cached_tokens` for a reader to count
             with _tracer.span("serve.decode_step", cat="serve",
                               args={"active": 0}):
-                next_tok = turn.read()
+                next_tok = self._read(turn)
         except Exception as e:
             self._fail_inflight(res, e, reset_pages=True)
             return
         self._finish_turn(turn, next_tok, res)
+
+    def _read(self, turn, lookahead=False):
+        """A turn's tokens: the wait for the device and their copy to
+        the host. Traced as `serve.decode_read`, a child of the turn's
+        `serve.decode_step`; `lookahead` 1 where a later turn was
+        already in flight while it waited (the wait is then the host's
+        slack, not the device's idleness)."""
+        if _tracer.ACTIVE:
+            with _tracer.span("serve.decode_read", cat="serve",
+                              args={"lookahead": int(lookahead)}):
+                return turn.read()
+        return turn.read()
 
     def _commit(self, rows, plans, next_tok, res):
         """The turn's host tail: commit each slot's accepted tokens, emit
@@ -1022,18 +1034,25 @@ class Scheduler:
         # after the dispatch returns, or stay the device's own on a CPU
         tables = self._page_tables.copy()
 
+        def dispatch():
+            if width == 1:
+                read = self._rt.decode_launch(tables, lens, toks[:, 0], mask)
+                return _Turn(rows, plans,
+                             lambda: read()[0].reshape(-1, 1), t0)
+            out, _ = self._rt.decode_multi(tables, lens, toks, qlens, mask)
+            return _Turn(rows, plans, lambda: out, t0)
+
         def launch():
             prev, cur = self._inflight, None
             if rows:
-                if width == 1:
-                    read = self._rt.decode_launch(tables, lens, toks[:, 0],
-                                                  mask)
-                    cur = _Turn(rows, plans,
-                                lambda: read()[0].reshape(-1, 1), t0)
+                # the dispatch and the array building that belongs to it
+                # (a widened turn's tokens come back with its dispatch)
+                if _tracer.ACTIVE:
+                    with _tracer.span("serve.decode_launch", cat="serve",
+                                      args={"active": len(rows)}):
+                        cur = dispatch()
                 else:
-                    out, _ = self._rt.decode_multi(tables, lens, toks,
-                                                   qlens, mask)
-                    cur = _Turn(rows, plans, lambda: out, t0)
+                    cur = dispatch()
                 if prev is not None:
                     self._m_ahead.inc()
                     self.lookahead_turns += 1
@@ -1041,7 +1060,8 @@ class Scheduler:
             # a read that raises leaves NO turn in flight: the error
             # takes the turn dispatched after it along
             self._inflight = None
-            next_tok = None if due is None else due.read()
+            next_tok = None if due is None else self._read(
+                due, lookahead=cur is not None and cur is not due)
             self._inflight = None if cur is due else cur
             return due, next_tok
 

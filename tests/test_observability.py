@@ -634,6 +634,9 @@ def test_op_scopes_gives_a_fusion_the_scopes_of_what_it_calls():
     from mxnet_tpu.observability import compilex
     text = """HloModule jit_program, entry_computation_layout={()->f32[4]}
 
+FunctionNames
+1 "mx_not_an_instruction"
+
 %fused_computation.7 (param_0.1: f32[4], param_1.2: u32[4]) -> f32[4] {
   %param_0.1 = f32[4]{0} parameter(0)
   %param_1.2 = u32[4]{0} parameter(1)
@@ -647,22 +650,109 @@ def test_op_scopes_gives_a_fusion_the_scopes_of_what_it_calls():
   ROOT %neg.1 = f32[4]{0} negate(%param_0.3), metadata={op_name="jit(program)/jvp(dense)/neg"}
 }
 
+%region_0.5 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %add.6 = f32[] add(%a, %b), metadata={op_name="jit(program)/reduce_sum"}
+}
+
+%body.9 (c: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %c = (s32[], f32[4]{0}) parameter(0)
+  %get-tuple-element.1 = f32[4]{0} get-tuple-element(%c), index=1
+  %fusion.18 = f32[4]{0} fusion(%get-tuple-element.1), kind=kLoop, calls=%fused_computation.8, metadata={op_name="jit(program)/while/body/jit(mx_moe)/jit(mx_moe_route)/neg"}
+  %call.3 = f32[4]{0} call(%fusion.18), to_apply=%callee.2
+  ROOT %tuple.4 = (s32[], f32[4]{0}) tuple(%c, %call.3)
+}
+
+%callee.2 (x: f32[4]) -> f32[4] {
+  %x = f32[4]{0} parameter(0)
+  ROOT %exp.1 = f32[4]{0} exponential(%x), metadata={op_name="jit(program)/while/body/LONG"}
+}
+
+%cond.9 (c.1: (s32[], f32[4])) -> pred[] {
+  %c.1 = (s32[], f32[4]{0}) parameter(0)
+  ROOT %lt.1 = pred[] constant(true), metadata={op_name="jit(program)/while/cond/lt"}
+}
+
+%branch_a.1 (t: f32[4]) -> f32[4] {
+  %t = f32[4]{0} parameter(0)
+  ROOT %sin.1 = f32[4]{0} sine(%t), metadata={op_name="jit(program)/cond/branch_0_fun/sin"}
+}
+
+%branch_b.1 (u: f32[4]) -> f32[4] {
+  %u = f32[4]{0} parameter(0)
+  ROOT %cos.1 = f32[4]{0} cosine(%u), metadata={op_name="jit(program)/cond/branch_1_fun/cos"}
+}
+
 ENTRY %main.20 (p0: f32[4], p1: u32[4]) -> f32[4] {
   %p0 = f32[4]{0:T(128)} parameter(0)
   %p1 = u32[4]{0:T(128)} parameter(1)
   %fusion.16 = f32[4]{0:T(128)} fusion(%p0, %p1), kind=kLoop, calls=%fused_computation.7, metadata={op_name="jit(program)/mx_update/cond/branch_1_fun/add"}
   %fusion.17 = f32[4]{0:T(128)} fusion(%fusion.16), kind=kLoop, calls=%fused_computation.8
   %copy.4 = f32[4]{0:T(128)} copy(%fusion.17), metadata={op_name="jit(program)/mx_update/copy"}
+  %reduce.7 = f32[] reduce(%copy.4, %p0), dimensions={0}, to_apply=%region_0.5, metadata={op_name="jit(program)/reduce_sum"}
+  %while.8 = (s32[], f32[4]{0}) while(%copy.4), condition=%cond.9, body=%body.9, metadata={op_name="jit(program)/while"}
+  %conditional.9 = f32[4]{0} conditional(%p1, %copy.4, %copy.4), branch_computations={%branch_a.1, %branch_b.1}, metadata={op_name="jit(program)/cond"}
   ROOT %tanh.5 = f32[4]{0:T(128)} tanh(%copy.4), metadata={op_name="jit(program)/tanh" source_file="/x/mx_update.py"}
 }
-"""
+""".replace("LONG", "x" * 200)
     got = compilex.inspect_hlo_text(text)
-    assert got["fusions"] == 2
+    assert got["fusions"] == 3
     assert got["op_scopes"] == {
         "fusion.16": ("mx_dropout", "mx_update"),    # mixed: under both
         "convert.3": ("mx_dropout",), "mul.9": ("mx_dropout",),
-        "add.2": ("mx_update",), "copy.4": ("mx_update",)}
+        "add.2": ("mx_update",), "copy.4": ("mx_update",),
+        # a scope inside another is held with it: the path names both
+        "fusion.18": ("mx_moe", "mx_moe_route")}
     assert compilex.op_scopes("") == {}
+    # the module's name, as the profiler prints it on `XLA Modules`
+    assert got["module"] == "jit_program"
+    assert compilex.inspect_hlo_text("")["module"] is None
+    # where each instruction a trace can show came from: the entry's, and
+    # the bodies of while / conditional / call; not a fusion's inside
+    # (convert.3, neg.1), not a reducer's (add.6); cut to 120 characters
+    names = got["op_names"]
+    assert names == {
+        "fusion.16": "jit(program)/mx_update/cond/branch_1_fun/add",
+        "copy.4": "jit(program)/mx_update/copy",
+        "reduce.7": "jit(program)/reduce_sum",
+        "while.8": "jit(program)/while", "conditional.9": "jit(program)/cond",
+        "tanh.5": "jit(program)/tanh",
+        # a fusion without metadata of its own takes its root's
+        "fusion.17": "jit(program)/jvp(dense)/neg",
+        "fusion.18":
+            "jit(program)/while/body/jit(mx_moe)/jit(mx_moe_route)/neg",
+        "exp.1": ("jit(program)/while/body/" + "x" * 200)[:120],
+        "lt.1": "jit(program)/while/cond/lt",
+        "sin.1": "jit(program)/cond/branch_0_fun/sin",
+        "cos.1": "jit(program)/cond/branch_1_fun/cos"}
+    assert len(names["exp.1"]) == compilex.OP_NAME_CHARS == 120
+    assert compilex.op_names("") == {}
+
+
+def test_op_names_are_parsed_when_an_inspection_is_first_read(monkeypatch):
+    """A compile leaves the module text compressed; `op_names` is parsed
+    from it when `last_hlo` / `last_inspections()` is first read (a
+    training step's parse at compile time moved the host collector's
+    pauses into its steps: ISSUE 36's chip runs)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.observability import compilex
+    monkeypatch.setenv("MXTPU_HLO_TELEMETRY", "always")
+    monkeypatch.setattr(compilex, "_inspections", {})
+    fn = compilex.instrument(jax.jit(lambda x: jnp.tanh(x) * 2),
+                             "test_deferred_names")
+    fn(jnp.ones((8, 8)))
+    kept = compilex._inspections["test_deferred_names"]
+    assert "op_names" not in kept and isinstance(kept["hlo_z"], bytes)
+    assert kept["module"].startswith("jit_") and kept["fusions"] >= 0
+    got = compilex.last_inspections()["test_deferred_names"]
+    assert got is kept and "hlo_z" not in got
+    assert any(v.startswith("jit(") for v in got["op_names"].values())
+    assert fn.last_hlo["op_names"] == got["op_names"]
+    # the pure function and the gates' `analyze_jit` parse at once
+    eager = compilex.analyze_jit(fn, jnp.ones((8, 8)))
+    assert eager["op_names"] == got["op_names"] and "hlo_z" not in eager
 
 
 def test_a_recording_starts_with_its_own_ring_and_thread_names():

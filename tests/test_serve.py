@@ -241,6 +241,7 @@ def test_paged_decode_bitwise_parity():
     logits at every step. The jitted production path is additionally
     checked to pick identical tokens (whole-program XLA fusion is allowed
     its ~1-ULP reassociation, but never a different argmax here)."""
+    import jax
     import jax.numpy as jnp
     from mxnet_tpu.models.transformer import (decode_step, decoder_weights,
                                               encoder_weights)
@@ -282,11 +283,14 @@ def test_paged_decode_bitwise_parity():
     for t in range(8):
         logits_d, caches = decode_step(
             w, caches, mem_kv, mem_vl, jnp.asarray(tok[:1]), t)
-        # the shared core, executed eagerly: bitwise
-        (kp, vp, _, _), (_, logits_e) = rt._decode_program(
-            (kp, vp, None, None),
-            (pt_dev, jnp.asarray(lens), jnp.asarray(tok), active,
-             jnp.zeros((2,), jnp.int32), rt.mem_k, rt.mem_v, rt.mem_vl))
+        # the shared core, executed eagerly: bitwise (its named scopes,
+        # jitted functions of their own, run op by op too)
+        with jax.disable_jit():
+            (kp, vp, _, _), (_, logits_e) = rt._decode_program(
+                (kp, vp, None, None),
+                (pt_dev, jnp.asarray(lens), jnp.asarray(tok), active,
+                 jnp.zeros((2,), jnp.int32), rt.mem_k, rt.mem_v,
+                 rt.mem_vl))
         assert np.array_equal(np.asarray(logits_e)[0],
                               np.asarray(logits_d)[0]), f"step {t}"
         # the jitted production path: same token choice, logits ~1 ULP

@@ -1,6 +1,8 @@
 """The serve turn's spans (ISSUE 26): `serve.turn` and its phases, the
 request's `submit -> admitted -> request_done` chain, queue wait where the
-request leaves the queue, and nothing recorded with the tracer off."""
+request leaves the queue, and nothing recorded with the tracer off. ISSUE
+36: `serve.decode_step`'s two children, the launch of a turn and the read
+of a turn's tokens."""
 import numpy as np
 import pytest
 
@@ -67,6 +69,16 @@ def _turn_trees(spans):
     return out
 
 
+def _children(step, inside):
+    """`serve.decode_step`'s children, by start: the launch and the read,
+    wholly inside it and one after the other."""
+    kids = [s for s in inside
+            if s[0] in ("serve.decode_launch", "serve.decode_read")
+            and step[1] <= s[1] and s[2] <= step[2]]
+    assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+    return kids
+
+
 def _check_phases(phases, inside):
     # nested in time: each phase ends before the next begins
     assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
@@ -94,9 +106,16 @@ def test_every_turn_holds_its_phases_in_order_on_one_thread():
     for _, phases, inside in trees:
         assert [s[0] for s in phases] == PHASES
         _check_phases(phases, inside)
+        # the serial turn: its launch, then the read of ITS tokens with
+        # no later turn in flight
+        launch, read = _children(phases[2], inside)
+        assert launch[0] == "serve.decode_launch"
+        assert launch[4] == {"active": phases[2][4]["active"]}
+        assert (read[0], read[4]) == ("serve.decode_read", {"lookahead": 0})
     assert len({t[3] for t, _, _ in trees}) == 1
-    assert {s[0] for s in spans} <= set(PHASES) | {"serve.turn",
-                                                   "serve.prefill"}
+    assert {s[0] for s in spans} <= set(PHASES) | {
+        "serve.turn", "serve.prefill", "serve.decode_launch",
+        "serve.decode_read"}
     srv.close()
 
 
@@ -129,12 +148,72 @@ def test_a_backlog_turn_keeps_the_four_phases_around_the_turn_in_flight():
     for (_, phases, inside), shape in zip(trees, shapes):
         _check_phases(phases, inside)
         step = phases[shape.index("decode_step")]
+        kids = _children(step, inside)
         if step[4]["active"]:
             assert step[4]["cached_tokens"] >= 0
+            assert kids[0][0] == "serve.decode_launch"
+            assert kids[0][4] == {"active": step[4]["active"]}
+            # the turn that starts a run of lookahead reads nothing; every
+            # other launch is followed by ONE read: of the turn before,
+            # which waited with this one in flight (`lookahead` 1: as
+            # many as the scheduler counts, below), or of this one where
+            # the order is serial
+            assert [k[0] for k in kids[1:]] \
+                == ["serve.decode_read"] * ("commit" in shape)
+            assert all(k[4] in ({"lookahead": 0}, {"lookahead": 1})
+                       for k in kids[1:])
         else:
             assert step[4] == {"active": 0}      # a read, no dispatch
             assert "commit" in shape
+            assert [(k[0], k[4]) for k in kids] \
+                == [("serve.decode_read", {"lookahead": 0})]
+    reads = [s for _, _, inside in trees for s in inside
+             if s[0] == "serve.decode_read"]
+    assert len(reads) == sched.decode_turns      # every turn is read once
+    assert sum(r[4]["lookahead"] for r in reads) == sched.lookahead_turns
     assert all(len(h.result()) == 6 for h in hs)
+    srv.close()
+
+
+def test_a_drain_is_a_read_alone_and_a_widened_turn_reads_in_its_launch():
+    """`defrag` (as `shutdown`, a dry pool, a fault) first reads the turn
+    in flight: a `serve.decode_step` of `active` 0 that holds ONE child,
+    the read, with nothing in flight behind it. A widened (speculative)
+    turn's tokens come back with its dispatch: launch and read are both
+    there, and the read has nothing left to wait for."""
+    srv = _server()
+    sched = srv.scheduler
+    hs = [srv.submit(s) for s in _sources()]
+    while sched._inflight is None:
+        sched.step()
+    tracer.start()
+    sched.defrag()
+    tracer.stop()
+    spans = _spans(_events())
+    (step,) = [s for s in spans if s[0] == "serve.decode_step"]
+    assert step[4] == {"active": 0}
+    assert [(k[0], k[4]) for k in _children(step, spans)] \
+        == [("serve.decode_read", {"lookahead": 0})]
+    assert not [s for s in spans if s[0] == "serve.decode_launch"]
+    sched.run_until_idle()
+    assert all(len(h.result()) == 6 for h in hs)
+    srv.close()
+
+    srv = _server(speculative_k=2, max_prompt_len=8)
+    tracer.clear()
+    tracer.start()
+    hs = [srv.submit(s) for s in _sources()]
+    srv.scheduler.run_until_idle()
+    tracer.stop()
+    assert srv.scheduler.lookahead_turns == 0    # a widened turn is serial
+    spans = _spans(_events())
+    steps = [s for s in spans if s[0] == "serve.decode_step"]
+    assert len(steps) == srv.scheduler.decode_turns > 0
+    for step in steps:
+        launch, read = _children(step, spans)
+        assert (launch[0], read[0]) == ("serve.decode_launch",
+                                        "serve.decode_read")
+        assert read[4] == {"lookahead": 0}
     srv.close()
 
 
@@ -220,6 +299,7 @@ def test_tracer_off_records_nothing_and_the_counters_still_fill():
     srv.scheduler.run_until_idle()
     off = [h.result() for h in hs]
     assert tracer.events_recorded() == 0
+    assert srv.scheduler.lookahead_turns > 0     # counted all the same
     assert all(h.t_admit is not None for h in hs)
     assert hist.count == n0 + len(hs)
     assert hist.sum - sum0 == pytest.approx(
