@@ -144,8 +144,10 @@ def moe_forward(w, spec, x, valid=None, tile=32):
     out of the routed part (padding, empty slots). Returns (y (T, d), the
     rows each held expert took (held_n,) int32, the expert ids every row
     chose (T, k) int32). No capacity: every routed row that names a held
-    expert is computed. Its five parts are named device-time scopes
-    (`mx_moe_*`, below), so that a device trace prices each."""
+    expert is computed: its row is gathered into the tiles of its expert
+    and gathered back, no row is scattered. Its five parts are named
+    device-time scopes (`mx_moe_*`, below), so that a device trace prices
+    each."""
     idx, wts = mx_moe_route(w, x, spec=spec)
     rows, dest, mine, tile_group, used, counts = mx_moe_dispatch(
         x, idx, valid, spec=spec, tile=tile)
@@ -166,18 +168,18 @@ def mx_moe_route(w, x, spec):
 @partial(jax.jit, static_argnames=("spec", "tile"))
 def mx_moe_dispatch(x, idx, valid, spec, tile):
     """Which (token, choice) pairs name a held expert, where each pair's
-    row lies among the tiles (`gmm.layout`), and the rows scattered there:
+    row lies among the tiles and which pair lies in each of the tiles'
+    rows (`gmm.layout`), and the rows gathered there, ONE row of x a row
+    of the layout; a row where no pair lies holds some row of x:
     (rows (cap, d), dest, mine (T, k), tile_group, used, counts)."""
-    t, d = x.shape
     local = idx - spec.held_lo
     mine = (local >= 0) & (local < spec.held_n)
     if valid is not None:
         mine = mine & valid[:, None]
     group = jnp.where(mine, local, spec.held_n).reshape(-1)
-    dest, tile_group, used, counts = gmm.layout(group, spec.held_n, tile)
-    cap = gmm.rows_capacity(t * spec.top_k, spec.held_n, tile)
-    token = jnp.arange(t * spec.top_k, dtype=jnp.int32) // spec.top_k
-    rows = jnp.zeros((cap, d), x.dtype).at[dest].set(x[token], mode="drop")
+    dest, tile_group, used, counts, src, _ = gmm.layout(
+        group, spec.held_n, tile)
+    rows = x[src // spec.top_k]
     return rows, dest, mine, tile_group, used, counts
 
 

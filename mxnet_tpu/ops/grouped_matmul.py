@@ -35,11 +35,17 @@ def rows_capacity(n_assign, n_groups, tile):
 
 
 def layout(group, n_groups, tile):
-    """Where each routed row goes. group: (A,) int32, the group of each
-    row, `n_groups` for a row that no group here takes. Returns (dest
-    (A,) int32 row in the padded layout, `rows_capacity` for a row not
-    taken; tile_group (tiles,) int32; tiles_used () int32; counts
-    (n_groups,) int32 rows taken by each group)."""
+    """Where each routed row goes, and which row lies where. group: (A,)
+    int32, the group of each row, `n_groups` for a row that no group here
+    takes. Returns (dest (A,) int32 row in the padded layout,
+    `rows_capacity` for a row not taken; tile_group (tiles,) int32;
+    tiles_used () int32; counts (n_groups,) int32 rows taken by each
+    group; src (cap,) int32, the inverse of dest: the row of `group` that
+    lies in each row of the layout, row 0 where none does; live (cap,)
+    bool, whether one does). The rows of a group keep the order they came
+    in. Lay rows out with ONE gather, `x[src]`: a scatter of rows through
+    dest pays for every row, taken or not (`models/decoder_lm.py`,
+    `mx_moe_dispatch`)."""
     a = group.shape[0]
     cap = rows_capacity(a, n_groups, tile)
     every = jnp.zeros((n_groups + 1,), jnp.int32).at[group].add(1)
@@ -57,7 +63,11 @@ def layout(group, n_groups, tile):
     tile_group = jnp.minimum(
         jnp.searchsorted(ends, tile_start, side="right").astype(jnp.int32),
         n_groups - 1)
-    return dest, tile_group, (ends[-1] // tile).astype(jnp.int32), counts
+    # the inverse of dest; a row not taken has dest == cap and is dropped
+    src = jnp.full((cap,), -1, jnp.int32).at[dest].set(
+        jnp.arange(a, dtype=jnp.int32), mode="drop")
+    return (dest, tile_group, (ends[-1] // tile).astype(jnp.int32), counts,
+            jnp.maximum(src, 0), src >= 0)
 
 
 def _gmm_kernel(tg_ref, used_ref, x_ref, w_ref, o_ref, *, nt):

@@ -482,10 +482,10 @@ def test_grouped_matmul_kernel_equals_the_row_by_row_product(interpret):
     group[:20] = 2
     x = rng.normal(size=(rows, k)).astype(np.float32)
     w = rng.normal(size=(groups, k, n)).astype(np.float32)
-    dest, tile_group, used, counts = gmm.layout(jnp.asarray(group), groups,
-                                                tile)
+    dest, tile_group, used, counts, src, _ = gmm.layout(jnp.asarray(group),
+                                                        groups, tile)
     cap = gmm.rows_capacity(rows, groups, tile)
-    laid = jnp.zeros((cap, k), jnp.float32).at[dest].set(x, mode="drop")
+    laid = jnp.asarray(x)[src]
     out = np.asarray(gmm.grouped_matmul(laid, jnp.asarray(w), tile_group,
                                         used, tile))
     np.testing.assert_array_equal(
@@ -496,6 +496,90 @@ def test_grouped_matmul_kernel_equals_the_row_by_row_product(interpret):
                                        atol=1e-3)
         else:
             assert int(dest[i]) == cap
+
+
+def _layout_case(name):
+    """(group (A,), groups, tile): `groups` in `group` is a row not taken."""
+    rng = np.random.default_rng(15)
+    groups, tile, rows = 5, 8, 64
+    if name == "an_empty_group":
+        group = rng.integers(0, groups + 1, rows)
+        group[group == 3] = 1
+    elif name == "whole_tiles_exactly":
+        group = np.repeat([0, 2, groups, 4], 16)        # two tiles each
+        rng.shuffle(group)
+    elif name == "no_row_taken":
+        group = np.full(rows, groups)
+    elif name == "one_group_takes_every_row":
+        group = np.full(rows, 2)
+    elif name == "valid_rows_left_out":                  # as the layer does
+        idx = rng.integers(0, 16, (16, 4))              # 16 tokens, top-4
+        mine = (idx >= 4) & (idx < 4 + groups) & (np.arange(16) < 11)[:, None]
+        group = np.where(mine, idx - 4, groups).reshape(-1)
+    else:
+        group = rng.integers(0, groups + 1, rows)
+    return np.asarray(group, np.int32), groups, tile
+
+
+@pytest.mark.parametrize("case", [
+    "random", "an_empty_group", "whole_tiles_exactly", "no_row_taken",
+    "one_group_takes_every_row", "valid_rows_left_out"])
+def test_the_layouts_inverse_map_names_the_row_that_lies_in_each_row(case):
+    group, groups, tile = _layout_case(case)
+    dest, tile_group, used, counts, src, live = map(
+        np.asarray, gmm.layout(jnp.asarray(group), groups, tile))
+    cap = gmm.rows_capacity(len(group), groups, tile)
+    assert src.shape == live.shape == (cap,) and src.dtype == np.int32
+    at = np.flatnonzero(live)
+    # a live row holds the row of `group` whose place it is, of the tile's
+    # group, in a used tile; rows of a group keep the order they came in
+    np.testing.assert_array_equal(dest[src[at]], at)
+    np.testing.assert_array_equal(group[src[at]], tile_group[at // tile])
+    assert (at // tile < used).all()
+    # and every row taken is some live row's: the two maps are inverses
+    np.testing.assert_array_equal(np.sort(src[at]),
+                                  np.flatnonzero(dest < cap))
+    assert len(at) == int(counts.sum()) == int((group < groups).sum())
+    # a row where none lies names SOME row, so that a gather stays in range
+    assert ((src >= 0) & (src < len(group))).all()
+    for g in range(groups):
+        mine = src[at][group[src[at]] == g]
+        np.testing.assert_array_equal(mine, np.sort(mine))
+
+
+def _scatters(jaxpr):
+    """Every scatter of a jaxpr and of the jaxprs its equations hold:
+    (primitive name, the shape of the updates)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("scatter"):
+            found.append((eqn.primitive.name, eqn.invars[2].aval.shape))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _scatters(sub)
+    return found
+
+
+def test_the_expert_layer_scatters_no_row_of_its_input():
+    """The tiles' rows are GATHERED through the layout's inverse map: the
+    only scatters left move int32 (the counts, a row's rank, the inverse
+    map itself)."""
+    spec = spec_of(held=(4, 4))
+    w = seeded(dlm.MoELayer(spec), 16).weights()
+    x = jnp.zeros((37, 64), jnp.float32)
+    valid = jnp.arange(37) < 30
+    found = _scatters(jax.make_jaxpr(
+        lambda w, x, valid: dlm.moe_forward(w, spec, x, valid))(
+            w, x, valid).jaxpr)
+    assert found, "the walk sees layout's int32 scatters"
+    assert all(shape in ((), (37 * 4,)) for _, shape in found), found
+    # and the walk would have seen the scatter of rows
+    rows = jax.make_jaxpr(lambda x, dest: jax.jit(
+        lambda x, dest: jnp.zeros((200, 64)).at[dest].set(x, mode="drop"))(
+            x, dest))(x, jnp.zeros((37,), jnp.int32)).jaxpr
+    assert _scatters(rows) == [("scatter", (37, 64))]
 
 
 # ------------------------------------------- grouped-KV paged attention

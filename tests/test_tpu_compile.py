@@ -376,6 +376,31 @@ def test_grouped_matmul_compiles(chip_compile, monkeypatch, k, n):
     assert kernel_calls(text, ("mxtpu_gmm",)) == {"mxtpu_gmm": 1}
 
 
+def test_expert_dispatch_gathers_its_rows_at_the_latent_servers_shape(
+        chip_compile):
+    """`mx_moe_dispatch` at pangu_ultra_ep16's decode shape (256 slots x
+    top-8 pairs, rows of 7680, 16 experts held of 256): the tiles' rows
+    come from a gather; no scatter has an operand of their size."""
+    import re
+    from mxnet_tpu.models import decoder_lm as dlm
+    from mxnet_tpu.ops import grouped_matmul as gmm
+    spec = dlm.LMSpec(hidden=7680, heads=8, kv_heads=2, head_dim=16,
+                      kda_heads=4, kda_head_dim=16, conv_kernel=4,
+                      num_experts=256, top_k=8, expert_width=2048,
+                      held_lo=16, held_n=16, scaling=2.5, eps=1e-5,
+                      pattern=("gqa",))
+    cap = gmm.rows_capacity(256 * 8, 16, 32)
+    text = chip_compile(
+        lambda x, idx, valid: dlm.mx_moe_dispatch(x, idx, valid, spec=spec,
+                                                  tile=32),
+        ((256, 7680), BF16), ((256, 8), I32), ((256,), jnp.bool_))
+    assert re.search(rf"bf16\[{cap},7680\]", text)      # the rows are made
+    wide = [line for line in text.splitlines() if "scatter(" in line
+            and re.search(rf"\[{cap},7680\]", line)]
+    assert not wide, wide
+    assert "scatter(" in text       # layout's int32 scatters are seen
+
+
 def test_kda_step_compiles(chip_compile):
     from mxnet_tpu.ops import kda
     vec = ((16, 64, 128), F32)
