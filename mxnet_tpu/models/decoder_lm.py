@@ -1,11 +1,15 @@
-"""A decoder-only language model assembled from a layer pattern. Four
-mixers: softmax attention with grouped KV heads and no positional term
-(`"gqa"`, its output gated or not), Kimi Delta Attention linear-attention
-layers (`"kda"`), multi-head latent attention with a rotary term
-(`"mla"`) and Mamba-2 state-space layers (`"mamba"`). Two feed-forwards:
-a mixture of experts that is told which experts it holds (`"moe"`:
-SwiGLU experts, or ungated relu^2 experts with a shared expert of its own
-width) and a dense SwiGLU (`"dense"`).
+"""A decoder-only language model assembled from a layer pattern. Five
+mixers: softmax attention with grouped KV heads over every position
+(`"gqa"`, its output gated or not) and the same over a sliding window of
+the last `window` positions (`"swa"`), both without a positional term or,
+with `LMSpec.attn_rope`, with q and k rotated over the whole head (plain
+RoPE on window layers, YaRN-scaled on full layers where `rope_yarn` is
+given); Kimi Delta Attention linear-attention layers (`"kda"`),
+multi-head latent attention with a rotary term (`"mla"`) and Mamba-2
+state-space layers (`"mamba"`). Two feed-forwards: a mixture of experts
+that is told which experts it holds (`"moe"`: SwiGLU experts, or ungated
+relu^2 experts; scored by sigmoid or softmax; with a shared expert of its
+own width or without one) and a dense SwiGLU (`"dense"`).
 
 A layer is a pre-norm residual PAIR (mixer, then feed-forward) with
 RMSNorm, or with `LMSpec.paired` false ONE sub-layer, `x + f(N(x))`,
@@ -28,6 +32,7 @@ exchange that a multi-chip deployment adds around this is not here
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import NamedTuple
 
@@ -44,11 +49,13 @@ from ..ops import ssd as ssd_ops
 from ..ops.nn_ops import dense_nt as _mm, rms_norm, swiglu
 from ..ops.pallas_kernels import flash_attention
 
-__all__ = ["LMSpec", "DecoderLM", "GatedAttention", "KDALayer",
-           "LatentAttention", "Mamba2Layer", "DenseFFN", "MoELayer",
-           "lm_weights", "moe_forward", "moe_route", "rope", "mla_sequence",
-           "mamba_sequence", "mx_gqa", "mx_kda", "mx_mla", "mx_mamba",
-           "mx_moe", "mx_ffn", "mx_gqa_seq", "mx_kda_seq", "mx_mla_seq",
+__all__ = ["LMSpec", "DecoderLM", "GatedAttention", "WindowAttention",
+           "KDALayer", "LatentAttention", "Mamba2Layer", "DenseFFN",
+           "MoELayer", "lm_weights", "moe_forward", "moe_route", "rope",
+           "attn_rope_table", "ring_pages_for", "mla_sequence",
+           "mamba_sequence", "mx_gqa", "mx_swa", "mx_kda", "mx_mla",
+           "mx_mamba", "mx_moe", "mx_ffn", "mx_gqa_seq", "mx_swa_seq",
+           "mx_kda_seq", "mx_mla_seq",
            "mx_mamba_seq", "mx_moe_route", "mx_moe_dispatch",
            "mx_moe_experts", "mx_moe_combine", "mx_moe_shared"]
 
@@ -73,8 +80,9 @@ class LMSpec(NamedTuple):
     held_n: int
     scaling: float
     eps: float
-    pattern: tuple            # a layer's mixer: "gqa" | "kda" | "mla" |
-    #                           "mamba"; not `paired`, also "moe" | "dense"
+    pattern: tuple            # a layer's mixer: "gqa" | "swa" | "kda" |
+    #                           "mla" | "mamba"; not `paired`, also "moe" |
+    #                           "dense"
     # what only an "mla" layer, a dense layer or a sandwich block reads
     q_rank: int = 0           # the query's latent
     kv_rank: int = 0          # the cached latent c_kv: key AND value
@@ -96,6 +104,19 @@ class LMSpec(NamedTuple):
     ssm_state: int = 0        # B and C's size, the state's second axis
     ssm_groups: int = 0       # B and C are shared by heads / groups heads
     ssm_chunk: int = 128      # positions a step of the chunked scan
+    # what only a "swa" layer reads: the keys a query attends, t - window
+    # < j <= t
+    window: int = 0
+    # the positional term of "gqa" and "swa" layers: q and k rotated over
+    # the whole head at `rope_theta`; on "gqa" (full) layers YaRN-scaled
+    # where `rope_yarn` = (factor, original_max_position_embeddings,
+    # beta_fast, beta_slow, attention_factor)
+    attn_rope: bool = False
+    rope_yarn: tuple = ()
+    # the expert layer's router: "sigmoid" | "softmax" over all experts;
+    # and whether a shared expert is added to the routed sum
+    router_score: str = "sigmoid"
+    shared_expert: bool = True
 
     def ffn_kinds(self):
         return self.ffn or ("moe",) * len(self.pattern)
@@ -124,11 +145,14 @@ def relu2(x):
 
 
 def moe_route(w, spec, x):
-    """(expert ids (T, k) int32, weights (T, k) float32): sigmoid scores
-    over ALL experts, the k largest of score + selection bias (where the
+    """(expert ids (T, k) int32, weights (T, k) float32): scores over ALL
+    experts in float32, a sigmoid each or (`router_score` "softmax") one
+    softmax over them; the k largest of score + selection bias (where the
     spec has one) chosen, the chosen scores normalised to sum to one,
     times the scaling factor."""
-    s = jax.nn.sigmoid(jax.lax.dot_general(
+    score = jax.nn.softmax if spec.router_score == "softmax" \
+        else jax.nn.sigmoid
+    s = score(jax.lax.dot_general(
         x, w["router_weight"], (((1,), (1,)), ((), ())),
         preferred_element_type=_F32))
     ranked = s + w["router_bias"].astype(_F32) if spec.router_bias else s
@@ -140,7 +164,8 @@ def moe_route(w, spec, x):
 
 def moe_forward(w, spec, x, valid=None, tile=32):
     """The expert layer for tokens x (T, d): the held experts' terms of
-    the routed sum plus the shared expert. `valid` (T,) bool leaves rows
+    the routed sum plus the shared expert (where the spec has one).
+    `valid` (T,) bool leaves rows
     out of the routed part (padding, empty slots). Returns (y (T, d), the
     rows each held expert took (held_n,) int32, the expert ids every row
     chose (T, k) int32). No capacity: every routed row that names a held
@@ -153,8 +178,9 @@ def moe_forward(w, spec, x, valid=None, tile=32):
         x, idx, valid, spec=spec, tile=tile)
     out = mx_moe_experts(w, rows, tile_group, used, spec=spec, tile=tile)
     routed = mx_moe_combine(out, dest, mine, wts)
-    shared = mx_moe_shared(w, x, spec=spec)
-    return (routed + shared.astype(_F32)).astype(x.dtype), counts, idx
+    if spec.shared_expert:
+        routed = routed + mx_moe_shared(w, x, spec=spec).astype(_F32)
+    return routed.astype(x.dtype), counts, idx
 
 
 # `moe_forward`'s parts, each a named device-time scope (a jitted function
@@ -220,9 +246,60 @@ def mx_moe_shared(w, x, spec):
 
 
 # ---------------------------------------------- gated grouped attention
-def gqa_project(w, spec, x):
+def rotate(x, pos, inv, m=1.0):
+    """Rotate the last axis of x (T, r) or (T, H, r) by position pos
+    (T,): value i pairs with value i + r/2 (the half-split convention),
+    the pair turning by pos * inv[i], cos and sin times `m`. Float32
+    arithmetic."""
+    half = x.shape[-1] // 2
+    ang = pos.astype(_F32)[:, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if m != 1.0:
+        cos, sin = cos * m, sin * m
+    if x.ndim == 3:
+        cos, sin = cos[:, None], sin[:, None]
+    a, b = x[..., :half].astype(_F32), x[..., half:].astype(_F32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           -1).astype(x.dtype)
+
+
+def yarn_range(spec):
+    """(low, high): the pairs YaRN's ramp runs between, from the turns a
+    pair makes over the original context (`beta_fast` at `low`,
+    `beta_slow` at `high`), truncated outwards and kept inside the head."""
+    _, orig, fast, slow, _ = spec.rope_yarn
+
+    def pair_of(turns):
+        return spec.head_dim * math.log(orig / (2 * math.pi * turns)) \
+            / (2 * math.log(spec.rope_theta))
+
+    low = max(math.floor(pair_of(fast)), 0)
+    high = min(math.ceil(pair_of(slow)), spec.head_dim - 1)
+    return low, high + (0.001 if low == high else 0)
+
+
+def attn_rope_table(spec, kind):
+    """(inv (dh / 2,) float32, m): the turn a position of pair i and the
+    factor on cos and sin, for a "swa" layer (plain RoPE at `rope_theta`,
+    m 1) or a "gqa" layer (the same, or with `rope_yarn` YaRN: pairs
+    under `low` keep their frequency, pairs over `high` turn `factor`
+    times slower, a linear ramp between; m the attention factor)."""
+    half = spec.head_dim // 2
+    inv = spec.rope_theta ** (-jnp.arange(half, dtype=_F32) / half)
+    if kind == "swa" or not spec.rope_yarn:
+        return inv, 1.0
+    low, high = yarn_range(spec)
+    ramp = jnp.clip((jnp.arange(half, dtype=_F32) - low) / (high - low),
+                    0.0, 1.0)
+    return (inv * (1.0 - ramp) + inv / spec.rope_yarn[0] * ramp,
+            float(spec.rope_yarn[4]))
+
+
+def gqa_project(w, spec, x, pos=None, kind="gqa"):
     """x (T, d) -> q (T, H, dh), output gate (T, H * dh) or None where
-    the spec has none, k, v (T, Hkv, dh). No positional term."""
+    the spec has none, k, v (T, Hkv, dh). With `spec.attn_rope` q and k
+    are rotated to their positions pos (T,) by the table of the layer's
+    `kind` (`attn_rope_table`); else no positional term."""
     h, hk, dh = spec.heads, spec.kv_heads, spec.head_dim
     if spec.attn_gate:
         q, gate, k, v = jnp.split(
@@ -233,8 +310,11 @@ def gqa_project(w, spec, x):
         q, k, v = jnp.split(_mm(x, w["qkv_weight"]),
                             [h * dh, (h + hk) * dh], -1)
     t = x.shape[0]
-    return (q.reshape(t, h, dh), gate, k.reshape(t, hk, dh),
-            v.reshape(t, hk, dh))
+    q, k = q.reshape(t, h, dh), k.reshape(t, hk, dh)
+    if spec.attn_rope:
+        inv, m = attn_rope_table(spec, kind)
+        q, k = rotate(q, pos, inv, m), rotate(k, pos, inv, m)
+    return q, gate, k, v.reshape(t, hk, dh)
 
 
 def gqa_output(w, attn, gate):
@@ -246,14 +326,23 @@ def gqa_output(w, attn, gate):
     return _mm(a, w["o_weight"])
 
 
-def gqa_sequence(w, spec, x):
-    """Causal attention over one sequence x (T, d), any T (the flash
-    kernel where T allows, else plain attention). Returns (y, k, v)."""
-    q, gate, k, v = gqa_project(w, spec, x)
+def gqa_sequence(w, spec, x, kind="gqa"):
+    """Causal attention over one sequence x (T, d) from position 0, any
+    T (the flash kernel where T allows, else plain attention); a "swa"
+    layer attends the last `spec.window` keys only. Returns (y, k, v),
+    k as a cache keeps it (rotated where the spec says so)."""
+    q, gate, k, v = gqa_project(w, spec, x, jnp.arange(x.shape[0]), kind)
     rep = spec.heads // spec.kv_heads
     kk, vv = (jnp.repeat(a, rep, 1).transpose(1, 0, 2)[None] for a in (k, v))
-    a = flash_attention(q.transpose(1, 0, 2)[None], kk, vv, causal=True)
+    a = flash_attention(q.transpose(1, 0, 2)[None], kk, vv, causal=True,
+                        window=spec.window if kind == "swa" else None)
     return gqa_output(w, a[0].transpose(1, 0, 2), gate), k, v
+
+
+def ring_pages_for(window, psize):
+    """Pages of a "swa" layer's per-slot ring: the window's own and one
+    more, because a prefill writes whole pages (`ring_paged_attention`)."""
+    return -(-window // psize) + 1
 
 
 # ------------------------------------------------------------------ KDA
@@ -365,18 +454,10 @@ def mamba_sequence(w, spec, x, valid=None):
 
 # ----------------------------------------------- latent attention (MLA)
 def rope(x, pos, theta):
-    """Rotate the last axis of x (T, r) or (T, H, r) by position pos
-    (T,): value i pairs with value i + r/2 (the half-split convention),
-    the pair turning by pos * theta^(-2i / r). Float32 arithmetic."""
+    """`rotate` with the plain table: the pair i turning by
+    pos * theta^(-2i / r)."""
     half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=_F32) / half)
-    ang = pos.astype(_F32)[:, None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    if x.ndim == 3:
-        cos, sin = cos[:, None], sin[:, None]
-    a, b = x[..., :half].astype(_F32), x[..., half:].astype(_F32)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           -1).astype(x.dtype)
+    return rotate(x, pos, theta ** (-jnp.arange(half, dtype=_F32) / half))
 
 
 def mla_project(w, spec, x, pos):
@@ -453,11 +534,32 @@ def mx_gqa(w, x, k_pages, v_pages, page_tables, lens, page, off, spec):
     """One decode position a slot through the paged KV of one layer.
     k_pages/v_pages: (P, psize, Hkv * dh)."""
     from ..ops.pallas_kernels import ragged_paged_attention
-    q, gate, k, v = gqa_project(w, spec, x)
+    q, gate, k, v = gqa_project(w, spec, x, lens)
     k_pages = k_pages.at[page, off].set(k.reshape(k.shape[0], -1))
     v_pages = v_pages.at[page, off].set(v.reshape(v.shape[0], -1))
     a = ragged_paged_attention(q, k_pages, v_pages, page_tables, lens + 1)
     return gqa_output(w, a, gate), k_pages, v_pages
+
+
+@partial(jax.jit, static_argnames=("spec",))
+def mx_swa(w, x, k_ring, v_ring, lens, valid, spec):
+    """One decode position a slot through a sliding-window layer's RING.
+    k_ring/v_ring: (S * R, psize, Hkv * dh), slot s owning pages s * R ..
+    s * R + R - 1; the position (`lens`, the slot's length) is written at
+    ring page (lens // psize) % R, row lens % psize, an empty slot's
+    (`valid` false) nowhere, then the window's keys are attended
+    (`ring_paged_attention`)."""
+    from ..ops.pallas_kernels import ring_paged_attention
+    q, gate, k, v = gqa_project(w, spec, x, lens, "swa")
+    s, (total, psize, _) = x.shape[0], k_ring.shape
+    ring = ring_pages_for(spec.window, psize)
+    page = jnp.where(valid, jnp.arange(s) * ring + lens // psize % ring,
+                     total)
+    off = lens % psize
+    k_ring = k_ring.at[page, off].set(k.reshape(s, -1), mode="drop")
+    v_ring = v_ring.at[page, off].set(v.reshape(s, -1), mode="drop")
+    a = ring_paged_attention(q, k_ring, v_ring, lens + 1, spec.window)
+    return gqa_output(w, a, gate), k_ring, v_ring
 
 
 @partial(jax.jit, static_argnames=("spec",))
@@ -518,6 +620,31 @@ def mx_gqa_seq(w, x, spec):
 
 
 @partial(jax.jit, static_argnames=("spec",))
+def mx_swa_seq(w, x, k_ring, v_ring, slot, n, spec):
+    """A prompt's first `n` positions (x (T, d), whole pages, padded)
+    through a sliding-window layer, and their K and V into slot `slot`'s
+    ring: ring page r takes the newest of the prompt's pages that is
+    congruent to it (one gather of the ring's R pages, written as one
+    block: the ring is the slot's own). A ring page for which the prompt
+    has no page yet, and the rows past the prompt's end, hold what they
+    held or padding: a query masks them by position. Returns (y, k_ring,
+    v_ring)."""
+    y, k, v = gqa_sequence(w, spec, x, "swa")
+    psize = k_ring.shape[1]
+    ring = ring_pages_for(spec.window, psize)
+    last = jnp.maximum(n - 1, 0) // psize       # the page of position n-1
+    r = jnp.arange(ring)
+    src = jnp.maximum(last - (last - r) % ring, 0)
+
+    def write(pool, a):
+        pages = a.reshape(-1, psize, pool.shape[-1])[src]
+        return jax.lax.dynamic_update_slice(pool, pages,
+                                            (slot * ring, 0, 0))
+
+    return y, write(k_ring, k), write(v_ring, v)
+
+
+@partial(jax.jit, static_argnames=("spec",))
 def mx_kda_seq(w, x, valid, spec):
     return kda_sequence(w, spec, x, valid)
 
@@ -568,9 +695,12 @@ def _scan_batch(sequence, w, spec, x, chunk):
 
 class GatedAttention(_PureBlock):
     """Causal softmax attention, `kv_heads` KV heads under `heads` query
-    heads, no positional term, output gated by sigmoid(W_gate x) unless
-    the spec says `attn_gate` false (`qkv_weight` then, without the
-    gate's rows)."""
+    heads, q and k rotated where the spec says `attn_rope` (else no
+    positional term), output gated by sigmoid(W_gate x) unless the spec
+    says `attn_gate` false (`qkv_weight` then, without the gate's
+    rows)."""
+
+    _kind = "gqa"
 
     def __init__(self, spec, **kwargs):
         super().__init__(**kwargs)
@@ -588,7 +718,21 @@ class GatedAttention(_PureBlock):
             self.o_weight = self.params.get("o_weight", shape=(d, h * dh))
 
     def _pure(self, w, x):
-        return jax.vmap(lambda s: gqa_sequence(w, self._spec, s)[0])(x)
+        return jax.vmap(
+            lambda s: gqa_sequence(w, self._spec, s, self._kind)[0])(x)
+
+
+class WindowAttention(GatedAttention):
+    """`GatedAttention` over a sliding window: a query attends the last
+    `spec.window` keys, itself among them; where the spec rotates, by the
+    plain table whatever the full layers scale."""
+
+    _kind = "swa"
+
+    def __init__(self, spec, **kwargs):
+        if spec.window < 1:
+            raise MXNetError("a 'swa' layer needs window >= 1")
+        super().__init__(spec, **kwargs)
 
 
 class KDALayer(_PureBlock):
@@ -684,12 +828,14 @@ class DenseFFN(nn.SwiGLU):
 
 
 class MoELayer(_PureBlock):
-    """Sigmoid-scored top-k routing over `num_experts` experts (SwiGLU,
-    or with `expert_act` "relu2" ungated, W_down relu(W_up u)^2) of
-    which this block HOLDS experts held_lo .. held_lo + held_n - 1, plus
-    one shared expert of `shared_width`. Dropless. With all experts held it is the whole
-    layer; with a share it is one chip's part of an expert-parallel
-    layer, without the exchange."""
+    """Top-k routing, scored by a sigmoid an expert or one softmax over
+    them (`router_score`), over `num_experts` experts (SwiGLU, or with
+    `expert_act` "relu2" ungated, W_down relu(W_up u)^2) of which this
+    block HOLDS experts held_lo .. held_lo + held_n - 1, plus one shared
+    expert of `shared_width` unless the spec says `shared_expert` false.
+    Dropless. With all experts held it is the whole layer; with a share
+    it is one chip's part of an expert-parallel layer, without the
+    exchange."""
 
     def __init__(self, spec, **kwargs):
         super().__init__(**kwargs)
@@ -701,6 +847,9 @@ class MoELayer(_PureBlock):
         if spec.expert_act not in ("swiglu", "relu2"):
             raise MXNetError(f"expert_act {spec.expert_act!r}: 'swiglu' "
                              f"or 'relu2'")
+        if spec.router_score not in ("sigmoid", "softmax"):
+            raise MXNetError(f"router_score {spec.router_score!r}: "
+                             f"'sigmoid' or 'softmax'")
         up, shared_up = (
             (("experts_up", (n, wd, d)), ("shared_up", (ws, d)))
             if spec.expert_act == "relu2" else
@@ -712,7 +861,8 @@ class MoELayer(_PureBlock):
                     *([("router_bias", (spec.num_experts,))]
                       if spec.router_bias else []),
                     up, ("experts_down", (n, wd, d)),
-                    shared_up, ("shared_down", (d, ws))):
+                    *([shared_up, ("shared_down", (d, ws))]
+                      if spec.shared_expert else [])):
                 setattr(self, name, self.params.get(name, shape=shape))
 
     def _pure(self, w, x):
@@ -721,8 +871,8 @@ class MoELayer(_PureBlock):
             .reshape(b, t, d)
 
 
-_MIXERS = {"gqa": GatedAttention, "kda": KDALayer, "mla": LatentAttention,
-           "mamba": Mamba2Layer}
+_MIXERS = {"gqa": GatedAttention, "swa": WindowAttention, "kda": KDALayer,
+           "mla": LatentAttention, "mamba": Mamba2Layer}
 
 
 class DecoderBlock(HybridBlock):

@@ -43,7 +43,8 @@ from ..tune import overrides as _tune_overrides
 __all__ = ["flash_attention", "flash_block_attention", "fused_layer_norm",
            "attention_reference", "on_tpu",
            "single_query_cached_attention", "ragged_paged_attention",
-           "latent_paged_attention", "pool_lanes",
+           "latent_paged_attention", "ring_paged_attention",
+           "ring_rows_back", "pool_lanes",
            "kernel_mesh"]
 
 
@@ -243,10 +244,13 @@ def attention_reference(q, k, v, causal=False, sm_scale=None, mask=None):
 # Pallas flash attention forward
 # ---------------------------------------------------------------------------
 def _flash_fwd_kernel(*refs, sm_scale, causal, block_q, block_k,
-                      num_heads, has_lengths):
+                      num_heads, has_lengths, window=None):
     """has_lengths: a scalar-prefetch (B,) int32 `kv_lengths` ref leads the
     arg list; key positions >= kv_lengths[b] are masked (padding mask) and
-    fully-masked kv blocks are skipped dynamically."""
+    fully-masked kv blocks are skipped dynamically. window (with causal):
+    query t sees keys t - window < j <= t, and the kv blocks that lie
+    wholly behind a q block's window are skipped as those above the
+    diagonal are."""
     if has_lengths:
         (vl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
          m_scr, l_scr, acc_scr) = refs
@@ -279,6 +283,8 @@ def _flash_fwd_kernel(*refs, sm_scale, causal, block_q, block_k,
             qi = q_start + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             kj = k_start + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(qi >= kj, s, -1e30)
+            if window is not None:
+                s = jnp.where(qi - kj < window, s, -1e30)
         if has_lengths:
             kj = k_start + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(kj < vl, s, -1e30)
@@ -300,6 +306,10 @@ def _flash_fwd_kernel(*refs, sm_scale, causal, block_q, block_k,
     live = True
     if causal:
         live = k_start <= q_start + block_q - 1
+        if window is not None:
+            # the block's last key within the window of its first query
+            live = jnp.logical_and(
+                live, k_start + block_k - 1 > q_start - window)
     if has_lengths:
         live = jnp.logical_and(live, k_start < vl) if causal \
             else k_start < vl
@@ -321,22 +331,23 @@ def _flash_fwd_kernel(*refs, sm_scale, causal, block_q, block_k,
         lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
 
 
-def _flash_fwd_pallas(q, k, v, causal, sm_scale, lengths=None):
+def _flash_fwd_pallas(q, k, v, causal, sm_scale, lengths=None, window=None):
     """Returns (out, lse); lse is the per-row logsumexp of the scaled
     logits, (B, H, Sq) fp32 — the backward kernels' softmax residual.
     lengths: optional (B,) int32 kv valid lengths (padding mask).
-    Sq and Sk may differ (cross-attention); causal requires Sq == Sk."""
+    Sq and Sk may differ (cross-attention); causal requires Sq == Sk.
+    window: the sliding window of a causal call (`flash_attention`)."""
     block_q, block_k = _block_sizes(q.shape[2], k.shape[2])
     local = functools.partial(_flash_fwd_local, causal=causal,
                               sm_scale=sm_scale, block_q=block_q,
-                              block_k=block_k)
+                              block_k=block_k, window=window)
     if lengths is None:
         return _over_mesh(local, (q, k, v), (2, 2, 2), (2, 2))
     return _over_mesh(local, (q, k, v, lengths), (2, 2, 2, 1), (2, 2))
 
 
 def _flash_fwd_local(q, k, v, lengths=None, *, causal, sm_scale, block_q,
-                     block_k):
+                     block_k, window=None):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bh = b * h
@@ -348,14 +359,28 @@ def _flash_fwd_local(q, k, v, lengths=None, *, causal, sm_scale, block_q,
     kern = functools.partial(
         _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, num_heads=h,
-        has_lengths=has_lengths)
+        has_lengths=has_lengths, window=window)
+
+    if window is None:
+        def kv_at(bh_, i, j, *_):
+            return (bh_, j, 0)
+    else:
+        def kv_at(bh_, i, j, *_):
+            # a skipped step names the nearest live block of its q block:
+            # the pipeline fetches a block once while its index stands, so
+            # what the kernel does not compute is not fetched either
+            first = lax.div(jnp.maximum(i * block_q - (window - 1), 0),
+                            jnp.int32(block_k))
+            last = lax.div(i * block_q + (block_q - 1), jnp.int32(block_k))
+            return (bh_, jnp.clip(j, first, last), 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1 if has_lengths else 0,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh_, i, j, *_: (bh_, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, i, j, *_: (bh_, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, i, j, *_: (bh_, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_at),
+            pl.BlockSpec((1, block_k, d), kv_at),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh_, i, j, *_: (bh_, i, 0)),
@@ -388,7 +413,8 @@ def _flash_fwd_local(q, k, v, lengths=None, *, causal, sm_scale, block_q,
     return out.reshape(b, h, sq, d), lse[..., 0].reshape(b, h, sq)
 
 
-def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lengths=None):
+def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lengths=None,
+                    window=None):
     """Fused attention. q,k,v: (B, H, S, D) -> (B, H, S, D).
 
     On TPU with S % 128 == 0 runs the Pallas flash kernel (O(S) memory,
@@ -397,10 +423,32 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lengths=None):
     kv_lengths: optional (B,) int32 per-sequence valid key length (the
     reference's padding mask expressed TPU-natively — key positions
     >= kv_lengths[b] are masked, and fully-masked kv blocks are skipped
-    inside the kernel via scalar prefetch)."""
+    inside the kernel via scalar prefetch).
+
+    window: optional int, with `causal` and without `kv_lengths`: query t
+    attends the `window` keys t - window < j <= t (sliding-window
+    attention; the current key is one of them). The kernel neither
+    computes nor fetches the key blocks that lie wholly behind a query
+    block's window. Forward only on the kernel path (the serving
+    prefill); the XLA path differentiates as any masked attention."""
+    if window is not None:
+        if not causal or kv_lengths is not None:
+            raise ValueError("window goes with causal=True and without "
+                             "kv_lengths")
+        return _flash_window(q, k, v, sm_scale, int(window))
     if kv_lengths is None:
         return _flash_plain(q, k, v, causal, sm_scale)
     return _flash_vl(q, k, v, kv_lengths, causal, sm_scale)
+
+
+def _flash_window(q, k, v, sm_scale, window):
+    if sm_scale is None:
+        sm_scale = 1.0 / (q.shape[-1] ** 0.5)
+    if _pallas_ok(q.shape[2]) and _pallas_ok(k.shape[2]):
+        return _flash_fwd_pallas(q, k, v, True, sm_scale, window=window)[0]
+    t = jnp.arange(q.shape[2])
+    return attention_reference(q, k, v, causal=True, sm_scale=sm_scale,
+                               mask=t[:, None] - t[None, :] < window)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -1132,8 +1180,12 @@ def _rpa_flat_kernel(*refs, psize, pps, kv_heads, rows, window, group,
     query head of its group), position-major, so `group` rows share one
     position's mask and a KV tile is read once for the whole group (8
     query heads a KV head fill the 8-sublane tile that a lone query row
-    pads). On the device it is `mxtpu_rpa_flat`, a kernel of its own
-    beside `mxtpu_rpa`."""
+    pads). `window` counts QUERY rows a slot (the widened verify form;
+    1 in a decode turn), not keys: every key up to the slot's length is
+    attended. The span of KEYS a sliding-window layer's query reads is
+    `_rpa_ring_kernel`'s `span`, over a ring and not these pools. On the
+    device it is `mxtpu_rpa_flat`, a kernel of its own beside
+    `mxtpu_rpa`."""
     pt_ref, len_ref, q_ref = refs[:3]
     k_refs, v_refs = refs[3:3 + pps], refs[3 + pps:3 + 2 * pps]
     o_ref, m_scr, l_scr, acc_scr = refs[3 + 2 * pps:]
@@ -1226,6 +1278,139 @@ def _rpa_flat_pallas(q, k_pages, v_pages, page_tables, lengths, sm_scale):
     return out.reshape(S, H, rows, dh)[:, :, :R] \
         .reshape(S, H, W, G, dh).transpose(0, 2, 1, 3, 4) \
         .reshape(S, W, Hq, dh)
+
+
+# ---------------------------------------------------------------------------
+# ring paged attention (sliding-window decode)
+# ---------------------------------------------------------------------------
+def ring_rows_back(lengths, n):
+    """(S, n) int32: how many positions behind the slot's current one
+    (`lengths - 1`) the position that ring row r last took lies. Position
+    p is kept at ring row p % n (page (p // psize) % R, row p % psize, n =
+    R * psize), so row r holds the newest position <= the current one
+    that is congruent to it; a row never written reads further back than
+    the current position itself."""
+    base = (lengths - 1) % n
+    d = base[:, None] - jnp.arange(n, dtype=jnp.int32)[None, :]
+    return jnp.where(d < 0, d + n, d)
+
+
+def _ring_attention_lax(q, k_ring, v_ring, lengths, span, sm_scale):
+    """Pure-lax form of `ring_paged_attention`: the slot's whole ring as
+    a dense context, masked by the position each row holds."""
+    S, Hq, dh = q.shape
+    n = k_ring.shape[1]
+    H = k_ring.shape[2] // dh
+    back = ring_rows_back(lengths, n)
+    keep = (back < span) & (back < lengths[:, None])
+    qg = q.reshape(S, H, Hq // H, dh)
+    kc, vc = (r.reshape(S, n, H, dh) for r in (k_ring, v_ring))
+    s = jnp.einsum("shgd,snhd->shgn", qg, kc,
+                   preferred_element_type=jnp.float32) * sm_scale
+    p = jax.nn.softmax(jnp.where(keep[:, None, None, :], s, -1e30), -1)
+    out = jnp.einsum("shgn,snhd->shgd", p.astype(vc.dtype), vc)
+    return out.reshape(S, Hq, dh)
+
+
+def _rpa_ring_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, kv_heads, rows,
+                     span, sm_scale, dh):
+    """Sliding-window decode attention over a per-slot RING: one SLOT a
+    grid step with all its KV heads, its whole ring one block of K and
+    one of V (the ring is the slot's own contiguous pages, so no page
+    table and a block spec a pool, not a page). A row of the ring is
+    masked by the position it holds, which follows from the slot's length
+    alone (`ring_rows_back`): the `span` newest positions are attended,
+    whatever older lap or nothing a row still holds is not. The query
+    block stacks the KV heads' `rows` query rows as `_rpa_flat_kernel`'s
+    does (where `window` counts QUERY rows a slot; the span of KEYS a
+    query may read is `span` here). One softmax over the ring, no running
+    maximum: a step sees every key there is."""
+    n = k_ref.shape[1]
+    length = len_ref[pl.program_id(0)]
+    base = lax.rem(length - 1, jnp.int32(n))
+    back = base - lax.broadcasted_iota(jnp.int32, (rows, n), 1)
+    back = jnp.where(back < 0, back + n, back)
+    keep = (back < span) & (back < length)
+    for h in range(kv_heads):
+        r = slice(h * rows, (h + 1) * rows)
+        lanes = slice(h * dh, (h + 1) * dh)
+        s = jax.lax.dot_general(
+            q_ref[0, r, :], k_ref[0, :, lanes], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.where(keep, s, -1e30)
+        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        v = v_ref[0, :, lanes]
+        o = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        o_ref[0, r, :] = (o / jnp.sum(p, axis=-1, keepdims=True)).astype(
+            o_ref.dtype)
+
+
+def _rpa_ring_pallas(q, k_ring, v_ring, lengths, span, sm_scale):
+    """q: (S, Hq, dh); rings (S, n, H * dh); returns q's shape."""
+    S, Hq, dh = q.shape
+    n = k_ring.shape[1]
+    H = k_ring.shape[2] // dh
+    G = Hq // H
+    # whole sublane tiles a KV head: 8 rows of 4 bytes, 16 of 2
+    tile = 8 * max(1, 4 // q.dtype.itemsize)
+    rows = -(-G // tile) * tile
+    qr = q.reshape(S, H, G, dh)
+    if rows != G:
+        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - G), (0, 0)))
+    qr = qr.reshape(S, H * rows, dh)
+    block = pl.BlockSpec((1, H * rows, dh), lambda s, ln: (s, 0, 0))
+    ring = pl.BlockSpec((1, n, H * dh), lambda s, ln: (s, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_rpa_ring_kernel, kv_heads=H, rows=rows,
+                          span=span, sm_scale=sm_scale, dh=dh),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(S,),
+            in_specs=[block, ring, ring], out_specs=block),
+        out_shape=_sds((S, H * rows, dh), q.dtype, q, k_ring, v_ring),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=_interpret(),
+        name="mxtpu_rpa_ring",
+    )(lengths.astype(jnp.int32), qr, k_ring, v_ring)
+    return out.reshape(S, H, rows, dh)[:, :, :G].reshape(S, Hq, dh)
+
+
+def ring_paged_attention(q, k_pages, v_pages, lengths, window,
+                         sm_scale=None):
+    """Decode attention of a SLIDING-WINDOW layer, one launch a layer and
+    decode step, over a cache that is a ring a slot.
+
+    q: (S, Hq, dh) one query a slot; k_pages/v_pages: (S * R, psize,
+    H * dh), slot s owning pages s * R .. s * R + R - 1 for its life,
+    position p kept at its ring page (p // psize) % R, row p % psize
+    (whoever writes the cache keeps that rule: `models.decoder_lm.mx_swa`,
+    `serve.lm_runtime`'s prefill, which writes whole pages and so keeps
+    one page more than the window fills); R * psize >= window, so that
+    the `window` newest positions never share a row. lengths: (S,)
+    int32 positions the slot holds INCLUDING the current one, as
+    `ragged_paged_attention` takes them. The query at position
+    t = lengths - 1 attends positions t - window < j <= t: every ring row
+    is masked by the position it holds, computed from `lengths`, not
+    stored. Grouped heads: query head h reads KV head h // (Hq // H).
+    Returns (S, Hq, dh).
+
+    On the TPU (or MXTPU_PALLAS_INTERPRET=1), for heads of whole 128-lane
+    tiles, the Pallas kernel `mxtpu_rpa_ring`: a slot a grid step, its
+    ring one block. Elsewhere a lax form with the same numbers."""
+    S, _, dh = q.shape
+    psize = k_pages.shape[1]
+    R = k_pages.shape[0] // S
+    if R * psize < window:
+        raise ValueError(f"a ring of {R} pages of {psize} cannot hold a "
+                         f"window of {window}")
+    if sm_scale is None:
+        sm_scale = 1.0 / (dh ** 0.5)
+    rings = [p.reshape(S, R * psize, p.shape[-1]) for p in (k_pages, v_pages)]
+    form = (_rpa_ring_pallas if _rpa_pallas_ok(psize) and dh % 128 == 0
+            else _ring_attention_lax)
+    return form(q, *rings, lengths, int(window), float(sm_scale))
 
 
 def _rpa_pallas_ok(psize):

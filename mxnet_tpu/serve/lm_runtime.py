@@ -3,13 +3,22 @@ ONE cached prefill executable and ONE cached decode executable behind the
 same `Server`, `Scheduler`, `PagePool` and `EngineLoop` that serve
 `TransformerNMT` through `serve.decode.DecodeRuntime`.
 
-Four kinds of device state live side by side, each only where the
+Five kinds of device state live side by side, each only where the
 model's pattern has its layers, all donated to both executables so every
 write is in place:
 
   * paged KV for the softmax-attention ("gqa") layers: pools of `(P,
     psize, Hkv * dh)` a layer (the shape the decode kernel reads in
     place), reached through the scheduler's page tables;
+  * a RING a slot for the sliding-window ("swa") layers, which no page
+    table reaches and the page pool does not count: K and V pools of
+    `(slots * R, psize, Hkv * dh)` a layer, R = window / psize + 1
+    pages, slot s owning pages s * R .. s * R + R - 1 for its life and
+    position p kept at ring page (p // psize) % R, row p % psize. What a
+    row holds from a lap before is further back than the window, and a
+    query masks every row by the position it holds
+    (`ops.pallas_kernels.ring_paged_attention`): the cache of a window
+    layer is bounded by the window, whatever the context;
   * latent pages for the latent-attention ("mla") layers, through the
     SAME page tables: ONE pool `(P, psize, lanes)` a layer, a row a token
     holding `c_kv | k_rope` (kv_rank + rope_dim values, zeros up to whole
@@ -34,11 +43,13 @@ clearing when a request leaves it, and a requeued request is simply
 prefilled again. The last prompt token is the first decode turn's input, so every generated
 token, the first included, comes out of the decode executable.
 
-Recurrent state cannot be shared by page or rewound by dropping pages:
-`page_reuse_refusal` says so and the scheduler refuses the radix prefix
-cache and speculative decoding for this runtime until state snapshots
-exist (ROADMAP Queue 2 B); without recurrent layers it still refuses
-both, because prefill cannot yet start after adopted pages.
+Recurrent state cannot be shared by page or rewound by dropping pages,
+and a slot's ring is no page of the pool and has overwritten its oldest
+rows by the time a draft is rejected: `page_reuse_refusal` says which
+holds and the scheduler refuses the radix prefix cache and speculative
+decoding for this runtime until state snapshots exist (ROADMAP Queue 2
+B); without recurrent or window layers it still refuses both, because
+prefill cannot yet start after adopted pages.
 
 The weights are an ARGUMENT of both executables, not constants: at
 several GB they cannot be baked into a program.
@@ -66,7 +77,7 @@ from .kv_pages import NULL_PAGE
 __all__ = ["LMRuntime"]
 
 _CHUNK = 32        # positions a step of the chunked KDA scan
-_MIXERS = ("gqa", "kda", "mla", "mamba")
+_MIXERS = ("gqa", "swa", "kda", "mla", "mamba")
 # a recurrent mixer's per-slot state and convolution tails in `_state`,
 # and its sequence form
 _RECURRENT = {"kda": ("kda", "conv", lm.mx_kda_seq),
@@ -161,6 +172,9 @@ class LMRuntime:
         self._last_mixer = max(i for i, (m, _) in enumerate(subs) if m)
         self._in_prefill = self._is_moe * (np.arange(len(subs))
                                            < self._last_mixer)
+        # pages a slot's ring has in each sliding-window layer
+        self.ring = lm.ring_pages_for(spec.window, self.page_size) \
+            if self._n["swa"] else 0
         self.page_reuse_refusal = (
             "the model has recurrent (KDA or state-space) layers: a "
             "slot's state after a prefix is one array a layer that pages "
@@ -168,6 +182,11 @@ class LMRuntime:
             "rejected speculative drafts would leave it wrong; both wait "
             "for state snapshots"
             if self._n["kda"] or self._n["mamba"] else
+            "the model has sliding-window layers: their keys and values "
+            "lie in a ring a slot that the page pool does not hold, so "
+            "adopted pages would leave the ring empty, and a rejected "
+            "draft has already overwritten the ring's oldest rows"
+            if self._n["swa"] else
             "the decoder-only runtime prefills a whole prompt in one "
             "dispatch and decodes one token a turn: it cannot start after "
             "adopted pages and has no widened verify executable yet")
@@ -202,12 +221,17 @@ class LMRuntime:
         s, sp = self.slots, self.spec
         ssm_conv = sp.ssm_dims()[1]
         pool = (self.num_pages, self.page_size, sp.kv_heads * sp.head_dim)
+        ring = (s * self.ring, self.page_size, sp.kv_heads * sp.head_dim)
         latent = (self.num_pages, self.page_size,
                   pool_lanes(sp.kv_rank + sp.rope_dim))
         c = 3 * sp.kda_heads * sp.kda_head_dim
         self._state = {
             "k": [jnp.zeros(pool, self._dtype) for _ in range(self._n["gqa"])],
             "v": [jnp.zeros(pool, self._dtype) for _ in range(self._n["gqa"])],
+            "ring_k": [jnp.zeros(ring, self._dtype)
+                       for _ in range(self._n["swa"])],
+            "ring_v": [jnp.zeros(ring, self._dtype)
+                       for _ in range(self._n["swa"])],
             "lat": [jnp.zeros(latent, self._dtype)
                     for _ in range(self._n["mla"])],
             "kda": [jnp.zeros((s, sp.kda_heads, sp.kda_head_dim,
@@ -231,6 +255,8 @@ class LMRuntime:
                      "touched": np.zeros((layers,), np.int64),
                      "dispatches": np.zeros((layers,), np.int64)}
         self._pending = []
+        # always-on counts of the sliding-window layers' decode turns
+        self._win = {"turns": 0, "ring_tokens": 0}
         # the last decode step's tokens, the next one's `prev_tok`
         self._last_tok = jnp.zeros((s,), jnp.int32)
         # the expert ids every row chose in the last prefill (layers,
@@ -244,6 +270,8 @@ class LMRuntime:
             self.slot_state_bytes())
         _obs_registry().gauge("serve_latent_cache_bytes").set(
             self.latent_cache_bytes())
+        _obs_registry().gauge("serve_ring_cache_bytes").set(
+            self.ring_cache_bytes())
 
     def slot_state_bytes(self):
         """Device bytes of the per-slot arrays (both recurrent forms'
@@ -257,10 +285,17 @@ class LMRuntime:
         padding to whole lane tiles included)."""
         return sum(a.size * a.dtype.itemsize for a in self._state["lat"])
 
+    def ring_cache_bytes(self):
+        """Device bytes of the sliding-window layers' rings, every slot's
+        (the page pool's budget does not count them)."""
+        return sum(a.size * a.dtype.itemsize
+                   for k in ("ring_k", "ring_v") for a in self._state[k])
+
     def kv_bytes_per_page(self):
-        """What one page holds over all layers: K and V of the "gqa"
-        layers, kv_rank + rope_dim values a token of the "mla" layers
-        (values, not the tiles they are kept in)."""
+        """What one page of the POOL holds over all layers: K and V of
+        the "gqa" layers, kv_rank + rope_dim values a token of the "mla"
+        layers (values, not the tiles they are kept in). A "swa" layer's
+        ring is no page of the pool: `ring_cache_bytes` counts it."""
         sp = self.spec
         per_token = (2 * self._n["gqa"] * sp.kv_heads * sp.head_dim
                      + self._n["mla"] * (sp.kv_rank + sp.rope_dim))
@@ -280,6 +315,15 @@ class LMRuntime:
         follows it."""
         return {k: v.copy() for k, v in self._moe.items()}
 
+    def window_counters(self):
+        """The sliding-window layers' always-on counts since the state
+        was made: `turns`, the decode turns launched, and `ring_tokens`,
+        the sum over those turns and their running slots of
+        min(len + 1, window): the keys (and values) ONE window layer's
+        decode attention had to read, the current position among them.
+        Counted from the `lens` a launch holds on the host."""
+        return dict(self._win)
+
     def _count(self, counts, prefill=False):
         self._moe["rows"] += counts
         self._moe["touched"] += (counts > 0).sum(1)
@@ -296,6 +340,18 @@ class LMRuntime:
         """The state-space layers' state arrays, one a "mamba" layer
         (read-only use)."""
         return list(self._state["ssm"])
+
+    @property
+    def kv_pages(self):
+        """The paged K and V pools, one (K, V) pair a "gqa" layer
+        (read-only use)."""
+        return list(zip(self._state["k"], self._state["v"]))
+
+    @property
+    def ring_pages(self):
+        """The rings, one (K, V) pair of `(slots * ring, psize, Hkv * dh)`
+        pools a "swa" layer (read-only use)."""
+        return list(zip(self._state["ring_k"], self._state["ring_v"]))
 
     @property
     def latent_pages(self):
@@ -378,6 +434,10 @@ class LMRuntime:
                 y, state["k"][j], state["v"][j] = lm.mx_gqa(
                     L["mixer"], h, state["k"][j], state["v"][j],
                     page_tables, lens, page, off, spec=spec)
+            elif kind == "swa":
+                y, state["ring_k"][j], state["ring_v"][j] = lm.mx_swa(
+                    L["mixer"], h, state["ring_k"][j], state["ring_v"][j],
+                    lens, valid, spec=spec)
             elif kind == "mla":
                 y, state["lat"][j] = lm.mx_mla(
                     L["mixer"], h, state["lat"][j], page_tables, lens,
@@ -424,6 +484,10 @@ class LMRuntime:
                 for name, a in (("k", k), ("v", v)):
                     state[name][j] = mx_cache_write(state[name][j], pages,
                                                     a, psize=psize)
+            elif kind == "swa":
+                y, state["ring_k"][j], state["ring_v"][j] = lm.mx_swa_seq(
+                    L["mixer"], h, state["ring_k"][j], state["ring_v"][j],
+                    slot, n, spec=spec)
             elif kind == "mla":
                 y, rows = lm.mx_mla_seq(L["mixer"], h, pos, spec=spec)
                 state["lat"][j] = mx_cache_write(state["lat"][j], pages,
@@ -457,9 +521,10 @@ class LMRuntime:
     def prefill(self, slot, prompt, pages):
         """Run all but the last token of `prompt` into decode slot `slot`
         (ONE dispatch): K/V or latent rows into `pages` (the slot's
-        granted pages, in order), the recurrent state and convolution
-        tails into the slot's rows, whatever a previous request left
-        there overwritten."""
+        granted pages, in order), a sliding-window layer's newest pages
+        into the slot's ring, the recurrent state and convolution tails
+        into the slot's rows, whatever a previous request left there
+        overwritten."""
         toks = np.asarray(prompt, np.int32).reshape(-1)
         if not 1 <= toks.size <= self.max_src_len:
             raise MXNetError(f"prompt of {toks.size} tokens: this server "
@@ -517,6 +582,11 @@ class LMRuntime:
         expert counts, and those of the prefills dispatched before it,
         into `moe_counters()`: read every launch, once, in order."""
         profiler.record_dispatch("serve_decode")
+        if self._n["swa"]:
+            run = np.asarray(active) > 0
+            self._win["turns"] += 1
+            self._win["ring_tokens"] += int(np.minimum(
+                np.asarray(lens)[run] + 1, self.spec.window).sum())
         (self._state, next_tok, logits, counts,
          self.routing["decode"]) = self._decode_fn(
             self._state, self._w, jnp.asarray(page_tables, jnp.int32),

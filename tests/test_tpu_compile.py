@@ -459,3 +459,31 @@ def test_grouped_matmul_compiles_at_a_width_of_half_lane_tiles(chip_compile):
     assert kernel_calls(text, ("mxtpu_gmm",)) == {"mxtpu_gmm": 2}
     assert not re.search(r"= bf16\[64,\d+,\d+[^=]* (copy|transpose|fusion)\(",
                          text)
+
+
+# the window-attention server's shapes (benchmarks/configs/
+# mellum2_12b_l8.json): 32 query heads over 4 KV heads of 128, a window of
+# 1024 over 16-token pages: a ring of 65 pages (1040 rows, not whole lane
+# tiles) a slot; prompts padded to the static 4096
+def test_ring_paged_attention_compiles(chip_compile):
+    slots = 16
+    text = chip_compile(
+        lambda *a: pk.ring_paged_attention(*a, 1024),
+        ((slots, 32, 128), BF16), ((slots * 65, 16, 512), BF16),
+        ((slots * 65, 16, 512), BF16), ((slots,), I32))
+    assert kernel_calls(text, ("mxtpu_rpa_ring", "mxtpu_rpa_flat")) \
+        == {"mxtpu_rpa_ring": 1, "mxtpu_rpa_flat": 0}
+    # a slot's ring is read where it lies (the 3-D pool seen as a ring a
+    # slot is a bitcast): nothing of a pool's size is made
+    import re
+    assert not re.search(r"= bf16\[(1040|16),(16|1040),512[^=]* (copy|"
+                         r"transpose|fusion)\(", text)
+
+
+@pytest.mark.parametrize("window", [None, 1024], ids=["full", "w1024"])
+def test_prefill_flash_compiles_at_the_static_prompt(chip_compile, window):
+    qkv = ((1, 32, 4096, 128), BF16)
+    text = chip_compile(
+        lambda q, k, v: pk.flash_attention(q, k, v, causal=True,
+                                           window=window), qkv, qkv, qkv)
+    assert kernel_calls(text, ("mxtpu_flash_fwd",)) == {"mxtpu_flash_fwd": 1}
