@@ -257,6 +257,8 @@ class LMRuntime:
         self._pending = []
         # always-on counts of the sliding-window layers' decode turns
         self._win = {"turns": 0, "ring_tokens": 0}
+        # always-on counts of the paged ("gqa") layers' decode turns
+        self._paged = {"turns": 0, "live_pages": 0, "table_pages": 0}
         # the last decode step's tokens, the next one's `prev_tok`
         self._last_tok = jnp.zeros((s,), jnp.int32)
         # the expert ids every row chose in the last prefill (layers,
@@ -323,6 +325,17 @@ class LMRuntime:
         decode attention had to read, the current position among them.
         Counted from the `lens` a launch holds on the host."""
         return dict(self._win)
+
+    def paged_counters(self):
+        """The paged ("gqa") layers' always-on counts since the state was
+        made: `turns`, the decode turns launched, `live_pages`, the sum
+        over those turns and their running slots of ceil((len + 1) /
+        page_size): the pages ONE such layer's decode attention had to
+        read, the current position's among them, and `table_pages`, the
+        running slots times the page table's width: what a kernel that
+        walks the whole table reads. Counted from the `lens` a launch
+        holds on the host."""
+        return dict(self._paged)
 
     def _count(self, counts, prefill=False):
         self._moe["rows"] += counts
@@ -582,11 +595,18 @@ class LMRuntime:
         expert counts, and those of the prefills dispatched before it,
         into `moe_counters()`: read every launch, once, in order."""
         profiler.record_dispatch("serve_decode")
+        run = np.asarray(active) > 0
+        seen = np.asarray(lens)[run] + 1
         if self._n["swa"]:
-            run = np.asarray(active) > 0
             self._win["turns"] += 1
             self._win["ring_tokens"] += int(np.minimum(
-                np.asarray(lens)[run] + 1, self.spec.window).sum())
+                seen, self.spec.window).sum())
+        if self._n["gqa"]:
+            self._paged["turns"] += 1
+            self._paged["live_pages"] += int((-(-seen // self.page_size))
+                                             .sum())
+            self._paged["table_pages"] += int(run.sum()) * np.shape(
+                page_tables)[1]
         (self._state, next_tok, logits, counts,
          self.routing["decode"]) = self._decode_fn(
             self._state, self._w, jnp.asarray(page_tables, jnp.int32),

@@ -233,6 +233,32 @@ def test_a_backlog_looks_ahead_and_keeps_every_token(model, reference, ends):
     srv.close()
 
 
+@pytest.mark.parametrize("pattern", [("gqa", "kda", "kda", "kda"),
+                                     ("kda", "kda")], ids=["gqa", "no-gqa"])
+def test_paged_counters_count_the_pages_a_full_layer_reads(pattern):
+    """`paged_counters()` from the `lens` each launch holds: a running
+    slot reads ceil((len + 1) / page_size) pages of a table that is
+    `max_pages_per_slot` wide; an empty slot reads none; nothing is
+    counted without a "gqa" layer."""
+    srv = _server(seeded(dlm.DecoderLM(VOCAB, spec_of(pattern=pattern)), 1))
+    rt = srv.runtime
+    width = rt.max_pages_per_slot
+    tables = np.zeros((rt.slots, width), np.int32)
+    tok = np.zeros((rt.slots,), np.int32)
+    launches = [([0, 7], [1, 1]), ([8, 15], [1, 0]), ([23, 3], [2, 1])]
+    for lens, active in launches:
+        rt.decode(tables, np.asarray(lens, np.int32), tok,
+                  np.asarray(active, np.int32))
+    got = rt.paged_counters()
+    if "gqa" in pattern:
+        # ceil((len + 1) / 8) of the five running slots: 1, 1, 2, 3, 1
+        assert got == {"turns": 3, "live_pages": 8,
+                       "table_pages": 5 * width}
+    else:
+        assert got == {"turns": 0, "live_pages": 0, "table_pages": 0}
+    srv.close()
+
+
 def test_the_previous_steps_tokens_feed_the_next_on_the_device(model):
     """`LMRuntime.decode_launch`'s `active` 2 / 1 / 0, as
     `DecodeRuntime`'s: the logits and the slots' recurrent state are
@@ -583,14 +609,14 @@ def test_the_expert_layer_scatters_no_row_of_its_input():
 
 
 # ------------------------------------------- grouped-KV paged attention
-def _paged_case(rng, dtype, dh=128):
+def _paged_case(rng, dtype, dh=128, lens=(1, 17, 170, 30), hq=8):
     """Pools page by page, (P, psize, H, dh): what `_dense_attention`
     reads; `_flat` and `_head_major` give the two forms a pool is kept in."""
-    s, hq, h, psize, npg, pool = 4, 8, 2, 16, 11, 60
+    s, h, psize, npg, pool = len(lens), 2, 16, 11, 60
     q = jnp.asarray(rng.normal(size=(s, hq, dh)), dtype)
     kp = jnp.asarray(rng.normal(size=(pool, psize, h, dh)), dtype)
     vp = jnp.asarray(rng.normal(size=(pool, psize, h, dh)), dtype)
-    lens = np.asarray([1, 17, 170, 30], np.int32)
+    lens = np.asarray(lens, np.int32)
     perm, c = rng.permutation(np.arange(1, pool)), 0
     tables = np.zeros((s, npg), np.int32)
     for i in range(s):
@@ -637,6 +663,44 @@ def test_paged_attention_with_grouped_kv_heads(form, monkeypatch):
     want = _dense_attention(q, kp, vp, tables, lens)
     pools = (_flat if form == "kernel-flat-pool" else _head_major)(kp, vp)
     got = pk.ragged_paged_attention(q, *pools, tables, lens)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+# the flat-pool kernel fetches a slot's pages itself, `cpp` a chunk (here
+# forced small through `_RPA_FLAT_STEP_KEYS`), over 11-page tables
+@pytest.mark.parametrize("lens,hq,window,step_keys", [
+    ((1, 16, 48, 176), 8, 1, 48),
+    ((176, 175, 33, 2), 8, 1, 32),
+    ((1, 176, 1, 150), 8, 1, 48),
+    ((5, 64, 130, 176), 16, 1, 64),
+    ((5, 64, 130, 176), 32, 1, 64),
+    ((1, 16, 47, 174), 8, 3, 48),
+], ids=["one-key-page-edge-chunk-edge-whole-table",
+        "chunks-that-do-not-divide-the-table", "one-key-then-a-long-slot",
+        "8-query-heads-a-kv-head", "16-query-heads-a-kv-head",
+        "a-window-of-3"])
+def test_flat_pool_kernel_fetches_the_live_pages(lens, hq, window,
+                                                 step_keys, interpret,
+                                                 monkeypatch):
+    """Ragged lengths in one launch, a last chunk past the table's width,
+    the next slot's first chunk fetched under a one-chunk slot (the
+    buffers' parity flips), grouped query heads, and the widened form
+    (query i sees lens + i keys), each against the dense attention."""
+    monkeypatch.setattr(pk, "_RPA_FLAT_STEP_KEYS", step_keys)
+    rng = np.random.default_rng(step_keys + hq + window)
+    q, kp, vp, tables, lens = _paged_case(rng, jnp.float32, lens=lens,
+                                          hq=hq)
+    assert pk._rpa_flat_cpp(11, 16, 256, 4) == step_keys // 16
+    if window == 1:
+        got = pk.ragged_paged_attention(q, *_flat(kp, vp), tables, lens)
+        want = _dense_attention(q, kp, vp, tables, lens)
+    else:
+        qw = jnp.asarray(rng.normal(size=(len(lens), window, hq, 128)),
+                         jnp.float32)
+        got = pk.ragged_paged_attention(qw, *_flat(kp, vp), tables, lens)
+        want = np.stack([_dense_attention(qw[:, i], kp, vp, tables,
+                                          lens + i)
+                         for i in range(window)], 1)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
