@@ -339,10 +339,13 @@ def test_prefill_program_writes_its_memory_buffers_in_place(one_chip,
 # the decoder-only servers' shapes (benchmarks/configs/): solar_open2_ep8's
 # 64 query heads over 8 KV heads of 128 and 64 pages a slot (16 slots of
 # its 256), nemotron3_nano_ep2's 32 over 2 and 96 pages, mellum2_12b_l8's
-# 32 over 4 and 320 pages at its 128 slots; 16-token pages
+# 32 over 4 and 320 pages at its 128 slots, falcon_h1_34b_l4's 20 over 4
+# (5 query heads a KV head: a group that fills no sublane tile) and 128
+# pages at its 128 slots; 16-token pages
 @pytest.mark.parametrize("slots,heads,kv_heads,npages", [
-    (16, 64, 8, 64), (256, 32, 2, 96), (128, 32, 4, 320),
-], ids=["solar_open2_ep8", "nemotron3_nano_ep2", "mellum2_12b_l8"])
+    (16, 64, 8, 64), (256, 32, 2, 96), (128, 32, 4, 320), (128, 20, 4, 128),
+], ids=["solar_open2_ep8", "nemotron3_nano_ep2", "mellum2_12b_l8",
+        "falcon_h1_34b_l4"])
 def test_grouped_kv_paged_attention_compiles_at_the_flat_pool(
         chip_compile, slots, heads, kv_heads, npages):
     """ONE `mxtpu_rpa_flat`, a grid step a slot (the kernel fetches the
@@ -446,6 +449,18 @@ def test_ssd_step_compiles(chip_compile):
         lambda *a: ssd.ssd_step_slots(*a)[1], ((16, 64, 64), F32),
         ((16, 64), F32), ((64,), F32), ((16, 8, 128), F32),
         ((16, 8, 128), F32), ((16, 64, 64, 128), F32))
+    assert kernel_calls(text, ("mxtpu_ssd_step",)) == {"mxtpu_ssd_step": 1}
+
+
+def test_ssd_step_compiles_at_the_parallel_hybrids_state(chip_compile):
+    """falcon_h1_34b_l4's state, 32 heads x 128 x 256 float32 (4.19 MB a
+    slot, twice cell 6's), 2 groups of B and C, at its 128 slots: ONE
+    grid step still holds a whole slot's state, in and out."""
+    from mxnet_tpu.ops import ssd
+    text = chip_compile(
+        lambda *a: ssd.ssd_step_slots(*a)[1], ((128, 32, 128), F32),
+        ((128, 32), F32), ((32,), F32), ((128, 2, 256), F32),
+        ((128, 2, 256), F32), ((128, 32, 128, 256), F32))
     assert kernel_calls(text, ("mxtpu_ssd_step",)) == {"mxtpu_ssd_step": 1}
 
 
