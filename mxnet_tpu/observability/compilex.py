@@ -316,13 +316,19 @@ def analyze_compiled(compiled, defer_names=False):
 def _abstract(x):
     """Shape/dtype/sharding skeleton of one argument leaf — lets the
     inspection lower() run after dispatch even where donation already
-    consumed the concrete buffers (aval metadata survives deletion)."""
+    consumed the concrete buffers (aval metadata survives deletion).
+    The sharding is named only where the array is committed to it, as
+    the call's own compile saw it: the inspection then finds the
+    executable the call compiled, and XLA neither compiles it nor loads
+    it from the persistent cache a second time."""
     if isinstance(x, jax.Array):
-        try:
-            return jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                        sharding=x.sharding)
-        except Exception:
-            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        if getattr(x, "committed", True):
+            try:
+                return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                            sharding=x.sharding)
+            except Exception:
+                pass
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
     return x
 
 

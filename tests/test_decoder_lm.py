@@ -135,6 +135,16 @@ def test_prefill_then_decode_gives_the_reference_logits(model, reference,
     srv.close()
 
 
+def test_every_rung_gives_what_the_static_length_gives(model,
+                                                         prefill_ladder):
+    """A GQA layer's pages and three KDA layers' state and tails, the
+    routing and the next logits: each prompt through its own rung of the
+    prefill ladder as through the static 512 (`conftest.prefill_ladder`),
+    ONE prefill trace."""
+    rt = prefill_ladder(model, page_size=8)
+    assert len(rt.kv_pages) == 1 and len(rt.kda_state) == 3
+
+
 def _greedy(reference, prompt, n):
     seq = list(prompt)
     for _ in range(n):

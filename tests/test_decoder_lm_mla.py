@@ -306,6 +306,15 @@ def test_prefill_then_decode_gives_the_reference_logits_and_rows(
     srv.close()
 
 
+def test_every_rung_gives_what_the_static_length_gives(model,
+                                                         prefill_ladder):
+    """Three latent layers' rows (a dense layer, then two with experts):
+    each prompt through its own rung of the prefill ladder as through the
+    static 512 (`conftest.prefill_ladder`), ONE prefill trace."""
+    rt = prefill_ladder(model, page_size=8)
+    assert len(rt.latent_pages) == 3 and rt.kv_pages == []
+
+
 def _greedy(reference, prompt, n, width=40):
     """The reference's argmax chain; the sequence rides in a fixed width
     (one compile): a causal forward's position does not see what follows."""

@@ -239,6 +239,16 @@ def test_prefill_then_decode_gives_the_reference_logits_state_and_pages(
     srv.close()
 
 
+def test_every_rung_gives_what_the_static_length_gives(model,
+                                                         prefill_ladder):
+    """Two parallel layers, each keeping a slot's Mamba-2 state and tails
+    AND pages: each prompt through its own rung of the prefill ladder as
+    through the static 512 (`conftest.prefill_ladder`), ONE prefill
+    trace."""
+    rt = prefill_ladder(model, page_size=8)
+    assert len(rt.ssm_state) == len(rt.kv_pages) == 2
+
+
 def _greedy(reference, prompt, n, width=40):
     seq = list(prompt)
     for _ in range(n):

@@ -232,6 +232,16 @@ def test_prefill_then_decode_gives_the_reference_logits_and_state(
     srv.close()
 
 
+def test_every_rung_gives_what_the_static_length_gives(model,
+                                                         prefill_ladder):
+    """Three Mamba-2 layers' state and tails, a GQA layer's pages and the
+    expert layers of one sub-layer each: each prompt through its own rung
+    of the prefill ladder as through the static 512
+    (`conftest.prefill_ladder`), ONE prefill trace."""
+    rt = prefill_ladder(model, page_size=8)
+    assert len(rt.ssm_state) == 3 and len(rt.kv_pages) == 1
+
+
 def _greedy(reference, prompt, n, width=40):
     seq = list(prompt)
     for _ in range(n):

@@ -277,6 +277,17 @@ def _server(model, **kw):
     return mx.serve.Server(model, **args)
 
 
+def test_every_rung_gives_what_the_static_length_gives(model,
+                                                         prefill_ladder):
+    """Three window layers' rings (a prompt of 511 laps a ring of 3 pages
+    of 16 ten times) and a full layer's pages: each prompt through its own
+    rung of the prefill ladder as through the static 512
+    (`conftest.prefill_ladder`), ONE prefill trace."""
+    rt = prefill_ladder(model, page_size=16)
+    assert rt.ring == 3 and len(rt.ring_pages) == 3 \
+        and len(rt.kv_pages) == 1
+
+
 def _greedy(reference, prompt, n, width=128):
     seq = list(prompt)
     for _ in range(n):

@@ -755,6 +755,43 @@ def test_op_names_are_parsed_when_an_inspection_is_first_read(monkeypatch):
     assert eager["op_names"] == got["op_names"] and "hlo_z" not in eager
 
 
+@pytest.mark.parametrize("committed", [False, True],
+                         ids=["uncommitted", "committed"])
+def test_the_inspection_reads_the_executable_the_call_compiled(monkeypatch,
+                                                               committed):
+    """An instrumented call that compiles is inspected from the
+    executable the call compiled: ONE backend compile (or, with a
+    persistent cache, one load) for the call and its inspection together,
+    whether the arguments are committed to a device or not, donated
+    ones included. An inspection that named a sharding the call never saw
+    compiled the program again: each serving program was loaded twice in
+    set-up."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src import monitoring
+    from mxnet_tpu.observability import compilex
+    monkeypatch.setenv("MXTPU_HLO_TELEMETRY", "always")
+    put = (lambda a: jax.device_put(a, jax.devices()[0])) if committed \
+        else (lambda a: a)
+    fn = compilex.instrument(
+        jax.jit(lambda s, x, n: ({"a": s["a"] + x.sum() * n}, x * 2),
+                donate_argnums=(0,)), f"test_one_compile_{committed}")
+    args = ({"a": put(jnp.zeros((4,)))}, put(jnp.ones((8,))),
+            put(jnp.int32(3)))
+    seen = []
+
+    def on(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(duration)
+    monitoring.register_event_duration_secs_listener(on)
+    try:
+        fn(*args)
+    finally:
+        monitoring.unregister_event_duration_listener(on)
+    assert fn.compile_count == 1 and fn.last_hlo["fusions"] >= 0
+    assert len(seen) == 1
+
+
 def test_a_recording_starts_with_its_own_ring_and_thread_names():
     # neither the ring size one caller asked for nor the name of a thread
     # that has exited (idents are reused) outlives its recording

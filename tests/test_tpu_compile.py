@@ -511,3 +511,139 @@ def test_prefill_flash_compiles_at_the_static_prompt(chip_compile, window):
         lambda q, k, v: pk.flash_attention(q, k, v, causal=True,
                                            window=window), qkv, qkv, qkv)
     assert kernel_calls(text, ("mxtpu_flash_fwd",)) == {"mxtpu_flash_fwd": 1}
+
+
+def _prefill_ladder(one_chip, config, lib):
+    """(runtime, ladder, top): `LMRuntime`'s prefill program at a
+    configuration's real shapes (benchmarks/configs/<config>.json, its
+    spec from benchmarks/lib/<lib>.py) compiled for the described chip,
+    and its body at the top rung alone (the one-length program). The
+    weights and the state are shapes only."""
+    import importlib
+    import json
+    from functools import partial
+    from types import SimpleNamespace
+    from mxnet_tpu.models.decoder_lm import DecoderLM
+    from mxnet_tpu.serve.lm_runtime import LMRuntime
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           config + ".json")) as f:
+        cfg = json.load(f)
+    spec = importlib.import_module("benchmarks.lib." + lib).spec_of(cfg)
+    model = DecoderLM(cfg["vocab_size"], spec)
+    for p in model.collect_params().values():
+        p._trace_override = SimpleNamespace(
+            _data=jax.ShapeDtypeStruct(p.shape, BF16))
+
+    class Shapes(LMRuntime):
+        def reset_pages(self):
+            def made():
+                LMRuntime.reset_pages(self)
+                return self._state
+            self._state = jax.eval_shape(made)
+
+    srv = cfg["server"]
+    per = (srv["max_prompt_len"] + srv["max_new_tokens"]) // srv["page_size"]
+    rt = Shapes(model, slots=srv["slots"], num_pages=srv["slots"] * per + 1,
+                page_size=srv["page_size"], max_pages_per_slot=per,
+                max_prompt_len=srv["max_prompt_len"])
+
+    def aval(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    args = (*jax.tree_util.tree_map(aval, (rt._state, rt._w)),
+            i32(rt._plen), i32(), i32(), i32(per))
+    ladder = rt._prefill_fn._jfn.lower(*args).compile()
+    top = jax.jit(partial(rt._prefill_at, rt._plen),
+                  donate_argnums=(0,)).lower(*args).compile()
+    assert rt.prefill_traces == 1
+    return rt, ladder, top
+
+
+def _one_conditional_over_the_rungs(rt, text, alone):
+    """The ladder's program holds ONE conditional, a branch a rung, each
+    the body at its length (as many flash calls as the top rung's program
+    has), and donates what the one-length program donates."""
+    import re
+    from mxnet_tpu.observability import compilex
+    branches = re.findall(r"branch_computations=\{([^}]*)\}", text)
+    assert len(branches) == 1 \
+        and len(branches[0].split(",")) == len(rt.rungs) == 2
+    flash = kernel_calls(alone, ("mxtpu_flash_fwd",))["mxtpu_flash_fwd"]
+    assert flash > 0 and kernel_calls(text, ("mxtpu_flash_fwd",)) \
+        == {"mxtpu_flash_fwd": 2 * flash}
+    aliased = compilex.inspect_hlo_text(text)["aliased_inputs"]
+    assert aliased == compilex.inspect_hlo_text(alone)["aliased_inputs"]
+    return aliased
+
+
+def test_lm_prefill_ladder_is_one_program_over_the_latent_pools(one_chip,
+                                                               chip_compile):
+    """`LMRuntime`'s prefill at pangu_ultra_ep16's shapes (five latent
+    layers, 256 slots of 128 pages of 16 rows of 640 lanes: 3.36 GB of
+    pools; prompts to 1024): ONE program whose conditional has a branch
+    for each rung of the ladder 512 / 1024, the five pools donated into
+    the results as the one-length program donates them and written in
+    place in every branch (nothing of a pool's size but parameters, tuple
+    elements and scatters), and the branches' temporaries overlapping:
+    the program holds at most 1.1 times the top rung's alone."""
+    rt, program, top = _prefill_ladder(one_chip, "pangu_ultra_ep16",
+                                       "lm_mla")
+    assert rt.rungs == (512, 1024)
+    text = program.as_text()
+    assert _one_conditional_over_the_rungs(rt, text, top.as_text()) == 5
+    made = pool_sized_results(text, rt._state["lat"][0].size)
+    assert made["fusion:scatter"] == 2 * 5, made
+    moved = {op: n for op, n in made.items()
+             if op not in ("parameter", "get-tuple-element", "bitcast",
+                           "scatter", "fusion:scatter")}
+    assert not moved, made
+    assert program.memory_analysis().temp_size_in_bytes \
+        <= 1.1 * top.memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("config,lib,layers", [
+    ("falcon_h1_34b_l4", "lm_par", {"k": 4, "v": 4, "ssm": 4}),
+    ("solar_open2_ep8", "lm", {"k": 1, "v": 1, "kda": 3})],
+    ids=["parallel", "kda"])
+def test_lm_prefill_ladder_hands_out_the_recurrent_rows(one_chip, chip_compile,
+                                                        config, lib, layers):
+    """`LMRuntime`'s prefill at the real shapes of a model with recurrent
+    layers: falcon_h1_34b_l4's four parallel layers (each 128 slots'
+    Mamba-2 state of 32 x 128 x 256 in float32, 536.9 MB, beside K/V
+    pools of 16,385 pages of 16 x 512), solar_open2_ep8's three KDA
+    layers (256 slots' state, 1.07 GB a layer) and one GQA layer. ONE
+    conditional over the rungs 512 / 1024 (256 / 512) writes the K/V
+    pools in place in every branch and hands out the slot's recurrent
+    rows, which the program writes after it: nothing of a state's or a
+    pool's size is copied, transposed, sliced or made by any fusion but
+    an in-place update (a branch that wrote a KDA state itself had it
+    copied into and out of the conditional: 1.1 GB of temporaries). The
+    branches add at most 64 MB of temporaries, not a state array: the rows
+    handed out stay live while the later layers run. Read when this was
+    written: 161.4 MB against the top rung's 127.3 alone (falcon; the
+    rows 16.9 MB), 97.2 against 52.1 (solar; the rows 13.0 MB)."""
+    rt, program, top = _prefill_ladder(one_chip, config, lib)
+    assert rt.rungs == tuple(rt._plen // d for d in (2, 1))
+    text = program.as_text()
+    aliased = _one_conditional_over_the_rungs(rt, text, top.as_text())
+    assert {k: len(rt._state[k]) for k in layers} == layers
+    tails = {"ssm": "ssm_conv", "kda": "conv"}
+    assert aliased == sum(layers.values()) + sum(
+        n for k, n in layers.items() if k in tails)
+    state = [a for k in layers for a in rt._state[k]]
+    made = pool_sized_results(text, min(a.size for a in state))
+    # (a `fusion:bitcast` is a view of the MLP's 43,008 x 5120 weights,
+    # larger than a state array, that the one-length program has too)
+    moved = {op: n for op, n in made.items()
+             if op not in ("parameter", "get-tuple-element", "bitcast",
+                           "fusion:bitcast", "scatter", "fusion:scatter",
+                           "dynamic-update-slice",
+                           "fusion:dynamic-update-slice")}
+    assert not moved, made
+    assert program.memory_analysis().temp_size_in_bytes \
+        <= top.memory_analysis().temp_size_in_bytes + 64e6
