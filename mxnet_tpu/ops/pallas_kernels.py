@@ -120,23 +120,22 @@ def _block_sizes(sq, sk):
             pick(sk, "flash_block_k", "MXTPU_FLASH_BLOCK_K"))
 
 
-def _rpa_block_k(psize):
+def _rpa_block_k(chunk):
     """Forced K tile of `_rpa_kernel`'s body (ISSUE 20). A grid step
-    holds a slot's heads and several whole pages (`_rpa_plan`) and by
-    default (= psize) takes a head's keys of the step as one tile; a
-    forced value walks them in tiles of `block` rows of a page, inside
-    the step: more, narrower softmax updates over the same blocks, no
-    more grid steps or DMAs (a step a sub-page tile is what it asked
-    for before PR 31). MXTPU_RPA_BLOCK_K / tune override `rpa_block_k`;
-    must divide the page size and keep the 8-sublane tile, else the
-    default is used loudly."""
+    holds a slot's heads and fetches its pages a chunk of `chunk` keys
+    at a time (`_rpa_chunk`); by default a chunk's keys are one tile; a
+    forced value walks them in tiles of that many rows inside the
+    chunk: more, narrower softmax updates over the same buffers, no
+    more grid steps or DMAs. MXTPU_RPA_BLOCK_K / tune override
+    `rpa_block_k`; must divide the chunk and keep the 8-sublane tile,
+    else the default is used loudly."""
     forced, src = _knob("rpa_block_k", "MXTPU_RPA_BLOCK_K")
     if not forced:
-        return psize
-    if forced % 8 == 0 and 8 <= forced <= psize and psize % forced == 0:
+        return chunk
+    if forced % 8 == 0 and 8 <= forced <= chunk and chunk % forced == 0:
         return forced
-    _note_ignored(src, "MXTPU_RPA_BLOCK_K", forced, psize, psize)
-    return psize
+    _note_ignored(src, "MXTPU_RPA_BLOCK_K", forced, chunk, chunk)
+    return chunk
 
 
 def _rpa_sublanes(W):
@@ -880,7 +879,7 @@ def pool_lanes(head_dim):
     """The row width to keep a head-major (H, P, psize, lanes) pool at.
     On the TPU the head size rounded up to whole 128-lane tiles: the
     client's default layout for such an array is row-major, which is
-    what `_rpa_kernel`'s (heads, 1, psize, lanes) blocks read; an array whose
+    what `_rpa_kernel`'s (heads, psize, lanes) page copies read; an array whose
     minor dimension is under 128 it lays out with another dimension in
     the lanes (to save the padding), and every program over it then
     copies the pool into the kernel's layout and back. Elsewhere there
@@ -946,223 +945,251 @@ def _paged_attention_lax_multi(q, k_pages, v_pages, page_tables, lengths,
     return out.transpose(0, 2, 1, 3)
 
 
-# what the page blocks of one grid step may hold of the chip's fast memory,
-# both buffers of the pipeline counted: half of the 16 MiB a kernel gets
+# what the K and V buffers of a paged kernel's chunks may hold of the
+# chip's fast memory, every buffer counted: half of the 16 MiB a kernel
+# gets
 _RPA_VMEM_BUDGET = 8 << 20
 
+# bytes of K (and as many of V) a chunk of `mxtpu_rpa` fetches at most:
+# whole pages of every head up to this many; and the buffers of K (and as
+# many of V) it keeps, so that this many chunks less one are in flight
+# while one is computed on (PERF.md section 6 has the probe of 1, 2 and
+# 4 pages a chunk and of 2, 3, 4 and 6 buffers at 64 KB pages)
+_RPA_STEP_BYTES = 128 << 10
+_RPA_BUFFERS = 4
 
-def _rpa_plan(H, npages, psize, lanes, itemsize):
-    """(heads, pages) one grid step of `_rpa_kernel` takes, from the
-    shapes alone: all `H` heads of a slot and `min(8, npages)` of its
-    pages, as long as the K and V blocks of the step, each
-    double-buffered and in whole sublane tiles, fit `_RPA_VMEM_BUDGET`;
-    a longer page or more heads take fewer pages a step, then a divisor
-    of the heads. The grid is `_rpa_steps` of these."""
+
+def _rpa_chunk(H, npages, psize, lanes, itemsize):
+    """Keys a chunk of `mxtpu_rpa` fetches, from the shapes alone: whole
+    pages of every head, as many as `_RPA_STEP_BYTES` of K hold and the
+    table has, while the buffers of K and of V fit `_RPA_VMEM_BUDGET`;
+    where one page of every head is more than a buffer's share, the
+    largest part of a page in whole sublane tiles that fits."""
     tile = 8 * max(1, 4 // itemsize)        # sublane rows of a tile
-    page = 2 * 2 * -(-psize // tile) * tile * lanes * itemsize
-    heads = max(h for h in range(1, H + 1)
-                if H % h == 0 and (h == 1 or h * page <= _RPA_VMEM_BUDGET))
-    pages = max(1, min(8, npages, _RPA_VMEM_BUDGET // (heads * page)))
-    return heads, pages
+    key = H * lanes * itemsize              # a key of every head
+    page = -(-psize // tile) * tile * key
+    room = _RPA_VMEM_BUDGET // (2 * _RPA_BUFFERS)
+    if page <= room:
+        return psize * max(1, min(npages, _RPA_STEP_BYTES // page,
+                                  room // page))
+    return max((r for r in range(tile, psize, tile)
+                if psize % r == 0 and r * key <= room), default=psize)
 
 
-def _rpa_steps(S, H, npages, heads, pages):
-    """The grid of one `mxtpu_rpa` call: a row a (slot, group of
-    `heads`), a step for each `pages` of the table's width."""
-    return S * (H // heads), -(-npages // pages)
-
-
-def _rpa_row(g, groups):
-    """(slot, group of heads) of grid row `g`: the row itself and 0 where
-    a slot is one row, else `lax.div` / `lax.rem`, one instruction each
-    (`//` and `%` lower to sign corrections that Mosaic traces anew in
-    every one of a step's 17 index maps: 3 s of a server's set-up)."""
-    if groups == 1:
-        return g, 0
-    return lax.div(g, jnp.int32(groups)), lax.rem(g, jnp.int32(groups))
-
-
-def _rpa_kernel(*refs, psize, pps, block_k, heads, groups, window, sm_scale,
-                quant=False):
+def _rpa_kernel(*refs, psize, chunk, block_k, window, sm_scale, quant=False):
     """Ragged paged attention over head-major (H, P, psize, lanes) pools:
-    one SLOT per grid row with `heads` of its heads (all of them, unless
-    `_rpa_plan` had to split: `groups` rows a slot then) and `pps` pages
-    per inner step, one block spec a page, so the pipeline gathers them
-    together: a page's block is the (heads, 1, psize, lanes) of the pool
-    where it lies. A short context at small pages is bound by the count
-    of grid steps, not by bytes, and this form takes heads x pps fewer
-    than one (slot, head, page) a step. The page ids were already
-    consumed by the BlockSpec index maps (scalar prefetch); here we only
-    need the slot's valid length for masking and for skipping the steps
-    past it. The body walks the heads; a head's keys of the step are one
-    (pps * psize, lanes) tile, or `block_k`-row tiles where a forced
+    one SLOT a grid step with all its heads, the slot's pages fetched by
+    the kernel's own DMAs from the pools left in HBM, a chunk of `chunk`
+    keys at a time. A page of every head is ONE strided copy,
+    `pool.at[:, page]` (or a part of it, where a page of every head is
+    more than a buffer holds: `_rpa_chunk`). Only the chunks that hold a
+    key the slot's last query row sees are fetched, in the order they are
+    computed on: this slot's, then the next slots', into a ring of
+    `_RPA_BUFFERS` buffers of K and as many of V, so that up to that many
+    chunks less one are in flight while one is computed on. A cursor in
+    SMEM (`cur`: the slot and chunk to fetch next, the chunks fetched and
+    the chunks computed) carries the ring from grid step to grid step, so
+    the grid is sequential. A chunk is fetched whole, so the pages of the
+    last chunk past the length come too (the table names the null page
+    there). A block spec a page cost the pipeline about 60 ns whether the
+    page was live or not: most of a call at 256 slots of eight pages;
+    and one chunk in flight left the call bound by each slot's fetch
+    latency (PERF.md section 6).
+
+    The heads go in ONE pass: `q . K^T` and `p . V` are batched over the
+    head axis, (H, wp, lanes) x (H, keys, lanes), the running max and sum
+    of every head in one (H, wp, 128) scratch, lane-replicated as the
+    flash kernels keep theirs (a (wp, 1) column does not lower). A chunk's
+    keys are one tile, or `block_k`-row tiles where a forced
     `_rpa_block_k` asks for them.
 
-    `window` (ISSUE 12) real query rows per slot, padded to the
-    8-sublane tile: query row i masks keys at `len_ref[slot] + i` —
-    consecutive positions, so a single per-slot scalar carries the whole
-    ragged query-length structure. The one-token decode turn is
-    window == 1 of the same kernel: a (1, dh) query block with a (1, 1)
-    running max does not lower on the chip (Mosaic has no broadcast
-    over sublanes and lanes at once), so every form keeps its running
-    max/sum as lane-replicated (rows, 128) like the flash kernels.
-    Rows beyond a slot's real window produce garbage nobody commits.
+    `window` real query rows a slot, padded to `wp` sublanes: query row i
+    sees `len_ref[slot] + i` keys (the widened verify form; 1 in a decode
+    turn), so the chunks up to key `length + window - 1` are fetched. Rows
+    past the length are masked; every key a chunk computes on was fetched
+    for it. Rows beyond a slot's real window, and an empty slot's row,
+    produce garbage nobody reads.
 
-    quant (ISSUE 14): the page pools are int8 and two extra scalar-
-    prefetch refs carry the per-page/per-head f32 dequant scales — a
-    page's block dequantizes in VMEM right after the DMA, one scalar a
-    head and page, so HBM only ever moves int8 bytes."""
-    pt_ref, len_ref = refs[:2]
-    ks_ref, vs_ref = refs[2:4] if quant else (None, None)
-    refs = refs[4 if quant else 2:]
-    q_ref, k_refs, v_refs = refs[0], refs[1:1 + pps], refs[1 + pps:1 + 2 * pps]
-    o_ref, m_scr, l_scr, acc_scr = refs[1 + 2 * pps:]
-    # grid row slot * groups + head group, step of pps pages
-    (s_idx, hg), j = _rpa_row(pl.program_id(0), groups), pl.program_id(1)
-    length = len_ref[s_idx]                 # keys visible to query row 0
-    k_start = j * pps * psize
-    wp = q_ref.shape[2]                     # padded query rows (>= 8)
+    `nc_ref` holds each slot's count of chunks, worked out before the
+    call, so that the kernel divides nothing.
+
+    quant: the pools are int8 and two more scalar-prefetch refs carry the
+    (H, P) float32 dequant scales: a chunk dequantizes in VMEM right after
+    its DMA, a scalar a head and page, so HBM only ever moves int8
+    bytes."""
+    pt_ref, len_ref, nc_ref = refs[:3]
+    ks_ref, vs_ref = refs[3:5] if quant else (None, None)
+    (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, cur, m_scr, l_scr,
+     acc_scr) = refs[5 if quant else 3:]
+    s_idx, n_slots = pl.program_id(0), pl.num_programs(0)
+    nbuf = kbuf.shape[0]
+    heads, wp, lanes = q_ref.shape[1:]
     npages = pt_ref.shape[1]
-    # the step's key tiles: [(page of the step, rows of it), ...] each
-    if block_k == psize:
-        tiles = [[(i, slice(None)) for i in range(pps)]]
-    else:
-        tiles = [[(i, slice(b, b + block_k))]
-                 for i in range(pps) for b in range(0, psize, block_k)]
+    rows = min(chunk, psize)                # keys a copy
+    cpp = chunk // rows                     # copies (pages) a chunk
+    keys = npages * psize                   # keys the table holds
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, -1e30)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def page_of(slot, c, i):
+        """(page id, first row) of copy i of chunk c of `slot`."""
+        if rows == psize:
+            return pt_ref[slot, jnp.minimum(c * cpp + i, npages - 1)], 0
+        per = psize // chunk
+        return (pt_ref[slot, lax.div(c, jnp.int32(per))],
+                pl.multiple_of(lax.rem(c, jnp.int32(per)) * chunk, chunk))
 
-    # steps beyond what the LAST real query row sees are skipped — the
-    # ragged part: a 3-token request costs one step of work while its
-    # 300-token neighbour walks its whole table, in the same launch
-    @pl.when(k_start < length + window - 1)
-    def _compute():
+    def fetch(slot, c, buf):
+        """Start the DMAs of chunk `c` of `slot` into buffer `buf`, a
+        descriptor a page of K and one of V."""
+        for i in range(cpp):
+            page, r0 = page_of(slot, c, i)
+            for pool, kv, b in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                src = (pool.at[:, page] if rows == psize
+                       else pool.at[:, page, pl.ds(r0, rows)])
+                pltpu.make_async_copy(
+                    src, kv.at[buf, :, pl.ds(i * rows, rows)],
+                    sem.at[b, buf]).start()
+
+    def top_up(done, at):
+        """Fetch the chunks after `at` = (slot, chunk, fetched) until
+        every buffer but the one chunk `done` was computed in holds or
+        awaits a chunk not yet computed on."""
+        def more(st):
+            return (st[2] < done + nbuf) & (st[0] < n_slots)
+
+        def one(st):
+            slot, c, fetched = st
+            fetch(slot, c, lax.rem(fetched, nbuf))
+            last = c + 1 == nc_ref[slot]
+            return (jnp.where(last, slot + 1, slot),
+                    jnp.where(last, 0, c + 1), fetched + 1)
+        return lax.while_loop(more, one, at)
+
+    @pl.when(s_idx == 0)
+    def _first():
+        for i in range(4):
+            cur[i] = 0
+
+    m_scr[...] = jnp.full_like(m_scr, -1e30)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    length = len_ref[s_idx]
+    q = q_ref[0]                            # (heads, wp, lanes)
+    qi = lax.broadcasted_iota(jnp.int32, (wp, block_k), 0)
+    col = lax.broadcasted_iota(jnp.int32, (wp, block_k), 1)
+    if quant:
+        hi = lax.broadcasted_iota(jnp.int32, (heads, chunk, lanes), 0)
+        pi = lax.broadcasted_iota(jnp.int32, (heads, chunk, lanes), 1) \
+            // rows
+
+    def dequant(x, scales, c):
+        # the same element-wise form as the lax fallback's gathered
+        # dequant (parity pinned in interpret): a scalar a head and page
+        sc = jnp.zeros(x.shape, jnp.float32)
+        for i in range(cpp):
+            page = page_of(s_idx, c, i)[0]
+            for h in range(heads):
+                sc = jnp.where((hi == h) & (pi == i), scales[h, page], sc)
+        return x.astype(jnp.float32) * sc
+
+    def body(c, st):
+        done, at = st[0], top_up(st[0], st[1:])
+        buf = lax.rem(done, nbuf)
+        # ONE wait a pool for the chunk's bytes: the semaphore counts
+        # bytes, and the chunk's pages fill its buffer whole
+        for kv, b in ((kbuf, 0), (vbuf, 1)):
+            pltpu.make_async_copy(kv.at[buf], kv.at[buf],
+                                  sem.at[b, buf]).wait()
+        k_all, v_all = kbuf[buf], vbuf[buf]     # (heads, chunk, lanes)
         if quant:
-            page_ids = [pt_ref[s_idx, jnp.minimum(j * pps + i, npages - 1)]
-                        for i in range(pps)]
+            k_all, v_all = (dequant(k_all, ks_ref, c),
+                            dequant(v_all, vs_ref, c))
+        for t in range(0, chunk, block_k):
+            k, v = k_all[:, t:t + block_k], v_all[:, t:t + block_k]
+            s = jax.lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * sm_scale
+            kj = c * chunk + t + col
+            keep = kj < length + qi
+            if keys % chunk:
+                # the last chunk's pages past the table's width
+                keep &= kj < keys
+            s = jnp.where(keep[None], s, -1e30)     # (heads, wp, block_k)
+            m_prev = m_scr[:, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_scr[:, :, :1] + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+            acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        return (done + 1,) + at
 
-        def rows_of(pages, scales, h, parts):
-            out = []
-            for i, rows in parts:
-                x = pages[i][h, 0, rows, :]
-                if quant:
-                    # dequantize in VMEM, same element-wise form as the
-                    # lax fallback's gathered dequant (parity pinned in
-                    # interpret)
-                    x = x.astype(jnp.float32) * scales[
-                        hg * heads + h, page_ids[i]]
-                out.append(x)
-            return jnp.concatenate(out, 0)
-
-        for h in range(heads):
-            q = q_ref[0, h]                 # (wp, lanes)
-            for t, parts in enumerate(tiles):
-                k = rows_of(k_refs, ks_ref, h, parts)
-                v = rows_of(v_refs, vs_ref, h, parts)
-                nk = k.shape[0]
-                s = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * sm_scale
-                qi = lax.broadcasted_iota(jnp.int32, (wp, nk), 0)
-                kj = k_start + t * nk + lax.broadcasted_iota(
-                    jnp.int32, (wp, nk), 1)
-                keep = kj < length + qi
-                if npages % pps:
-                    # the last step's pages past the table's width
-                    keep &= kj < npages * psize
-                s = jnp.where(keep, s, -1e30)
-                m_prev = m_scr[h, :, :1]    # (wp, 1)
-                m_new = jnp.maximum(m_prev,
-                                    jnp.max(s, axis=-1, keepdims=True))
-                alpha = jnp.exp(m_prev - m_new)
-                p = jnp.exp(s - m_new)      # (wp, nk) fp32
-                l_new = alpha * l_scr[h, :, :1] + jnp.sum(
-                    p, axis=-1, keepdims=True)
-                acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                m_scr[h] = jnp.broadcast_to(m_new, (wp, 128))
-                l_scr[h] = jnp.broadcast_to(l_new, (wp, 128))
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finalize():
-        # a slot with length 0 (empty decode slot) has l == 0: guard the
-        # divide; its output is garbage the scheduler never reads
-        o_ref[0] = (acc_scr[:] /
-                    jnp.maximum(l_scr[:, :, :1], 1e-30)).astype(o_ref.dtype)
+    st = lax.fori_loop(0, nc_ref[s_idx], body,
+                       (cur[3], cur[0], cur[1], cur[2]))
+    cur[3], cur[0], cur[1], cur[2] = st
+    # a slot with length 0 has every key of its first row masked: p = 1
+    # over keys that are finite, an output nobody reads
+    o_ref[0] = (acc_scr[...] /
+                jnp.maximum(l_scr[:, :, :1], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "sm_scale", "plan", "block_k", "wp", "interpret"))
+    "sm_scale", "chunk", "block_k", "wp", "interpret"))
 def _rpa_pallas(q, k_pages, v_pages, page_tables, lengths, k_scales=None,
-                v_scales=None, *, sm_scale, plan, block_k, wp, interpret):
-    """q: (S, W, H, dh); pools (H, P, psize, lanes), the shape the block
-    specs read: a page's block is its (heads, 1, psize, lanes) of the
-    pool where it lies (a strided DMA of one tile a head), so nothing of
-    a pool's size is made around the kernel; int8 scales (H, P). The
-    grid is `_rpa_steps` of `plan`, what `_rpa_plan` gives a step. The
-    kernel sees a head `lanes` wide: the query's lanes past dh are zero,
-    the output's are dropped. Returns (S, W, H, dh).
+                v_scales=None, *, sm_scale, chunk, block_k, wp, interpret):
+    """q: (S, W, H, dh); pools (H, P, psize, lanes), left in HBM (nothing
+    of a pool's size is made or copied); int8 scales (H, P). One grid step
+    a slot, `chunk` keys (`_rpa_chunk`) a fetch. The kernel sees a head
+    `lanes` wide: the query's lanes past dh are zero, the output's are
+    dropped. Returns (S, W, H, dh).
 
-    Jitted, so that a decoder's layers, which call it at one shape,
-    trace and lower the body and its unrolled heads once and not once a
-    layer. Whatever is decided while tracing comes in as a static
-    argument for that: the plan, the forced knobs (`block_k`,
-    `_rpa_block_k`; `wp`, `_rpa_sublanes`: the query rows padded to the
-    8-sublane tile) and interpret mode."""
+    Jitted, so that a decoder's layers, which call it at one shape, trace
+    and lower the body once and not once a layer. Whatever is decided
+    while tracing comes in as a static argument for that: the chunk, the
+    forced knobs (`block_k`, `_rpa_block_k`; `wp`, `_rpa_sublanes`: the
+    query rows padded to the 8-sublane tile) and interpret mode."""
     S, W, H, dh = q.shape
-    psize, lanes = k_pages.shape[2:]
-    npages = page_tables.shape[1]
+    lanes = k_pages.shape[3]
     quant = k_scales is not None
-    heads, pps = plan
-    groups = H // heads
     qr = q.transpose(0, 2, 1, 3)
     if wp != W or lanes != dh:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, wp - W), (0, lanes - dh)))
-    qr = qr.reshape(S * groups, heads, wp, lanes)
-
-    def page_at(i):
-        # the paged gather: the page id comes from the scalar-prefetched
-        # table, so the DMA fetches exactly the pages the slot owns —
-        # never a dense (S, Lmax) context; entries past a slot's length
-        # are the null page, and a block whose index stays is not
-        # fetched again
-        def index(g, j, pt, ln, *_):
-            s_idx, hg = _rpa_row(g, groups)
-            return hg, pt[s_idx, jnp.minimum(j * pps + i, npages - 1)], 0, 0
-        return index
-    pages = [pl.BlockSpec((heads, 1, psize, lanes), page_at(i))
-             for i in range(pps)]
-    block = pl.BlockSpec((1, heads, wp, lanes),
-                         lambda g, j, pt, ln, *_: (g, 0, 0, 0))
-    scal = (page_tables.astype(jnp.int32), lengths.astype(jnp.int32))
+    block = pl.BlockSpec((1, H, wp, lanes), lambda s, *_: (s, 0, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((_RPA_BUFFERS, H, chunk, lanes), k_pages.dtype)
+    lengths = lengths.astype(jnp.int32)
+    # the chunks that hold a key the last query row sees, at least one
+    keys = page_tables.shape[1] * k_pages.shape[2]
+    n_chunks = jnp.clip((lengths + (W - 1) + (chunk - 1)) // chunk, 1,
+                        -(-keys // chunk))
+    scal = (page_tables.astype(jnp.int32), lengths, n_chunks)
     if quant:
         # (H, P) f32 in SMEM: the kernel reads one scalar a head and page
         scal += (k_scales.astype(jnp.float32),
                  v_scales.astype(jnp.float32))
     out = pl.pallas_call(
-        functools.partial(_rpa_kernel, psize=psize, pps=pps,
-                          block_k=block_k, heads=heads, groups=groups,
-                          window=W, sm_scale=sm_scale, quant=quant),
+        functools.partial(_rpa_kernel, psize=k_pages.shape[2], chunk=chunk,
+                          block_k=block_k, window=W, sm_scale=sm_scale,
+                          quant=quant),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(scal),  # page tables + lengths (+ scales)
-            grid=_rpa_steps(S, H, npages, heads, pps),
-            in_specs=[block] + pages + pages, out_specs=block,
-            scratch_shapes=[pltpu.VMEM((heads, wp, 128), jnp.float32),
-                            pltpu.VMEM((heads, wp, 128), jnp.float32),
-                            pltpu.VMEM((heads, wp, lanes), jnp.float32)]),
-        out_shape=_sds((S * groups, heads, wp, lanes), q.dtype,
-                       q, k_pages, v_pages),
+            num_scalar_prefetch=len(scal),  # tables, lengths, chunks (+ scales)
+            grid=(S,), in_specs=[block, pool, pool], out_specs=block,
+            scratch_shapes=[buf, buf,
+                            pltpu.SemaphoreType.DMA((2, _RPA_BUFFERS)),
+                            pltpu.SMEM((4,), jnp.int32),
+                            pltpu.VMEM((H, wp, 128), jnp.float32),
+                            pltpu.VMEM((H, wp, 128), jnp.float32),
+                            pltpu.VMEM((H, wp, lanes), jnp.float32)]),
+        out_shape=_sds((S, H, wp, lanes), q.dtype, q, k_pages, v_pages),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="mxtpu_rpa",
-    )(*scal, qr, *([k_pages] * pps), *([v_pages] * pps))
-    return out.reshape(S, H, wp, lanes)[:, :, :W, :dh].transpose(0, 2, 1, 3)
+    )(*scal, qr, k_pages, v_pages)
+    return out[:, :, :W, :dh].transpose(0, 2, 1, 3)
 
 
 def _rpa_flat_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
@@ -1490,13 +1517,14 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lengths,
     k_pages/v_pages: fixed-size page pools in the shape their kernel
     reads where they lie, so that a decode program makes nothing of a
     pool's size: head-major (H, P, psize, lanes) for `_rpa_kernel` (a
-    slot's heads and eight of its pages a grid step, a page's block the
-    (H, 1, psize, lanes) of the pool; a row holds the head's dh values
-    and zeros up to `lanes`: KEEP a pool at `pool_lanes(dh)`, any width
-    from dh up is read), or page-major with the heads merged into the
-    lanes, (P, psize, H * dh), the shape to KEEP a pool in when dh is a
-    multiple of 128, because `_rpa_flat_kernel` then takes a slot's
-    heads a grid step and fetches its pages itself;
+    slot and all its heads a grid step, the slot's live pages fetched by
+    the kernel, a page of every head, `pool[:, page]`, one DMA; a row
+    holds the head's dh values and zeros up to `lanes`: KEEP a pool at
+    `pool_lanes(dh)`, any width from dh up is read), or page-major with
+    the heads merged into the lanes, (P, psize, H * dh), the shape to
+    KEEP a pool in when dh is a multiple of 128, because
+    `_rpa_flat_kernel` then reads a KV head's tile once for its whole
+    group of query heads;
     page_tables: (S, npages) int32 page ids per slot (unused entries
     must point at a valid page — the pool's reserved null page 0);
     lengths: (S,) int32 valid cached positions per slot INCLUDING the
@@ -1515,22 +1543,21 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lengths,
     GATHERED context.
 
     On TPU (or MXTPU_PALLAS_INTERPRET=1) runs the Pallas kernel: the page
-    table rides in scalar-prefetch SMEM. Over head-major pools
-    (`mxtpu_rpa`) the BlockSpec index maps read it to DMA the owned pages,
-    skipping the compute of pages beyond each slot's length; over flat
-    pools (`mxtpu_rpa_flat`) the kernel reads it to DMA a slot's pages
-    itself, `_RPA_FLAT_STEP_KEYS` keys at a time up to the slot's length
-    and no further, from the pools left in HBM — mixed-length slots
-    share one launch either way. Elsewhere the pure-lax gather fallback
+    table rides in scalar-prefetch SMEM, and the kernel reads it to DMA
+    a slot's pages itself from the pools left in HBM, a chunk at a time
+    up to the slot's length and no further: `_rpa_chunk` keys over
+    head-major pools (`mxtpu_rpa`), `_RPA_FLAT_STEP_KEYS` over flat pools
+    (`mxtpu_rpa_flat`) — mixed-length slots share one launch either
+    way. Elsewhere the pure-lax gather fallback
     reproduces the same numbers through `single_query_cached_attention`
     (inference-only; no custom vjp).
 
     Tunable knobs (ISSUE 20; MXTPU_RPA_BLOCK_K / MXTPU_RPA_SUBLANES or a
-    tune/overrides.py scope): a sub-page K tile inside a grid step
+    tune/overrides.py scope): a K tile inside a fetched chunk
     (`_rpa_block_k`) and the padded query-row count of the widened form
     (`_rpa_sublanes`). Invalid values fall back loudly
-    (`pallas_block_override_ignored`). The grid itself has no knob: it
-    follows from the shapes (`_rpa_plan`)."""
+    (`pallas_block_override_ignored`). The chunk itself has no knob: it
+    follows from the shapes (`_rpa_chunk`)."""
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     if (k_scales is None) != (v_scales is None):
@@ -1562,12 +1589,12 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, lengths,
     # the one-token decode turn is the W == 1 window of the same kernel
     qw = q if q.ndim == 4 else q[:, None]
     H, _, psize, lanes = k_pages.shape
+    chunk = _rpa_chunk(H, page_tables.shape[1], psize, lanes,
+                       k_pages.dtype.itemsize)
     out = _rpa_pallas(
         qw, k_pages, v_pages, page_tables, lengths, k_scales, v_scales,
-        sm_scale=float(sm_scale), block_k=_rpa_block_k(psize),
-        wp=_rpa_sublanes(qw.shape[1]), interpret=_interpret(),
-        plan=_rpa_plan(H, page_tables.shape[1], psize, lanes,
-                       k_pages.dtype.itemsize))
+        sm_scale=float(sm_scale), chunk=chunk, block_k=_rpa_block_k(chunk),
+        wp=_rpa_sublanes(qw.shape[1]), interpret=_interpret())
     return out if q.ndim == 4 else out[:, 0]
 
 

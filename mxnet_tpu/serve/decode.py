@@ -21,9 +21,10 @@ buffers. The host pays one dispatch a turn (a dispatch a request cost it
 for the filled rows only; `prefill(slot, src)` is the batch of one.
 
 The pools are ONE array a layer, kept head-major `(H, P, psize, lanes)`:
-the shape `mxtpu_rpa`'s block specs read (a page's block is its
-`(H, 1, psize, lanes)`: a slot's heads and eight of its pages make one
-grid step), so the kernel takes a pool where it lies
+the shape `mxtpu_rpa` reads where it lies in HBM (a slot and all its
+heads a grid step; the kernel fetches the slot's live pages itself, a
+page of every head, `pool[:, page]`, one strided DMA; `paged_counters()`
+counts those pages), so the kernel takes a pool where it lies
 and a decode or verify program holds no operation whose result has a
 pool's size (tests/test_tpu_compile.py pins that on the chip's own
 compiler). Two things keep it so. A row is `pool_lanes(dh)` wide, the
@@ -92,7 +93,7 @@ from ..observability import registry as _obs_registry
 from ..observability import tracer as _tracer
 from ..observability import compilex as _compilex
 from ..ops.pallas_kernels import pool_lanes, ragged_paged_attention
-from .kv_pages import NULL_PAGE
+from .kv_pages import NULL_PAGE, count_pages
 
 __all__ = ["DecodeRuntime", "MemoryStateLost"]
 
@@ -289,6 +290,7 @@ class DecodeRuntime:
         # dispatches (`prefill_many`)
         self.prefill_rows = min(self.slots, 32)
         self._m_rows = _obs_registry().counter("serve_prefill_rows")
+        self._paged = {"turns": 0, "live_pages": 0, "table_pages": 0}
         # retrace telemetry: the python bodies run ONLY while jax traces,
         # so these counters are exactly the number of compilations — the
         # check_dispatch serve gate asserts they stay at 1 across every
@@ -462,6 +464,19 @@ class DecodeRuntime:
         return lax.fori_loop(0, jnp.sum(src_len > 0), encode_row,
                              (mem_k, mem_v, mem_vl))
 
+    def paged_counters(self):
+        """The decode turns' always-on counts since the runtime was made:
+        `turns`, the decode and verify launches, `live_pages`, the sum
+        over those launches and their running slots of ceil(seen /
+        page_size), where `seen` is the keys the slot's last real query
+        row attends (len + 1 in a decode turn, len + its window's length
+        in a verify launch): the pages ONE layer's `mxtpu_rpa` had to
+        read, and `table_pages`, the running slots times the page table's
+        width: what a kernel that walks the whole table reads. Counted
+        from the `lens` a launch holds on the host, as
+        `LMRuntime.paged_counters()` counts its own."""
+        return dict(self._paged)
+
     # ---------------------------------------------------------- calls
     def prefill(self, slot, src_tokens, src_len=None):
         """Encode one request's source into decode slot `slot`: the
@@ -543,6 +558,8 @@ class DecodeRuntime:
         never left the device): a scheduler that launches a step before
         it has read the one before feeds the tokens back that way."""
         profiler.record_dispatch("serve_decode")
+        count_pages(self._paged, page_tables, active, np.asarray(lens) + 1,
+                    self.page_size)
         inputs = (jnp.asarray(page_tables, jnp.int32),
                   jnp.asarray(lens, jnp.int32), jnp.asarray(tok, jnp.int32),
                   jnp.asarray(active, jnp.int32), self._last_tok,
@@ -573,6 +590,8 @@ class DecodeRuntime:
             raise MXNetError("decode_multi needs width > 1 (construct "
                              "DecodeRuntime(width=k+1))")
         profiler.record_dispatch("serve_decode")
+        count_pages(self._paged, page_tables, active,
+                    np.asarray(lens) + np.asarray(qlens), self.page_size)
         inputs = (jnp.asarray(page_tables, jnp.int32),
                   jnp.asarray(lens, jnp.int32),
                   jnp.asarray(toks, jnp.int32),

@@ -56,12 +56,27 @@ from __future__ import annotations
 
 import threading
 
+import numpy as np
+
 from ..base import MXNetError
 from ..observability import registry as _obs_registry
 
 __all__ = ["PagePool", "PageAllocError", "NULL_PAGE"]
 
 NULL_PAGE = 0
+
+
+def count_pages(counts, page_tables, active, seen, page_size):
+    """Add one decode launch to a runtime's `paged_counters()` dict:
+    `turns`, `live_pages` (ceil(seen / page_size) over the running slots,
+    `seen` the keys a slot's last query attends) and `table_pages` (the
+    running slots times the page table's width), from the host arrays
+    the launch holds."""
+    run = np.asarray(active) > 0
+    counts["turns"] += 1
+    counts["live_pages"] += int((-(-np.asarray(seen)[run] // page_size))
+                                .sum())
+    counts["table_pages"] += int(run.sum()) * np.shape(page_tables)[1]
 
 
 class PageAllocError(MXNetError):

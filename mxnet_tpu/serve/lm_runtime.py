@@ -81,7 +81,7 @@ from ..observability import registry as _obs_registry
 from ..observability import tracer as _tracer
 from ..observability import compilex as _compilex
 from .decode import MemoryStateLost, _raised
-from .kv_pages import NULL_PAGE
+from .kv_pages import NULL_PAGE, count_pages
 
 __all__ = ["LMRuntime"]
 
@@ -734,11 +734,8 @@ class LMRuntime:
             self._win["ring_tokens"] += int(np.minimum(
                 seen, self.spec.window).sum())
         if self._n["kv"]:
-            self._paged["turns"] += 1
-            self._paged["live_pages"] += int((-(-seen // self.page_size))
-                                             .sum())
-            self._paged["table_pages"] += int(run.sum()) * np.shape(
-                page_tables)[1]
+            count_pages(self._paged, page_tables, active,
+                        np.asarray(lens) + 1, self.page_size)
         (self._state, next_tok, logits, counts,
          self.routing["decode"]) = self._decode_fn(
             self._state, self._w, jnp.asarray(page_tables, jnp.int32),

@@ -123,23 +123,45 @@ def _paged_fixture(seed=0, S=3, H=2, dh=8, P=9, psize=8, npages=2,
     return q, kp, vp, pt, lens
 
 
-# what one grid step's heads and pages do not divide (PR 31): 11 pages a
-# slot under 8 a step, an empty slot beside one that fills every page,
-# five heads; and a fast-memory budget that holds three of six heads and
-# one page a step
+# the chunks a slot's pages are fetched in: 11 pages a slot, an
+# empty slot beside one that fills every page, five heads, the table in
+# one chunk (`_RAGGED`) or in chunks of 3 pages that cut slots and do not
+# divide the table (`_CHUNKS`); a slot that fills 12 pages in whole
+# chunks of 4 between empty slots (`_FULL`); two slots that share a
+# prefix page (`_SHARED`); a chunk of 2 pages over a table of 7 that one
+# slot fills (`_UNEVEN`); and a fast-memory budget in which a chunk is
+# half a page (`_SPLIT`)
 _RAGGED = dict(H=5, npages=11, lens=(0, 88, 37, 1))
-_SPLIT = dict(H=6, npages=3, lens=(24, 0, 9), vmem=3 * 1024)
+_CHUNKS = dict(_RAGGED, pages_a_chunk=3)
+_FULL = dict(H=3, npages=12, lens=(1, 96, 0, 1), pages_a_chunk=4)
+_SHARED = dict(H=2, npages=4, lens=(30, 21, 5), pages_a_chunk=1, share=True)
+_UNEVEN = dict(H=4, npages=7, lens=(56, 17, 0), pages_a_chunk=2)
+_SPLIT = dict(H=6, npages=3, lens=(40, 0, 9), psize=16, split=8)
 
 
 def _ragged_case(monkeypatch, H, npages, lens, psize=8, W=None, int8=False,
-                 vmem=None, dh=8, seed=5):
+                 split=None, pages_a_chunk=None, share=False, dh=8, seed=5):
     """(q, kp, vp, pt, lens, scales): a slot's live pages in table order,
-    the null page behind them as the scheduler leaves it; `vmem` shrinks
-    the kernel's budget so that `_rpa_plan` has to split."""
+    the null page behind them as the scheduler leaves it; `split` shrinks
+    the kernel's budget so that a buffer holds that many keys, a part of a
+    page; `pages_a_chunk` sets the bytes a chunk fetches to that many
+    pages; `share` gives slot 1 slot 0's first page, as a cached prefix
+    does."""
     import jax.numpy as jnp
     from mxnet_tpu.ops import pallas_kernels as pk
-    if vmem:
-        monkeypatch.setattr(pk, "_RPA_VMEM_BUDGET", vmem)
+    item = 1 if int8 else 4
+    if split:
+        monkeypatch.setattr(pk, "_RPA_VMEM_BUDGET",
+                            2 * pk._RPA_BUFFERS * split * H * dh * item)
+        # an int8 page of 16 rows is less than its 32-row tile: whole
+        assert pk._rpa_chunk(H, npages, psize, dh, item) == (
+            psize if int8 else split)
+    if pages_a_chunk:
+        tile = 8 * max(1, 4 // item)
+        page = -(-psize // tile) * tile * H * dh * item
+        monkeypatch.setattr(pk, "_RPA_STEP_BYTES", pages_a_chunk * page)
+        assert pk._rpa_chunk(H, npages, psize, dh, item) == \
+            pages_a_chunk * psize
     rng = np.random.RandomState(seed)
     S = len(lens)
     live = [-(-n // psize) for n in lens]
@@ -148,6 +170,8 @@ def _ragged_case(monkeypatch, H, npages, lens, psize=8, W=None, int8=False,
     ids = iter(rng.permutation(sum(live)) + 1)
     for s, n in enumerate(live):
         pt[s, :n] = [next(ids) for _ in range(n)]
+    if share:
+        pt[1, 0] = pt[0, 0]
     shape = (H, sum(live) + 1, psize, dh)
     if int8:
         kp, vp = (rng.randint(-127, 128, shape).astype(np.int8)
@@ -199,19 +223,22 @@ def test_paged_attention_lax_matches_shared_math(lanes):
 @pytest.mark.parametrize("cfg", [
     {}, {"rpa_block_k": 8}, {"lanes": 128}, {"ragged": _RAGGED},
     {"ragged": dict(_RAGGED, psize=16, lens=(0, 176, 37, 1)),
-     "rpa_block_k": 8}, {"ragged": _SPLIT}],
+     "rpa_block_k": 8}, {"ragged": _SPLIT}, {"ragged": _CHUNKS},
+    {"ragged": _FULL}, {"ragged": _SHARED}, {"ragged": _UNEVEN},
+    {"ragged": _CHUNKS, "rpa_block_k": 8}],
     ids=["default", "block_k=8", "lanes=128", "ragged", "ragged-block_k=8",
-         "split"])
+         "split", "chunks", "full", "shared", "uneven", "chunks-block_k=8"])
 def test_paged_attention_kernel_interpret(monkeypatch, cfg):
     """The Pallas ragged-paged kernel numerics, pinned on CPU via
     interpret mode (same harness as the flash-kernel tests) — at the
     default block config AND under the ISSUE 20 `rpa_block_k` tuning
-    knob (psize=16 fixture so a sub-page tile is legal), AND over pools
-    whose rows are whole lane tiles, as the server keeps them (the
+    knob (psize=16 fixture so a tile under the chunk is legal), AND over
+    pools whose rows are whole lane tiles, as the server keeps them (the
     lanes past the head hold sevens): every reachable block config
-    must reproduce the lax fallback. So must the shapes a grid step of
-    all a slot's heads and eight of its pages does not divide
-    (`_RAGGED`, `_SPLIT`)."""
+    must reproduce the lax fallback. So must the chunks a slot's pages
+    are fetched in: chunk edges inside a slot and past the table's
+    width, a full slot between empty ones, a shared prefix page, and a
+    chunk of half a page (`_RAGGED` and its siblings, `_SPLIT`)."""
     monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
     from mxnet_tpu.ops.pallas_kernels import (_paged_attention_lax,
                                               ragged_paged_attention)
@@ -230,6 +257,35 @@ def test_paged_attention_kernel_interpret(monkeypatch, cfg):
         out_k = ragged_paged_attention(q, kp, vp, pt, lens)
     ref = _paged_attention_lax(q, kp, vp, pt, lens)
     _assert_seen_rows_close(out_k, ref, lens)
+
+
+def test_decode_runtime_paged_counters_count_the_live_pages():
+    """`DecodeRuntime.paged_counters()` from the `lens` each launch holds:
+    a running slot reads ceil((len + 1) / page_size) pages in a decode
+    turn and ceil((len + qlen) / page_size) in a verify launch, of a
+    table `max_pages_per_slot` wide; an empty slot reads none."""
+    from mxnet_tpu.models.transformer import decoder_weights, encoder_weights
+    from mxnet_tpu.serve.decode import DecodeRuntime
+    model = _tiny_model()
+    rt = DecodeRuntime(decoder_weights(model), encoder_weights(model),
+                       slots=3, num_pages=13, page_size=4,
+                       max_pages_per_slot=4, max_src_len=8, width=3)
+    assert rt.paged_counters() == {"turns": 0, "live_pages": 0,
+                                   "table_pages": 0}
+    tables = np.zeros((3, 4), np.int32)
+    tok = np.zeros((3,), np.int32)
+    for lens, active in (([0, 3, 9], [1, 1, 0]), ([1, 4, 15], [1, 2, 1])):
+        rt.decode(tables, np.asarray(lens, np.int32), tok,
+                  np.asarray(active, np.int32))
+    # ceil((len + 1) / 4) of the five running slots: 1, 1, 1, 2, 4
+    assert rt.paged_counters() == {"turns": 2, "live_pages": 9,
+                                   "table_pages": 5 * 4}
+    rt.decode_multi(tables, np.array([2, 6, 0], np.int32),
+                    np.zeros((3, 3), np.int32), np.array([3, 1, 1], np.int32),
+                    np.array([1, 1, 0], np.int32))
+    # ceil((len + qlen) / 4) of the two running slots: 2, 2
+    assert rt.paged_counters() == {"turns": 3, "live_pages": 13,
+                                   "table_pages": 7 * 4}
 
 
 # --------------------------------------------------- decode-path parity
@@ -1190,9 +1246,10 @@ def test_paged_attention_multi_rowwise_matches_single():
 @pytest.mark.parametrize("cfg", [
     {}, {"rpa_sublanes": 16}, {"rpa_block_k": 8},
     {"ragged": dict(_RAGGED, W=3)}, {"ragged": dict(_RAGGED, W=1)},
-    {"ragged": dict(_SPLIT, W=3)}],
+    {"ragged": dict(_SPLIT, W=3)}, {"ragged": dict(_CHUNKS, W=4)},
+    {"ragged": dict(_UNEVEN, W=4)}],
     ids=["default", "sublanes=16", "block_k=8", "ragged-W=3", "ragged-W=1",
-         "split-W=3"])
+         "split-W=3", "chunks-W=4", "uneven-W=4"])
 def test_paged_attention_multi_kernel_interpret(monkeypatch, cfg):
     """The widened Pallas kernel numerics, pinned on CPU via interpret
     mode against the lax fallback (same harness as the 1-wide test) —
@@ -1437,8 +1494,9 @@ def test_weight_int8_serve_matches_fp32():
 
 
 @pytest.mark.parametrize("tables", [dict(H=2, npages=2, lens=(12, 5, 16)),
-                                    _RAGGED, _SPLIT],
-                         ids=["fixture", "ragged", "split"])
+                                    _RAGGED, _SPLIT, _CHUNKS, _SHARED],
+                         ids=["fixture", "ragged", "split", "chunks",
+                              "shared"])
 @pytest.mark.parametrize("W", [None, 1, 3], ids=["single", "W=1", "W=3"])
 def test_paged_attention_quant_kernel_interpret(monkeypatch, tables, W):
     """The quantised Pallas kernels' numerics (scales via scalar
