@@ -11,12 +11,6 @@ one.
 Knob names (values are ints):
 
   flash_block_q / flash_block_k   flash attention Q/K tile sizes
-  rpa_block_k                     ragged-paged-attention sub-page K
-                                  tile inside a grid step (divides
-                                  page size, %8 == 0)
-  rpa_sublanes                    padded query-row count of the WIDENED
-                                  (multi-query verify) RPA launch
-                                  (>= W, %8 == 0)
 
 This module is import-light on purpose (stdlib only): pallas_kernels
 imports it at module top without creating a cycle.
@@ -28,7 +22,7 @@ from contextlib import contextmanager
 
 __all__ = ["scope", "current", "KNOBS"]
 
-KNOBS = ("flash_block_q", "flash_block_k", "rpa_block_k", "rpa_sublanes")
+KNOBS = ("flash_block_q", "flash_block_k")
 
 _tl = threading.local()
 

@@ -8,7 +8,7 @@ Layout (`autotune_winners.json` in the store directory):
        "<executable>|<platform>|<shape_class>": {
           "executable": ..., "platform": ..., "shape_class": ...,
           "jax": "0.4.37", "jaxlib": "0.4.36", "plan": null | "<sig>",
-          "pallas": {"rpa_block_k": 8, ...},       # overrides.KNOBS
+          "pallas": {"flash_block_q": 256, ...},  # overrides.KNOBS
           "flags": {"xla_...": true, ...},         # XLA compiler_options
           "score_ms": 1.23, "baseline_ms": 1.50, "trials": 5,
           "hlo": {"fusions": ..., "copies": ...},  # winner's counters

@@ -226,30 +226,25 @@ def test_search_rejects_dead_pallas_override():
     os.environ["MXTPU_PALLAS_INTERPRET"] = "1"
     try:
         rng = np.random.RandomState(0)
-        q = jnp.asarray(rng.randn(2, 2, 8).astype(np.float32))
-        kp = jnp.asarray(rng.randn(2, 5, 16, 8).astype(np.float32))
-        vp = jnp.asarray(rng.randn(2, 5, 16, 8).astype(np.float32))
-        pt = jnp.asarray(np.array([[1, 2], [3, 0]], np.int32))
-        ln = jnp.asarray(np.array([20, 7], np.int32))
+        q, k, v = (jnp.asarray(rng.randn(1, 2, 128, 32).astype(np.float32))
+                   for _ in range(3))
 
         ij = compilex.instrument(
-            jax.jit(lambda *a: pk.ragged_paged_attention(*a)),
-            "tune_toy_rpa")
-        wl = tune.Workload(ij, lambda: ((q, kp, vp, pt, ln), {}),
+            jax.jit(lambda *a: pk.flash_attention(*a)), "tune_toy_flash")
+        wl = tune.Workload(ij, lambda: ((q, k, v), {}),
                            contract=("allclose", 2e-6, 2e-6))
         res = tune.search(wl, candidates=[
-            # 12 does not divide psize=16 and is not a multiple of 8:
-            # the picker falls back to the default and says so
-            tune.Candidate("pallas:dead", pallas={"rpa_block_k": 12}),
-            tune.Candidate("pallas:bk8", pallas={"rpa_block_k": 8}),
+            # 96 does not divide the 128-long sequence: the picker falls
+            # back to the default and says so
+            tune.Candidate("pallas:dead", pallas={"flash_block_q": 96}),
+            tune.Candidate("pallas:bq128", pallas={"flash_block_q": 128}),
         ], trials=1)
         by_name = {r.candidate.name: r for r in res.candidates}
         assert by_name["pallas:dead"].rejected == "dead_pallas_override"
         # the VALID block config was read, compiled and honestly judged
-        # (its tile loop inside a grid step is more copies in the
-        # interpreter's HLO, which guard 2 may refuse: not a dead knob)
-        assert by_name["pallas:bk8"].rejected in (None,) or \
-            by_name["pallas:bk8"].rejected.startswith(
+        # (guard 2 may still refuse its HLO: not a dead knob)
+        assert by_name["pallas:bq128"].rejected in (None,) or \
+            by_name["pallas:bq128"].rejected.startswith(
                 ("numerics", "hlo_regression: copies"))
     finally:
         if prev is None:

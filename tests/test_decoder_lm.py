@@ -677,26 +677,33 @@ def test_paged_attention_with_grouped_kv_heads(form, monkeypatch):
 
 
 # the flat-pool kernel fetches a slot's pages itself, `cpp` a chunk (here
-# forced small through `_RPA_FLAT_STEP_KEYS`), over 11-page tables
-@pytest.mark.parametrize("lens,hq,window,step_keys", [
-    ((1, 16, 48, 176), 8, 1, 48),
-    ((176, 175, 33, 2), 8, 1, 32),
-    ((1, 176, 1, 150), 8, 1, 48),
-    ((5, 64, 130, 176), 16, 1, 64),
-    ((5, 64, 130, 176), 32, 1, 64),
-    ((1, 16, 47, 174), 8, 3, 48),
+# forced small through `_RPA_FLAT_STEP_KEYS`), over 11-page tables, into
+# a ring of `depth` buffers (`_RPA_FLAT_BUFFERS`)
+@pytest.mark.parametrize("lens,hq,window,step_keys,depth", [
+    ((1, 16, 48, 176), 8, 1, 48, 2),
+    ((176, 175, 33, 2), 8, 1, 32, 2),
+    ((1, 176, 1, 150), 8, 1, 48, 2),
+    ((5, 64, 130, 176), 16, 1, 64, 2),
+    ((5, 64, 130, 176), 32, 1, 64, 2),
+    ((1, 16, 47, 174), 8, 3, 48, 2),
+    ((176, 175, 33, 2), 8, 1, 32, 3),
+    ((1, 176, 1, 150), 8, 1, 48, 4),
+    ((1, 16, 47, 174), 8, 3, 48, 4),
 ], ids=["one-key-page-edge-chunk-edge-whole-table",
         "chunks-that-do-not-divide-the-table", "one-key-then-a-long-slot",
         "8-query-heads-a-kv-head", "16-query-heads-a-kv-head",
-        "a-window-of-3"])
+        "a-window-of-3", "a-ring-of-3-chunks-that-do-not-divide-the-table",
+        "a-ring-of-4-one-chunk-slots", "a-ring-of-4-a-window-of-3"])
 def test_flat_pool_kernel_fetches_the_live_pages(lens, hq, window,
-                                                 step_keys, interpret,
+                                                 step_keys, depth, interpret,
                                                  monkeypatch):
     """Ragged lengths in one launch, a last chunk past the table's width,
-    the next slot's first chunk fetched under a one-chunk slot (the
-    buffers' parity flips), grouped query heads, and the widened form
-    (query i sees lens + i keys), each against the dense attention."""
+    the next slots' first chunks fetched under one-chunk slots (the ring
+    runs ahead across slot edges), grouped query heads, and the widened
+    form (query i sees lens + i keys), each against the dense attention,
+    with the page walker's ring two, three and four buffers deep."""
     monkeypatch.setattr(pk, "_RPA_FLAT_STEP_KEYS", step_keys)
+    monkeypatch.setattr(pk, "_RPA_FLAT_BUFFERS", depth)
     rng = np.random.default_rng(step_keys + hq + window)
     q, kp, vp, tables, lens = _paged_case(rng, jnp.float32, lens=lens,
                                           hq=hq)

@@ -221,41 +221,38 @@ def test_paged_attention_lax_matches_shared_math(lanes):
 
 
 @pytest.mark.parametrize("cfg", [
-    {}, {"rpa_block_k": 8}, {"lanes": 128}, {"ragged": _RAGGED},
+    {}, {"psize": 16, "buffers": 3}, {"lanes": 128}, {"ragged": _RAGGED},
     {"ragged": dict(_RAGGED, psize=16, lens=(0, 176, 37, 1)),
-     "rpa_block_k": 8}, {"ragged": _SPLIT}, {"ragged": _CHUNKS},
+     "buffers": 2}, {"ragged": _SPLIT}, {"ragged": _CHUNKS},
     {"ragged": _FULL}, {"ragged": _SHARED}, {"ragged": _UNEVEN},
-    {"ragged": _CHUNKS, "rpa_block_k": 8}],
-    ids=["default", "block_k=8", "lanes=128", "ragged", "ragged-block_k=8",
-         "split", "chunks", "full", "shared", "uneven", "chunks-block_k=8"])
+    {"ragged": _CHUNKS, "buffers": 3}],
+    ids=["default", "buffers=3", "lanes=128", "ragged", "ragged-buffers=2",
+         "split", "chunks", "full", "shared", "uneven", "chunks-buffers=3"])
 def test_paged_attention_kernel_interpret(monkeypatch, cfg):
     """The Pallas ragged-paged kernel numerics, pinned on CPU via
-    interpret mode (same harness as the flash-kernel tests) — at the
-    default block config AND under the ISSUE 20 `rpa_block_k` tuning
-    knob (psize=16 fixture so a tile under the chunk is legal), AND over
-    pools whose rows are whole lane tiles, as the server keeps them (the
-    lanes past the head hold sevens): every reachable block config
-    must reproduce the lax fallback. So must the chunks a slot's pages
-    are fetched in: chunk edges inside a slot and past the table's
-    width, a full slot between empty ones, a shared prefix page, and a
-    chunk of half a page (`_RAGGED` and its siblings, `_SPLIT`)."""
+    interpret mode (same harness as the flash-kernel tests), over pools
+    whose rows are whole lane tiles, as the server keeps them (the lanes
+    past the head hold sevens), and with the page walker's ring two and
+    three buffers deep as well as `_RPA_BUFFERS`: every form must
+    reproduce the lax fallback. So must the chunks a slot's pages are
+    fetched in: chunk edges inside a slot and past the table's width, a
+    full slot between empty ones, a shared prefix page, and a chunk of
+    half a page (`_RAGGED` and its siblings, `_SPLIT`)."""
     monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
-    from mxnet_tpu.ops.pallas_kernels import (_paged_attention_lax,
-                                              ragged_paged_attention)
-    from mxnet_tpu.tune import overrides
+    from mxnet_tpu.ops import pallas_kernels as pk
     cfg = dict(cfg)
+    if "buffers" in cfg:
+        monkeypatch.setattr(pk, "_RPA_BUFFERS", cfg.pop("buffers"))
     if "ragged" in cfg:
         q, kp, vp, pt, lens, _ = _ragged_case(monkeypatch,
                                               **cfg.pop("ragged"))
     else:
-        q, kp, vp, pt, lens = _paged_fixture(
-            psize=16 if "rpa_block_k" in cfg else 8,
-            lanes=cfg.pop("lanes", 0))
-        if "rpa_block_k" in cfg:
-            lens = lens * 2          # reach into the second K block
-    with overrides.scope(cfg):
-        out_k = ragged_paged_attention(q, kp, vp, pt, lens)
-    ref = _paged_attention_lax(q, kp, vp, pt, lens)
+        q, kp, vp, pt, lens = _paged_fixture(psize=cfg.get("psize", 8),
+                                             lanes=cfg.get("lanes", 0))
+        if "psize" in cfg:
+            lens = lens * 2          # reach into the second page
+    out_k = pk.ragged_paged_attention(q, kp, vp, pt, lens)
+    ref = pk._paged_attention_lax(q, kp, vp, pt, lens)
     _assert_seen_rows_close(out_k, ref, lens)
 
 
@@ -1244,35 +1241,32 @@ def test_paged_attention_multi_rowwise_matches_single():
 
 
 @pytest.mark.parametrize("cfg", [
-    {}, {"rpa_sublanes": 16}, {"rpa_block_k": 8},
+    {}, {"buffers": 2}, {"psize": 16, "buffers": 3},
     {"ragged": dict(_RAGGED, W=3)}, {"ragged": dict(_RAGGED, W=1)},
     {"ragged": dict(_SPLIT, W=3)}, {"ragged": dict(_CHUNKS, W=4)},
     {"ragged": dict(_UNEVEN, W=4)}],
-    ids=["default", "sublanes=16", "block_k=8", "ragged-W=3", "ragged-W=1",
+    ids=["default", "buffers=2", "buffers=3", "ragged-W=3", "ragged-W=1",
          "split-W=3", "chunks-W=4", "uneven-W=4"])
 def test_paged_attention_multi_kernel_interpret(monkeypatch, cfg):
     """The widened Pallas kernel numerics, pinned on CPU via interpret
-    mode against the lax fallback (same harness as the 1-wide test) —
-    at the default config AND under the ISSUE 20 tuning knobs (padded
-    query-sublane count, sub-page K tile)."""
+    mode against the lax fallback (same harness as the 1-wide test), with
+    the page walker's ring `_RPA_BUFFERS`, two and three buffers deep."""
     monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
     import jax.numpy as jnp
-    from mxnet_tpu.ops.pallas_kernels import (_paged_attention_lax_multi,
-                                              ragged_paged_attention)
-    from mxnet_tpu.tune import overrides
+    from mxnet_tpu.ops import pallas_kernels as pk
     cfg = dict(cfg)
+    if "buffers" in cfg:
+        monkeypatch.setattr(pk, "_RPA_BUFFERS", cfg.pop("buffers"))
     if "ragged" in cfg:
         q, kp, vp, pt, lens, _ = _ragged_case(monkeypatch,
                                               **cfg.pop("ragged"))
     else:
-        q1, kp, vp, pt, lens = (_paged_fixture() if "rpa_block_k" not in cfg
-                                else _paged_fixture(psize=16))
+        q1, kp, vp, pt, lens = _paged_fixture(psize=cfg.get("psize", 8))
         S, H, dh = q1.shape
         rng = np.random.RandomState(22)
         q = jnp.asarray(rng.randn(S, 4, H, dh).astype(np.float32))
-    with overrides.scope(cfg):
-        out_k = ragged_paged_attention(q, kp, vp, pt, lens)
-    ref = _paged_attention_lax_multi(q, kp, vp, pt, lens)
+    out_k = pk.ragged_paged_attention(q, kp, vp, pt, lens)
+    ref = pk._paged_attention_lax_multi(q, kp, vp, pt, lens)
     _assert_seen_rows_close(out_k, ref, lens)
 
 

@@ -51,7 +51,6 @@ def chip_compile(one_chip, monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
     monkeypatch.setattr(pk, "on_tpu", lambda: True)
     monkeypatch.delenv("MXTPU_PALLAS_INTERPRET", raising=False)
-    monkeypatch.delenv("MXTPU_PALLAS_DISABLE", raising=False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()

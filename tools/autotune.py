@@ -5,15 +5,10 @@ Builds the framework's OWN gated executables — the reference-MLP
 captured training step (the check_fusion/check_dispatch zoo model) and
 the tiny-transformer serve decode turn — records one real dispatch of
 each into a replayable workload (`tune.capture_workload`), then runs
-the measured search (`tune.search`) over both compile-space dimensions:
-
-  * the curated XLA flag allowlist (`tune.default_flag_candidates`),
-  * the Pallas block knobs: `rpa_block_k` for the paged-decode kernel
-    (and `rpa_sublanes` for the widened verify form under `--spec`).
-    On a CPU mesh without `--interpret` the serve path runs the pure-
-    lax fallback, so the Pallas knobs are never read — those candidates
-    are reported `inert` and skipped instead of being measured under a
-    wrong label.
+the measured search (`tune.search`) over the curated XLA flag allowlist
+(`tune.default_flag_candidates`). Neither executable runs a kernel with
+a block knob (`tune.overrides.KNOBS` are the flash kernels'), so the
+search has no Pallas dimension here.
 
 Each executable's check_fusion BUDGETS row rides along as guard 1, so
 a winner here is by construction a build the tier-1 fusion gate would
@@ -82,8 +77,7 @@ def _serve_workloads(spec=False):
     """The check_fusion tiny-transformer server, warmed through one
     request, with the decode turn of a second request recorded.
     `spec=True` uses a speculative server instead and records the
-    widened `serve_verify` executable (the multi-query kernel form the
-    `rpa_sublanes` knob feeds)."""
+    widened `serve_verify` executable (the multi-query kernel form)."""
     import numpy as np
 
     import mxnet_tpu as mx
@@ -117,41 +111,13 @@ def _serve_workloads(spec=False):
     return wl, srv
 
 
-# ---------------------------------------------------------- candidates
-def _pallas_candidates(executable, page_size):
-    """The Pallas dimension for the serve executables, or (inert
-    candidates, reason) when the kernel path is not live — the lax
-    fallback never reads the knobs, so measuring them would label the
-    default build as a block-size experiment."""
-    from mxnet_tpu.ops import pallas_kernels as _pk
-    from mxnet_tpu.tune import Candidate
-
-    cands = []
-    if executable in ("serve_decode", "serve_verify"):
-        for bk in (8, page_size // 2):
-            if bk % 8 == 0 and 8 <= bk <= page_size \
-                    and page_size % bk == 0 and bk != page_size:
-                c = Candidate(f"pallas:rpa_block_k={bk}",
-                              pallas={"rpa_block_k": bk})
-                if c not in cands:
-                    cands.append(c)
-    if executable == "serve_verify":
-        cands.append(Candidate("pallas:rpa_sublanes=16",
-                               pallas={"rpa_sublanes": 16}))
-    if not cands:
-        return [], None
-    if not _pk._rpa_pallas_ok(page_size):
-        return cands, "lax fallback live (no TPU, no --interpret)"
-    return cands, None
-
-
 # ----------------------------------------------------------------- run
-def _search_one(name, wl, extra_cands, inert, trials, store):
+def _search_one(name, wl, trials, store):
     from mxnet_tpu import tune
     from check_fusion import BUDGETS
 
     budget = BUDGETS.get(name)
-    cands = tune.default_flag_candidates() + list(extra_cands)
+    cands = tune.default_flag_candidates()
     _log(f"[autotune] {name}: {len(cands)} candidate(s) + baseline, "
          f"trials={trials}, budget={'yes' if budget else 'no'}")
     res = tune.search(wl, candidates=cands, trials=trials,
@@ -175,8 +141,6 @@ def _search_one(name, wl, extra_cands, inert, trials, store):
         "rejected": {c.candidate.name: c.rejected
                      for c in res.candidates if c.rejected},
     }
-    if inert:
-        summary["inert_pallas"] = inert
     _log(f"[autotune] {name}: winner={summary['winner']} "
          f"({summary['baseline_ms']}ms -> {summary['winner_ms']}ms, "
          f"x{summary['speedup']})")
@@ -192,9 +156,6 @@ def main(argv=None):
     ap.add_argument("--trials", type=int, default=5,
                     help="timed dispatches per candidate (median "
                          "scored)")
-    ap.add_argument("--interpret", action="store_true",
-                    help="run Pallas kernels in interpret mode so the "
-                         "block-size dimension is live on a CPU mesh")
     ap.add_argument("--spec", action="store_true",
                     help="tune the speculative serve_verify executable "
                          "(multi-query kernel form) instead of "
@@ -202,9 +163,6 @@ def main(argv=None):
     ap.add_argument("--skip-serve", action="store_true",
                     help="tune only the captured training step")
     args = ap.parse_args(argv)
-
-    if args.interpret:
-        os.environ["MXTPU_PALLAS_INTERPRET"] = "1"
 
     from mxnet_tpu.tune import TuneStore
     store = TuneStore(args.dir)
@@ -222,7 +180,7 @@ def main(argv=None):
         failures += 1
     else:
         out["results"].append(_search_one(
-            "captured_step", wl, [], None, args.trials, store))
+            "captured_step", wl, args.trials, store))
 
     if not args.skip_serve:
         exe = "serve_verify" if args.spec else "serve_decode"
@@ -231,13 +189,7 @@ def main(argv=None):
             _log(f"[autotune] {exe} dispatch was not recorded")
             failures += 1
         else:
-            pall, inert = _pallas_candidates(exe, page_size=16)
-            if inert:
-                _log(f"[autotune] {exe}: {len(pall)} Pallas candidate(s)"
-                     f" inert — {inert}")
-                pall = []
-            out["results"].append(_search_one(
-                exe, wl, pall, inert, args.trials, store))
+            out["results"].append(_search_one(exe, wl, args.trials, store))
         srv.close()
 
     if any(r["persisted"] for r in out["results"]):
