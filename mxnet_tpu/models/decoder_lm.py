@@ -4,7 +4,9 @@ mixers: softmax attention with grouped KV heads over every position
 the last `window` positions (`"swa"`), both without a positional term or,
 with `LMSpec.attn_rope`, with q and k rotated over the whole head (plain
 RoPE on window layers, YaRN-scaled on full layers where `rope_yarn` is
-given); Kimi Delta Attention linear-attention layers (`"kda"`),
+given; on every attention layer, or on the kinds it names), each head of
+q and k RMS-normed first where `LMSpec.qk_norm` says so; Kimi Delta
+Attention linear-attention layers (`"kda"`),
 multi-head latent attention with a rotary term (`"mla"`) and Mamba-2
 state-space layers (`"mamba"`); and a PARALLEL layer of two of them,
 `"mamba+gqa"`: a Mamba-2 and a `"gqa"` mixer on one normed input, each
@@ -55,12 +57,12 @@ from ..ops import grouped_matmul as gmm
 from ..ops import kda as kda_ops
 from ..ops import ssd as ssd_ops
 from ..ops.nn_ops import dense_nt as _mm, rms_norm, swiglu
-from ..ops.pallas_kernels import flash_attention
+from ..ops.pallas_kernels import flash_attention, ring_pages_for
 
 __all__ = ["LMSpec", "DecoderLM", "GatedAttention", "WindowAttention",
            "KDALayer", "LatentAttention", "Mamba2Layer", "DenseFFN",
            "MoELayer", "lm_weights", "moe_forward", "moe_route", "rope",
-           "attn_rope_table", "ring_pages_for", "mla_sequence",
+           "attn_rope_table", "mla_sequence",
            "mamba_sequence", "ParallelMixer", "PAR", "par_mults",
            "dense_ffn", "mx_gqa", "mx_swa", "mx_kda", "mx_mla",
            "mx_mamba", "mx_par", "mx_par_ssm", "mx_par_attn", "mx_moe",
@@ -118,11 +120,17 @@ class LMSpec(NamedTuple):
     # < j <= t
     window: int = 0
     # the positional term of "gqa" and "swa" layers: q and k rotated over
-    # the whole head at `rope_theta`; on "gqa" (full) layers YaRN-scaled
-    # where `rope_yarn` = (factor, original_max_position_embeddings,
-    # beta_fast, beta_slow, attention_factor)
-    attn_rope: bool = False
+    # the whole head at `rope_theta`, on every such layer (True) or on the
+    # layer kinds named (("swa",): window layers rotate, full layers have
+    # no positional term); on "gqa" (full) layers YaRN-scaled where
+    # `rope_yarn` = (factor, original_max_position_embeddings, beta_fast,
+    # beta_slow, attention_factor)
+    attn_rope: bool | tuple = False
     rope_yarn: tuple = ()
+    # a per-head RMSNorm on q and on k of "gqa" and "swa" layers (gains
+    # `q_norm_gamma`, `k_norm_gamma` over a head's dh values), before any
+    # rotation and before k is cached
+    qk_norm: bool = False
     # the expert layer's router: "sigmoid" | "softmax" over all experts;
     # and whether a shared expert is added to the routed sum
     router_score: str = "sigmoid"
@@ -140,6 +148,13 @@ class LMSpec(NamedTuple):
 
     def ffn_kinds(self):
         return self.ffn or ("moe",) * len(self.pattern)
+
+    def rotates(self, kind):
+        """Whether q and k of an attention layer of `kind` ("gqa" or
+        "swa") are rotated (`attn_rope`)."""
+        if isinstance(self.attn_rope, tuple):
+            return kind in self.attn_rope
+        return bool(self.attn_rope)
 
     def sublayers(self):
         """(mixer or None, feed-forward or None) for each layer."""
@@ -329,10 +344,11 @@ def attn_rope_table(spec, kind):
 
 def gqa_project(w, spec, x, pos=None, kind="gqa"):
     """x (T, d) -> q (T, H, dh), output gate (T, H * dh) or None where
-    the spec has none, k, v (T, Hkv, dh). With `spec.attn_rope` q and k
-    are rotated to their positions pos (T,) by the table of the layer's
-    `kind` (`attn_rope_table`); else no positional term. k is times
-    `spec.key_mult`, as a cache keeps it."""
+    the spec has none, k, v (T, Hkv, dh). With `spec.qk_norm` each head
+    of q and of k is RMS-normed first. Where the spec rotates the
+    layer's `kind` (`LMSpec.rotates`) q and k are rotated to their
+    positions pos (T,) by that kind's table (`attn_rope_table`); else no
+    positional term. k is times `spec.key_mult`, as a cache keeps it."""
     h, hk, dh = spec.heads, spec.kv_heads, spec.head_dim
     if spec.attn_gate:
         q, gate, k, v = jnp.split(
@@ -344,7 +360,10 @@ def gqa_project(w, spec, x, pos=None, kind="gqa"):
                             [h * dh, (h + hk) * dh], -1)
     t = x.shape[0]
     q, k = q.reshape(t, h, dh), k.reshape(t, hk, dh)
-    if spec.attn_rope:
+    if spec.qk_norm:
+        q = rms_norm(q, w["q_norm_gamma"], spec.eps)
+        k = rms_norm(k, w["k_norm_gamma"], spec.eps)
+    if spec.rotates(kind):
         inv, m = attn_rope_table(spec, kind)
         q, k = rotate(q, pos, inv, m), rotate(k, pos, inv, m)
     return q, gate, _times(k, spec.key_mult), v.reshape(t, hk, dh)
@@ -372,10 +391,10 @@ def gqa_sequence(w, spec, x, kind="gqa"):
     return gqa_output(w, a[0].transpose(1, 0, 2), gate), k, v
 
 
-def ring_pages_for(window, psize):
-    """Pages of a "swa" layer's per-slot ring: the window's own and one
-    more, because a prefill writes whole pages (`ring_paged_attention`)."""
-    return -(-window // psize) + 1
+def _ring_of(spec, pool):
+    """Pages of each slot's ring in a ring pool (S * R, psize, lanes)."""
+    return ring_pages_for(spec.window, pool.shape[1], pool.shape[2],
+                          pool.dtype.itemsize)
 
 
 # ------------------------------------------------------------------ KDA
@@ -624,7 +643,7 @@ def mx_swa(w, x, k_ring, v_ring, lens, valid, spec):
     from ..ops.pallas_kernels import ring_paged_attention
     q, gate, k, v = gqa_project(w, spec, x, lens, "swa")
     s, (total, psize, _) = x.shape[0], k_ring.shape
-    ring = ring_pages_for(spec.window, psize)
+    ring = _ring_of(spec, k_ring)
     page = jnp.where(valid, jnp.arange(s) * ring + lens // psize % ring,
                      total)
     off = lens % psize
@@ -727,7 +746,7 @@ def mx_swa_seq(w, x, k_ring, v_ring, slot, n, spec):
     v_ring)."""
     y, k, v = gqa_sequence(w, spec, x, "swa")
     psize = k_ring.shape[1]
-    ring = ring_pages_for(spec.window, psize)
+    ring = _ring_of(spec, k_ring)
     last = jnp.maximum(n - 1, 0) // psize       # the page of position n-1
     r = jnp.arange(ring)
     src = jnp.maximum(last - (last - r) % ring, 0)
@@ -815,10 +834,11 @@ def _scan_batch(sequence, w, spec, x, chunk):
 
 class GatedAttention(_PureBlock):
     """Causal softmax attention, `kv_heads` KV heads under `heads` query
-    heads, q and k rotated where the spec says `attn_rope` (else no
-    positional term), output gated by sigmoid(W_gate x) unless the spec
-    says `attn_gate` false (`qkv_weight` then, without the gate's
-    rows)."""
+    heads, each head of q and k RMS-normed where the spec says `qk_norm`,
+    q and k rotated where the spec's `attn_rope` names the layer's kind
+    (else no positional term), output gated by sigmoid(W_gate x) unless
+    the spec says `attn_gate` false (`qkv_weight` then, without the
+    gate's rows)."""
 
     _kind = "gqa"
 
@@ -836,6 +856,11 @@ class GatedAttention(_PureBlock):
                 self.qkv_weight = self.params.get(
                     "qkv_weight", shape=((h + 2 * hk) * dh, d))
             self.o_weight = self.params.get("o_weight", shape=(d, h * dh))
+            if spec.qk_norm:
+                self.q_norm_gamma = self.params.get("q_norm_gamma",
+                                                    shape=(dh,))
+                self.k_norm_gamma = self.params.get("k_norm_gamma",
+                                                    shape=(dh,))
 
     def _pure(self, w, x):
         return jax.vmap(
@@ -844,8 +869,8 @@ class GatedAttention(_PureBlock):
 
 class WindowAttention(GatedAttention):
     """`GatedAttention` over a sliding window: a query attends the last
-    `spec.window` keys, itself among them; where the spec rotates, by the
-    plain table whatever the full layers scale."""
+    `spec.window` keys, itself among them; where the spec rotates window
+    layers, by the plain table whatever the full layers scale."""
 
     _kind = "swa"
 

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import math
 import os
 import threading
 
@@ -1362,47 +1363,116 @@ def _ring_attention_lax(q, k_ring, v_ring, lengths, span, sm_scale):
     return out.reshape(S, Hq, dh)
 
 
-def _rpa_ring_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, kv_heads, rows,
-                     span, sm_scale, dh):
-    """Sliding-window decode attention over a per-slot RING: one SLOT a
-    grid step with all its KV heads, its whole ring one block of K and
-    one of V (the ring is the slot's own contiguous pages, so no page
-    table and a block spec a pool, not a page). A row of the ring is
-    masked by the position it holds, which follows from the slot's length
-    alone (`ring_rows_back`): the `span` newest positions are attended,
-    whatever older lap or nothing a row still holds is not. The query
-    block stacks the KV heads' `rows` query rows as `_rpa_flat_kernel`'s
-    does (where `window` counts QUERY rows a slot; the span of KEYS a
-    query may read is `span` here). One softmax over the ring, no running
-    maximum: a step sees every key there is."""
-    n = k_ref.shape[1]
-    length = len_ref[pl.program_id(0)]
-    base = lax.rem(length - 1, jnp.int32(n))
-    back = base - lax.broadcasted_iota(jnp.int32, (rows, n), 1)
-    back = jnp.where(back < 0, back + n, back)
-    keep = (back < span) & (back < length)
-    for h in range(kv_heads):
-        r = slice(h * rows, (h + 1) * rows)
-        lanes = slice(h * dh, (h + 1) * dh)
-        s = jax.lax.dot_general(
-            q_ref[0, r, :], k_ref[0, :, lanes], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(keep, s, -1e30)
-        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-        v = v_ref[0, :, lanes]
-        o = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        o_ref[0, r, :] = (o / jnp.sum(p, axis=-1, keepdims=True)).astype(
-            o_ref.dtype)
+# buffers of a ring's blocks in `mxtpu_rpa_ring`: K and V, two each (the
+# pipeline fetches the next grid step's block while one is computed on)
+_RING_BUFFERS = 4
 
 
-def _rpa_ring_pallas(q, k_ring, v_ring, lengths, span, sm_scale):
-    """q: (S, Hq, dh); rings (S, n, H * dh); returns q's shape."""
+def ring_block_pages(pages, psize, lanes, itemsize):
+    """Pages a block of `mxtpu_rpa_ring` holds, from the shapes alone: a
+    slot's ring of `pages` pages of `psize` rows of `lanes` values in the
+    fewest blocks whose buffers (`_RING_BUFFERS`) fit `_RPA_VMEM_BUDGET`,
+    the ring's pages split evenly between them, a block whole sublane
+    tiles of rows unless it is the whole ring. A ring that is one block
+    is every ring whose four buffers fit; `ring_pages_for` rounds a ring of
+    more up to whole blocks."""
+    tile = 8 * max(1, 4 // itemsize)        # sublane rows of a tile
+    step = tile // math.gcd(tile, psize)    # pages of whole tiles
+    most = _RPA_VMEM_BUDGET // (_RING_BUFFERS * psize * lanes * itemsize)
+    most = max(step, most // step * step)
+    if pages <= most:
+        return pages
+    per = -(-pages // -(-pages // most))
+    return -(-per // step) * step
+
+
+def ring_pages_for(window, psize, lanes, itemsize):
+    """Pages of a sliding-window layer's per-slot ring of `psize`-row
+    pages of `lanes` values: the window's own and one more, because a
+    prefill writes whole pages (`ring_paged_attention`), rounded up to
+    whole blocks of `mxtpu_rpa_ring` (`ring_block_pages`: a ring that
+    fits the chip's fast memory whole is one block and keeps its
+    size)."""
+    pages = -(-window // psize) + 1
+    block = ring_block_pages(pages, psize, lanes, itemsize)
+    return -(-pages // block) * block
+
+
+def _rpa_ring_kernel(len_ref, nb_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
+                     l_scr, acc_scr, *, kv_heads, rows, n, span, sm_scale,
+                     dh):
+    """Sliding-window decode attention over a per-slot RING: one SLOT and
+    one BLOCK of its ring a grid step, all its KV heads at once (the
+    ring is the slot's own contiguous pages, so no page table: a block
+    spec over the slot's rows, `ring_block_pages` of them a block). Only
+    the blocks that hold a position the query sees are computed on
+    (`nb_ref[slot]`: a slot that has not filled its ring has its live
+    rows in the first blocks); the grid steps past them name the last
+    live block again, so the pipeline fetches nothing for them. A row of
+    the ring is masked by the position it holds, which follows from the
+    slot's length alone (`ring_rows_back`): the `span` newest positions
+    are attended, whatever older lap or nothing a row still holds is not.
+    The query block stacks the KV heads' `rows` query rows as
+    `_rpa_flat_kernel`'s does (where `window` counts QUERY rows a slot;
+    the span of KEYS a query may read is `span` here). Online softmax
+    across the blocks in float32, running max and sum lane-replicated
+    (rows, 128); a ring of one block is one pass."""
+    s_idx, j = pl.program_id(0), pl.program_id(1)
+    block = k_ref.shape[1]
+    length = len_ref[s_idx]
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, -1e30)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j < nb_ref[s_idx])
+    def _block():
+        base = lax.rem(length - 1, jnp.int32(n))
+        back = base - (j * block
+                       + lax.broadcasted_iota(jnp.int32, (rows, block), 1))
+        back = jnp.where(back < 0, back + n, back)
+        keep = (back < span) & (back < length)
+        for h in range(kv_heads):
+            r = slice(h * rows, (h + 1) * rows)
+            lanes = slice(h * dh, (h + 1) * dh)
+            s = jax.lax.dot_general(
+                q_ref[0, r, :], k_ref[0, :, lanes], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(keep, s, -1e30)
+            m_prev = m_scr[r, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_scr[r, :1] + jnp.sum(p, axis=-1,
+                                                   keepdims=True)
+            v = v_ref[0, :, lanes]
+            acc_scr[r, :] = acc_scr[r, :] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[r, :] = jnp.broadcast_to(m_new, (rows, 128))
+            l_scr[r, :] = jnp.broadcast_to(l_new, (rows, 128))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _out():
+        o_ref[0] = (acc_scr[:] /
+                    jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+def _rpa_ring_pallas(q, k_ring, v_ring, lengths, span, sm_scale, psize):
+    """q: (S, Hq, dh); rings (S, n, H * dh) of pages of `psize` rows;
+    returns q's shape."""
     S, Hq, dh = q.shape
-    n = k_ring.shape[1]
-    H = k_ring.shape[2] // dh
+    n, lanes = k_ring.shape[1:]
+    H = lanes // dh
     G = Hq // H
+    block = psize * ring_block_pages(n // psize, psize, lanes,
+                                     k_ring.dtype.itemsize)
+    if n % block:
+        raise ValueError(f"a ring of {n} rows is not whole blocks of "
+                         f"{block}: size it with ring_pages_for")
+    nb = n // block
     # whole sublane tiles a KV head: 8 rows of 4 bytes, 16 of 2
     tile = 8 * max(1, 4 // q.dtype.itemsize)
     rows = -(-G // tile) * tile
@@ -1410,20 +1480,28 @@ def _rpa_ring_pallas(q, k_ring, v_ring, lengths, span, sm_scale):
     if rows != G:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - G), (0, 0)))
     qr = qr.reshape(S, H * rows, dh)
-    block = pl.BlockSpec((1, H * rows, dh), lambda s, ln: (s, 0, 0))
-    ring = pl.BlockSpec((1, n, H * dh), lambda s, ln: (s, 0, 0))
+    lengths = lengths.astype(jnp.int32)
+    # the blocks that hold a live row: a slot past a whole ring, all
+    live = jnp.clip(-(-lengths // block), 1, nb).astype(jnp.int32)
+    qo = pl.BlockSpec((1, H * rows, dh), lambda s, j, ln, nl: (s, 0, 0))
+    ring = pl.BlockSpec((1, block, lanes),
+                        lambda s, j, ln, nl: (s, jnp.minimum(j, nl[s] - 1),
+                                              0))
     out = pl.pallas_call(
-        functools.partial(_rpa_ring_kernel, kv_heads=H, rows=rows,
+        functools.partial(_rpa_ring_kernel, kv_heads=H, rows=rows, n=n,
                           span=span, sm_scale=sm_scale, dh=dh),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(S,),
-            in_specs=[block, ring, ring], out_specs=block),
+            num_scalar_prefetch=2, grid=(S, nb),
+            in_specs=[qo, ring, ring], out_specs=qo,
+            scratch_shapes=[pltpu.VMEM((H * rows, 128), jnp.float32),
+                            pltpu.VMEM((H * rows, 128), jnp.float32),
+                            pltpu.VMEM((H * rows, dh), jnp.float32)]),
         out_shape=_sds((S, H * rows, dh), q.dtype, q, k_ring, v_ring),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
         name="mxtpu_rpa_ring",
-    )(lengths.astype(jnp.int32), qr, k_ring, v_ring)
+    )(lengths, live, qr, k_ring, v_ring)
     return out.reshape(S, H, rows, dh)[:, :, :G].reshape(S, Hq, dh)
 
 
@@ -1447,8 +1525,11 @@ def ring_paged_attention(q, k_pages, v_pages, lengths, window,
     Returns (S, Hq, dh).
 
     On the TPU (or MXTPU_PALLAS_INTERPRET=1), for heads of whole 128-lane
-    tiles, the Pallas kernel `mxtpu_rpa_ring`: a slot a grid step, its
-    ring one block. Elsewhere a lax form with the same numbers."""
+    tiles, the Pallas kernel `mxtpu_rpa_ring`: a slot and a block of its
+    ring a grid step, the ring in as few blocks as fit the chip's fast
+    memory (`ring_block_pages`: one where it fits whole), only the blocks
+    that hold a live row read. Elsewhere a lax form with the same
+    numbers."""
     S, _, dh = q.shape
     psize = k_pages.shape[1]
     R = k_pages.shape[0] // S
@@ -1458,9 +1539,11 @@ def ring_paged_attention(q, k_pages, v_pages, lengths, window,
     if sm_scale is None:
         sm_scale = 1.0 / (dh ** 0.5)
     rings = [p.reshape(S, R * psize, p.shape[-1]) for p in (k_pages, v_pages)]
-    form = (_rpa_ring_pallas if _rpa_pallas_ok(psize) and dh % 128 == 0
-            else _ring_attention_lax)
-    return form(q, *rings, lengths, int(window), float(sm_scale))
+    if _rpa_pallas_ok(psize) and dh % 128 == 0:
+        return _rpa_ring_pallas(q, *rings, lengths, int(window),
+                                float(sm_scale), psize)
+    return _ring_attention_lax(q, *rings, lengths, int(window),
+                               float(sm_scale))
 
 
 def _rpa_pallas_ok(psize):

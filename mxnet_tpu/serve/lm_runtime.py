@@ -18,8 +18,10 @@ whatever their mixers (`_KEEPS`):
   * a RING a slot for the sliding-window ("swa") layers, which no page
     table reaches and the page pool does not count: K and V pools of
     `(slots * R, psize, Hkv * dh)` a layer, R = window / psize + 1
-    pages, slot s owning pages s * R .. s * R + R - 1 for its life and
-    position p kept at ring page (p // psize) % R, row p % psize. What a
+    pages (rounded up to whole blocks of the decode kernel where a ring
+    is too large for the chip's fast memory whole), slot s owning pages
+    s * R .. s * R + R - 1 for its life and position p kept at ring page
+    (p // psize) % R, row p % psize. What a
     row holds from a lap before is further back than the window, and a
     query masks every row by the position it holds
     (`ops.pallas_kernels.ring_paged_attention`): the cache of a window
@@ -76,7 +78,7 @@ from .. import profiler
 from ..base import MXNetError
 from ..models import decoder_lm as lm
 from ..ops.nn_ops import rms_norm
-from ..ops.pallas_kernels import pool_lanes
+from ..ops.pallas_kernels import pool_lanes, ring_block_pages, ring_pages_for
 from ..observability import registry as _obs_registry
 from ..observability import tracer as _tracer
 from ..observability import compilex as _compilex
@@ -208,8 +210,13 @@ class LMRuntime:
         self._in_prefill = self._is_moe * (np.arange(len(subs))
                                            < self._last_mixer)
         # pages a slot's ring has in each sliding-window layer
-        self.ring = lm.ring_pages_for(spec.window, self.page_size) \
-            if self._n["ring"] else 0
+        lanes, itemsize = (spec.kv_heads * spec.head_dim,
+                           jnp.dtype(self._dtype).itemsize)
+        self.ring = ring_pages_for(spec.window, self.page_size, lanes,
+                                   itemsize) if self._n["ring"] else 0
+        # rows of a block of the ring that the decode kernel fetches whole
+        self.ring_block = self.page_size * ring_block_pages(
+            self.ring, self.page_size, lanes, itemsize) if self.ring else 0
         self.page_reuse_refusal = (
             "the model has recurrent (KDA or state-space) layers: a "
             "slot's state after a prefix is one array a layer that pages "
@@ -291,11 +298,12 @@ class LMRuntime:
                      "dispatches": np.zeros((layers,), np.int64)}
         self._pending = []
         # always-on counts of the sliding-window layers' decode turns
-        self._win = {"turns": 0, "ring_tokens": 0}
+        self._win = {"turns": 0, "ring_tokens": 0, "ring_rows": 0}
         # always-on counts of the paged ("gqa") layers' decode turns
         self._paged = {"turns": 0, "live_pages": 0, "table_pages": 0}
         # always-on counts of the prefills by the rung each ran at
         self._pre = {"prefills": 0, "prompt_tokens": 0, "rung_tokens": 0,
+                     "window_keys": 0,
                      "by_rung": dict.fromkeys(self.rungs, 0)}
         # the last decode step's tokens, the next one's `prev_tok`
         self._last_tok = jnp.zeros((s,), jnp.int32)
@@ -361,8 +369,12 @@ class LMRuntime:
         was made: `turns`, the decode turns launched, and `ring_tokens`,
         the sum over those turns and their running slots of
         min(len + 1, window): the keys (and values) ONE window layer's
-        decode attention had to read, the current position among them.
-        Counted from the `lens` a launch holds on the host."""
+        decode attention had to read, the current position among them;
+        and `ring_rows`, the same sum of the rows the decode kernel
+        fetched for them: the blocks of `ring_block` rows that hold a
+        position the slot has written (all of the ring's once it has
+        lapped it). Counted from the `lens` a launch holds on the
+        host."""
         return dict(self._win)
 
     def paged_counters(self):
@@ -380,8 +392,11 @@ class LMRuntime:
         """The prefills' always-on counts since the state was made:
         `prefills`, `prompt_tokens` (the positions they prefilled: each
         prompt but its last token), `rung_tokens` (the positions they
-        ran: each one's rung, `rungs`), `by_rung` {rung: prefills}.
-        Counted on the host from the prompt's length."""
+        ran: each one's rung, `rungs`), `window_keys` (the sum over the
+        prefilled positions t of min(t + 1, window): the keys ONE
+        sliding-window layer's prefill attention had to read; 0 without
+        such layers), `by_rung` {rung: prefills}. Counted on the host
+        from the prompt's length."""
         return dict(self._pre, by_rung=dict(self._pre["by_rung"]))
 
     def _count(self, counts, prefill=False):
@@ -684,6 +699,10 @@ class LMRuntime:
         pre["prompt_tokens"] += n
         pre["rung_tokens"] += rung
         pre["by_rung"][rung] += 1
+        if self._n["ring"]:
+            w = min(n, self.spec.window)
+            pre["window_keys"] += w * (w + 1) // 2 \
+                + (n - w) * self.spec.window
         old = jax.tree_util.tree_leaves(self._state)
 
         def launch():
@@ -730,9 +749,12 @@ class LMRuntime:
         run = np.asarray(active) > 0
         seen = np.asarray(lens)[run] + 1
         if self._n["ring"]:
+            rows = self.ring * self.page_size
             self._win["turns"] += 1
             self._win["ring_tokens"] += int(np.minimum(
                 seen, self.spec.window).sum())
+            self._win["ring_rows"] += int(np.minimum(
+                -(-seen // self.ring_block) * self.ring_block, rows).sum())
         if self._n["kv"]:
             count_pages(self._paged, page_tables, active,
                         np.asarray(lens) + 1, self.page_size)

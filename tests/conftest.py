@@ -100,10 +100,13 @@ def prefill_ladder(request, monkeypatch):
                 np.testing.assert_allclose(mine, theirs, atol=1e-5,
                                            err_msg=f"n={n}")
         rung = {n: min(r for r in rt.rungs if r >= n) for n in ns}
+        # the keys one window layer's prefill attention reads
+        keys = sum(min(t + 1, rt.spec.window) for n in ns
+                   for t in range(n)) if rt.ring else 0
         assert rt.prefill_traces == 1
         assert rt.prefill_counters() == {
             "prefills": len(ns), "prompt_tokens": sum(ns),
-            "rung_tokens": sum(rung.values()),
+            "rung_tokens": sum(rung.values()), "window_keys": keys,
             "by_rung": {r: list(rung.values()).count(r) for r in rt.rungs}}
         return rt
 

@@ -122,6 +122,80 @@ def test_ring_kernel_equals_its_lax_form(interpret, lengths):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
 
 
+@pytest.mark.parametrize("lengths", [(1, 5, 24, 25), (72, 73, 96, 97),
+                                     (200, 500, 1000, 3)], ids=str)
+def test_ring_kernel_in_blocks_equals_its_lax_form(interpret, monkeypatch,
+                                                   lengths):
+    """A ring too large for the kernel's budget whole (the budget cut so
+    that 3 pages of 8 rows of 2 x 128 float32 lanes fill it): 10 pages
+    rounded up to 12 in 4 blocks, slots under a block, at and past the
+    window, and several laps of the ring; online softmax over the
+    blocks, only the live ones read."""
+    monkeypatch.setattr(pk, "_RPA_VMEM_BUDGET", 4 * 3 * 8 * 256 * 4)
+    window, psize = 72, 8
+    ring = pk.ring_pages_for(window, psize, 256, 4)
+    assert ring == 12 and pk.ring_block_pages(ring, psize, 256, 4) == 3
+    rng = np.random.default_rng(sum(lengths))
+    s = len(lengths)
+    q = jnp.asarray(rng.normal(size=(s, 8, 128)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(s * ring, psize, 256)),
+                        jnp.float32) for _ in range(2))
+    ln = jnp.asarray(lengths, jnp.int32)
+    got = pk.ring_paged_attention(q, k, v, ln, window)
+    n = ring * psize
+    want = pk._ring_attention_lax(q, k.reshape(s, n, -1),
+                                  v.reshape(s, n, -1), ln, window,
+                                  128 ** -0.5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    with pytest.raises(ValueError, match="not whole blocks"):
+        pk.ring_paged_attention(q, k[:s * 10], v[:s * 10], ln, window)
+
+
+def test_ring_kernel_reads_no_block_past_a_slots_length(interpret,
+                                                        monkeypatch):
+    """A slot that has not filled its ring neither fetches nor computes
+    on the blocks past its length (the grid steps there name its last
+    live block again, and are skipped): NaN in every row past the second
+    block of 24 leaves slots of 1, 24, 25 and 48 positions as they
+    were."""
+    monkeypatch.setattr(pk, "_RPA_VMEM_BUDGET", 4 * 3 * 8 * 256 * 4)
+    rng = np.random.default_rng(48)
+    q = jnp.asarray(rng.normal(size=(4, 8, 128)), jnp.float32)
+    k, v = (rng.normal(size=(4, 96, 256)).astype(np.float32)
+            for _ in range(2))
+    ln = jnp.asarray([1, 24, 25, 48], jnp.int32)
+
+    def attend(k, v):
+        return np.asarray(pk.ring_paged_attention(
+            q, jnp.asarray(k).reshape(48, 8, 256),
+            jnp.asarray(v).reshape(48, 8, 256), ln, 72))
+
+    clean = attend(k, v)
+    k[:, 48:], v[:, 48:] = np.nan, np.nan
+    np.testing.assert_array_equal(attend(k, v), clean)
+    assert np.isfinite(clean).all()
+
+
+def test_ring_kernel_is_one_block_at_the_window_cells_shape(interpret):
+    """The window configuration's ring (65 pages of 16 rows, 4 KV heads
+    of 128 in bf16) fits the budget whole: one block a slot, as before
+    the kernel took blocks."""
+    assert pk.ring_block_pages(65, 16, 512, 2) == 65
+    rng = np.random.default_rng(65)
+    lengths = (700, 3000)
+    q = jnp.asarray(rng.normal(size=(2, 32, 128)), jnp.bfloat16)
+    k, v = (jnp.asarray(rng.normal(size=(2 * 65, 16, 512)), jnp.bfloat16)
+            for _ in range(2))
+    ln = jnp.asarray(lengths, jnp.int32)
+    got = pk.ring_paged_attention(q, k, v, ln, 1024)
+    f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+    want = pk._ring_attention_lax(f32[0], f32[1].reshape(2, 1040, -1),
+                                  f32[2].reshape(2, 1040, -1), ln, 1024,
+                                  128 ** -0.5)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), atol=2e-2)
+
+
 def test_ring_rows_hold_the_newest_position_congruent_to_them():
     back = np.asarray(pk.ring_rows_back(jnp.asarray([1, 41, 100]), 40))
     assert back.shape == (3, 40)
@@ -202,7 +276,8 @@ def test_a_ring_holds_the_last_positions_a_query_may_read(n, m):
     position a query at the last position may read; the neighbours'
     rings are untouched; an empty slot's step writes nowhere."""
     psize, slots = 8, 3
-    ring = dlm.ring_pages_for(SPEC.window, psize)
+    ring = pk.ring_pages_for(SPEC.window, psize,
+                             SPEC.kv_heads * SPEC.head_dim, 4)
     assert ring == 5
     rows = ring * psize
     rng = np.random.default_rng(n * 100 + m)

@@ -507,6 +507,25 @@ def test_ring_paged_attention_compiles(chip_compile):
                          r"transpose|fusion)\(", text)
 
 
+# the QK-normed window-and-NoPE expert server's shapes
+# (benchmarks/configs/trinity_large_ep8.json): 48 query heads over 8 KV
+# heads of 128, a window of 4096 over 16-token pages: a ring of 257 pages
+# (4112 rows of 1024 lanes, 8.4 MB a pool) that the kernel cannot hold
+# whole in its 16 MiB of fast memory, rounded up to 260 pages and taken
+# in 5 blocks of 52
+def test_ring_paged_attention_compiles_in_blocks(chip_compile):
+    slots, ring = 16, pk.ring_pages_for(4096, 16, 1024, 2)
+    assert ring == 260 and pk.ring_block_pages(ring, 16, 1024, 2) == 52
+    text = chip_compile(
+        lambda *a: pk.ring_paged_attention(*a, 4096),
+        ((slots, 48, 128), BF16), ((slots * ring, 16, 1024), BF16),
+        ((slots * ring, 16, 1024), BF16), ((slots,), I32))
+    assert kernel_calls(text, ("mxtpu_rpa_ring",)) == {"mxtpu_rpa_ring": 1}
+    import re
+    assert not re.search(r"= bf16\[(4160|16),(16|4160),1024[^=]* (copy|"
+                         r"transpose|fusion)\(", text)
+
+
 @pytest.mark.parametrize("window", [None, 1024], ids=["full", "w1024"])
 def test_prefill_flash_compiles_at_the_static_prompt(chip_compile, window):
     qkv = ((1, 32, 4096, 128), BF16)
